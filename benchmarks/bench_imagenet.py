@@ -66,7 +66,7 @@ def main() -> int:
     device_kind = jax.devices()[0].device_kind
     mesh = make_client_mesh(min(len(jax.devices()), NUM_WORKERS))
 
-    small = (SMALL or platform == "cpu") and not FORCE_FULL
+    small = SMALL and not FORCE_FULL
     if small:
         px, batch, micro, classes = 64, 4, 2, 10
         model = build_model("FixupResNet50", num_classes=classes, width=8)
@@ -170,18 +170,5 @@ def main() -> int:
     return 0
 
 
-def orchestrate() -> int:
-    out = bench.run_orchestrated("IMAGENET_BENCH_SMALL",
-                                 script=os.path.abspath(__file__))
-    if out is None:
-        out = {"metric": "imagenet_fixupresnet50_uncompressed_round_time",
-               "value": None, "unit": "ms/round", "vs_baseline": None,
-               "error": "all bench children failed or timed out"}
-    print(json.dumps(out), flush=True)
-    return 0
-
-
 if __name__ == "__main__":
-    if os.environ.get("BENCH_IS_WORKER") == "1":
-        raise SystemExit(bench.worker_entry(main))
-    raise SystemExit(orchestrate())
+    raise SystemExit(bench.worker_entry(main))

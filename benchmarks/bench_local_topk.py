@@ -66,7 +66,7 @@ def main() -> int:
     device_kind = jax.devices()[0].device_kind
     mesh = make_client_mesh(min(len(jax.devices()), NUM_WORKERS))
 
-    small = SMALL or platform == "cpu"
+    small = SMALL
     num_classes = 100
     if small:
         model_mod = build_model("ResNet9", num_classes=num_classes,
@@ -172,20 +172,5 @@ def main() -> int:
     return 0
 
 
-def orchestrate() -> int:
-    """Parent: run main() in a hard-killed child, degrading to a CPU
-    child (small geometry) if the TPU child dies or times out."""
-    out = bench.run_orchestrated("LTK_BENCH_SMALL",
-                                 script=os.path.abspath(__file__))
-    if out is None:
-        out = {"metric": "cifar100_resnet18_local_topk_round_time",
-               "value": None, "unit": "ms/round", "vs_baseline": None,
-               "error": "all bench children failed or timed out"}
-    print(json.dumps(out), flush=True)
-    return 0
-
-
 if __name__ == "__main__":
-    if os.environ.get("BENCH_IS_WORKER") == "1":
-        raise SystemExit(bench.worker_entry(main))
-    raise SystemExit(orchestrate())
+    raise SystemExit(bench.worker_entry(main))

@@ -94,7 +94,7 @@ def main() -> int:
     from commefficient_tpu.training import gpt2_train
     from commefficient_tpu.utils.schedules import LambdaLR, PiecewiseLinear
 
-    small = (SMALL or platform == "cpu") and not FORCE_FULL
+    small = SMALL and not FORCE_FULL
     t0 = time.time()
     with bench.alarm_guard(STAGE_TIMEOUT, "torch checkpoint"):
         ckpt_dir = make_torch_checkpoint(small)
@@ -236,19 +236,5 @@ def main() -> int:
     return 0
 
 
-def orchestrate() -> int:
-    out = bench.run_orchestrated("GPT2_FULL_SMALL",
-                                 script=os.path.abspath(__file__),
-                                 tpu_timeout=4800)
-    if out is None:
-        out = {"metric": "gpt2_small_pretrained_federated_finetune",
-               "platform": None,
-               "error": "all children failed or timed out"}
-    print(json.dumps(out), flush=True)
-    return 0
-
-
 if __name__ == "__main__":
-    if os.environ.get("BENCH_IS_WORKER") == "1":
-        raise SystemExit(bench.worker_entry(main))
-    raise SystemExit(orchestrate())
+    raise SystemExit(bench.worker_entry(main))

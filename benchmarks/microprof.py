@@ -68,7 +68,7 @@ def main():
         S[name] = round(v, 2)
         print(f"  {name}: {v:.2f} ms", file=sys.stderr, flush=True)
 
-    small = platform == "cpu"
+    small = os.environ.get("PROF_SMALL", "") == "1"
 
     # ---- config #5 geometry (GPT2-small) -------------------------------
     D5 = 1_000_000 if small else 123_756_289
@@ -138,16 +138,5 @@ def main():
     return 0
 
 
-def orchestrate() -> int:
-    out = bench.run_orchestrated("PROF_SMALL",
-                                 script=os.path.abspath(__file__))
-    if out is None:
-        out = {"error": "all microprof children failed or timed out"}
-    print(json.dumps(out, indent=1), flush=True)
-    return 0
-
-
 if __name__ == "__main__":
-    if os.environ.get("BENCH_IS_WORKER") == "1":
-        raise SystemExit(bench.worker_entry(main))
-    raise SystemExit(orchestrate())
+    raise SystemExit(bench.worker_entry(main))

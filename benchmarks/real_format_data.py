@@ -27,14 +27,10 @@ from __future__ import annotations
 
 import json
 import os
-import pickle
 import sys
 import time
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 import jax.numpy as jnp
 import numpy as np
@@ -47,58 +43,12 @@ BATCH = 32
 OUT = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                    "real_format_results.json")
 
-CIFAR10_LABELS = [
-    b"airplane", b"automobile", b"bird", b"cat", b"deer",
-    b"dog", b"frog", b"horse", b"ship", b"truck",
-]
-
-
-def write_cifar10_archive(root: str, seed: int = 0,
-                          n_per_batch: int = 10_000) -> str:
-    """A `cifar-10-batches-py` directory format-identical to the real
-    download: 5 train pickles x 10,000 rows + test_batch + batches.meta,
-    CHW uint8 b'data' rows, python list b'labels', pickle protocol 2
-    (the original archives' encoding). Image content is the
-    deterministic class-signal synthetic (the bytes are the only thing
-    zero-egress can't reproduce); everything downstream — file layout,
-    dict keys, dtypes, row format, reader code — is the real thing."""
-    d = os.path.join(root, "cifar-10-batches-py")
-    if os.path.isfile(os.path.join(d, "data_batch_5")):
-        return d
-    os.makedirs(d, exist_ok=True)
-    rng = np.random.RandomState(seed)
-    protos = rng.rand(10, 32, 32, 3).astype(np.float32)
-
-    def make_rows(n, tag):
-        labels = rng.randint(0, 10, size=n)
-        noise = rng.rand(n, 32, 32, 3).astype(np.float32)
-        imgs = ((0.6 * protos[labels] + 0.4 * noise) * 255).astype(np.uint8)
-        # real row format: CHW flattened to 3072, R plane first
-        data = imgs.transpose(0, 3, 1, 2).reshape(n, 3072)
-        fnames = [b"%s_s_%06d.png" % (CIFAR10_LABELS[l], i)
-                  for i, l in enumerate(labels)]
-        return {b"batch_label": tag, b"labels": labels.tolist(),
-                b"data": data, b"filenames": fnames}
-
-    for i in range(1, 6):
-        rows = make_rows(
-            n_per_batch, b"training batch %d of 5" % i)
-        with open(os.path.join(d, f"data_batch_{i}"), "wb") as f:
-            pickle.dump(rows, f, protocol=2)
-    with open(os.path.join(d, "test_batch"), "wb") as f:
-        pickle.dump(make_rows(n_per_batch, b"testing batch 1 of 1"), f,
-                    protocol=2)
-    with open(os.path.join(d, "batches.meta"), "wb") as f:
-        pickle.dump({b"num_cases_per_batch": n_per_batch,
-                     b"label_names": CIFAR10_LABELS,
-                     b"num_vis": 3072}, f, protocol=2)
-    return d
-
-
 def main():
     from commefficient_tpu.config import Config
     from commefficient_tpu.data import FedCIFAR10, FedLoader, FedValLoader
-    from commefficient_tpu.data.cifar import _try_load_cifar_pickles
+    from commefficient_tpu.data.cifar import (
+        _try_load_cifar_pickles, write_cifar10_archive,
+    )
     from commefficient_tpu.data.transforms import cifar10_transforms
     from commefficient_tpu.federated.api import FedModel, FedOptimizer
     from commefficient_tpu.models import ResNet9

@@ -256,13 +256,16 @@ def _site(eqn) -> str:
     return f"{short}:{best.line_num} ({best.function_name})"
 
 
-# primitives that only move/reshape data: every lattice property of
-# the (single data) operand survives
+# primitives that only move/reshape/retype data: every lattice
+# property of the (single data) operand survives. pvary/pcast are the
+# casts jax 0.9.0 inserts under shard_map's check_vma — also around a
+# literal guard like the 1.0 of jnp.maximum(count, 1.0) — and change a
+# value's varying-axes type, never the value.
 _SHAPE_ONLY = frozenset({
     "broadcast_in_dim", "reshape", "transpose", "squeeze",
     "expand_dims", "rev", "copy", "stop_gradient", "slice",
     "device_put", "sharding_constraint", "convert_element_type",
-    "real", "reduce_precision",
+    "real", "reduce_precision", "pvary", "pcast",
 })
 
 # gather-class: output elements are a subset of operand 0's elements
@@ -645,7 +648,8 @@ class _LatticeAuditor:
                                     and a.nonneg),
                            src=a.src)] * n_out
 
-        if name in ("all_gather", "ppermute", "all_to_all",
+        if name in ("all_gather", "all_gather_invariant", "ppermute",
+                    "all_to_all",
                     "pbroadcast", "pmax", "pmin"):
             a = _join(*ins) if ins else _DEFAULT
             return [dataclasses.replace(a, const_nonfinite=False)

@@ -26,7 +26,9 @@ from typing import Optional, Tuple
 import numpy as np
 
 from commefficient_tpu.data.fed_dataset import FedDataset
-from commefficient_tpu.utils.atomic_io import atomic_save, atomic_savez
+from commefficient_tpu.utils.atomic_io import (
+    atomic_save, atomic_savez, atomic_write_bytes,
+)
 
 
 def _try_load_cifar_pickles(root: str, name: str):
@@ -99,6 +101,53 @@ def _synthetic_cifar(num_classes: int, n_train: int, n_val: int, seed: int,
         return (imgs * 255).astype(np.uint8), labels.astype(np.int64)
 
     return gen(n_train), gen(n_val)
+
+
+CIFAR10_LABELS = [
+    b"airplane", b"automobile", b"bird", b"cat", b"deer",
+    b"dog", b"frog", b"horse", b"ship", b"truck",
+]
+
+
+def write_cifar10_archive(root: str, seed: int = 0,
+                          n_per_batch: int = 10_000) -> str:
+    """A `cifar-10-batches-py` directory format-identical to the real
+    download: 5 train pickles x 10,000 rows + test_batch + batches.meta,
+    CHW uint8 b'data' rows, python list b'labels', pickle protocol 2
+    (the original archives' encoding). Image content is the
+    deterministic class-signal synthetic (the bytes are the only thing
+    zero-egress can't reproduce); everything downstream — file layout,
+    dict keys, dtypes, row format, reader code — is the real thing."""
+    d = os.path.join(root, "cifar-10-batches-py")
+    if os.path.isfile(os.path.join(d, "batches.meta")):  # written last
+        return d
+    os.makedirs(d, exist_ok=True)
+    rng = np.random.RandomState(seed)
+    protos = rng.rand(10, 32, 32, 3).astype(np.float32)
+
+    def make_rows(n, tag):
+        labels = rng.randint(0, 10, size=n)
+        noise = rng.rand(n, 32, 32, 3).astype(np.float32)
+        imgs = ((0.6 * protos[labels] + 0.4 * noise) * 255).astype(np.uint8)
+        # real row format: CHW flattened to 3072, R plane first
+        data = imgs.transpose(0, 3, 1, 2).reshape(n, 3072)
+        fnames = [b"%s_s_%06d.png" % (CIFAR10_LABELS[l], i)
+                  for i, l in enumerate(labels)]
+        return {b"batch_label": tag, b"labels": labels.tolist(),
+                b"data": data, b"filenames": fnames}
+
+    def dump(name, obj):
+        atomic_write_bytes(os.path.join(d, name),
+                           pickle.dumps(obj, protocol=2))
+
+    for i in range(1, 6):
+        dump(f"data_batch_{i}",
+             make_rows(n_per_batch, b"training batch %d of 5" % i))
+    dump("test_batch", make_rows(n_per_batch, b"testing batch 1 of 1"))
+    dump("batches.meta", {b"num_cases_per_batch": n_per_batch,
+                          b"label_names": CIFAR10_LABELS,
+                          b"num_vis": 3072})
+    return d
 
 
 class FedCIFAR10(FedDataset):

@@ -40,7 +40,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from commefficient_tpu.data.fed_dataset import FedDataset
-from commefficient_tpu.utils.atomic_io import atomic_savez
+from commefficient_tpu.utils.atomic_io import (
+    atomic_savez, atomic_write_text,
+)
 
 SPECIAL_TOKENS = ("<bos>", "<eos>", "<speaker1>", "<speaker2>", "<pad>")
 IGNORE_INDEX = -1
@@ -257,6 +259,25 @@ def _synthetic_personachat(num_personas: int, dialogs_per_persona: int,
              for _ in range(dialogs_per_persona)]
     valid = [dialog(10_000 + p) for p in range(max(2, num_personas // 4))]
     return {"train": train, "valid": valid}
+
+
+def write_personachat_raw(dataset_dir: str, seed: int = 0,
+                          num_personas: int = 32,
+                          dialogs_per_persona: int = 2,
+                          utterances_per_dialog: int = 4,
+                          num_candidates: int = 2) -> str:
+    """Write a `personachat_self_original.json` in the raw schema
+    (the file FedPERSONA.prepare looks for) from `seed`, so a driver
+    can run WITHOUT --test where the real corpus cannot be fetched.
+    Content is the deterministic synthetic dialogs; the reader, the
+    tokenization and the partition downstream are the real path."""
+    path = os.path.join(dataset_dir, "PERSONA", FedPERSONA.RAW_NAME)
+    if not os.path.isfile(path):
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        atomic_write_text(path, json.dumps(_synthetic_personachat(
+            num_personas, dialogs_per_persona, utterances_per_dialog,
+            num_candidates, seed)))
+    return path
 
 
 class FedPERSONA(FedDataset):

@@ -38,9 +38,12 @@ from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
+# not exported by jax 0.9.0 (the one pinned in pyproject.toml): the
+# all_gather whose result is typed replicated, see its one use below
+from jax._src.lax.parallel import all_gather_invariant
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from commefficient_tpu.parallel.compat import pcast, shard_map
 
 from commefficient_tpu import compress
 from commefficient_tpu.config import Config
@@ -813,11 +816,15 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
                     V = jnp.concatenate(
                         [t.reshape(Wl, -1).astype(jnp.float32)
                          for t in leaves_a], axis=1)
-                    allV = jax.lax.all_gather(
+                    # the INVARIANT all_gather: its result is typed
+                    # replicated over `clients`, which is what lets
+                    # shard_map's check_vma accept the P() out_specs
+                    # of the aggregate and its stats below
+                    allV = all_gather_invariant(
                         V, "clients").reshape(-1, V.shape[1])
-                    n_w = jax.lax.all_gather(
+                    n_w = all_gather_invariant(
                         counts, "clients").reshape(-1)
-                    adm = jax.lax.all_gather(
+                    adm = all_gather_invariant(
                         surv_eff, "clients").reshape(-1) > 0
                     # per-cell eligibility: admitted AND finite (a
                     # screen-off round may admit NaN/Inf transmits;

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from commefficient_tpu.ops.kernels.vma import vary_together
+
 DEFAULT_BLOCK = 128
 NEG_INF = -1e30
 # pad rows of the saved logsumexp carry this so exp(s - lse) == 0
@@ -135,19 +137,25 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finalize():
         l_safe = jnp.maximum(l_scr[:, 0], 1e-30)
         o_ref[0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[:, 0] + jnp.log(l_safe)).astype(jnp.float32)
+        lse_ref[0] = (m_scr[:, :1] + jnp.log(l_safe)[:, None]).astype(
+            jnp.float32)                                # [bq, 1]
 
 
 def _flash_fwd_pallas(q, k, v, sm_scale, block_q, block_k,
                       interpret=False):
     B, H, L, Dh = q.shape
     assert L % block_q == 0 and L % block_k == 0
-    qf = q.reshape(B * H, L, Dh)
-    kf = k.reshape(B * H, L, Dh)
-    vf = v.reshape(B * H, L, Dh)
+    # inside shard_map (check_vma) the outputs must say over which
+    # manual axes they vary: wherever any operand does
+    vma, (qf, kf, vf) = vary_together(
+        q.reshape(B * H, L, Dh), k.reshape(B * H, L, Dh),
+        v.reshape(B * H, L, Dh))
 
     kernel = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                                block_q=block_q, block_k=block_k)
+    # lse is laid out [B*H, L, 1] with block (1, block_q, 1): Mosaic
+    # wants a block's last two dims (8, 128)-divisible or equal to the
+    # array's, which a (1, block_q) block of [B*H, L] is not
     o, lse = pl.pallas_call(
         kernel,
         grid=(B * H, L // block_q, L // block_k),
@@ -158,11 +166,11 @@ def _flash_fwd_pallas(q, k, v, sm_scale, block_q, block_k,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, Dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, L, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B * H, L), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, L, Dh), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B * H, L, 1), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max

@@ -44,18 +44,26 @@ geometries never reach this route.
 Interpret mode: every `pallas_call` here takes `interpret=True` off
 TPU (trace-time backend consult, same caveat class as
 `CSVec.encode_k_sparse`), so the tier-1 CPU suite runs the identical
-kernel bodies — the ISSUE-6 testing contract.
+kernel bodies — the ISSUE-6 testing contract. On a TPU nothing is
+interpreted.
 
-VMEM sizing: per-step residency is 3 rows of c f32 for encode and
-(r + 3) rows for the estimate/decode kernels (the scratch holds all
-r rotated rows of a chunk). `pallas_fits` gates each kernel on a
-conservative VMEM budget; an oversized geometry silently keeps the
-XLA route for THAT method — same route-gate discipline as
-DECODE_MATERIALIZE_LIMIT, static per geometry. At the flagship
-5 x 500k table the estimate/decode kernels sit at the 16 MiB edge,
-so the shipped budget keeps them on XLA there until the kernels are
-re-tiled on real hardware (PERF.md "Kernel backends" records this as
-the open TPU-tuning item).
+Compile status (PR 21, tests/test_tpu_compile.py): the chip's
+compiler has never accepted one of these kernels at a real shape. At
+the flagship 5 x 500,000 table the (1, c) blocks of [B, c] / [r, c]
+are refused (a block's last two dimensions must be divisible by 8 and
+128 or equal the array's); with (1, 1, c) blocks on [B, 1, c] the
+traced `pltpu.roll` of a [1, 500000] row is an unaligned
+`tpu.dynamic_rotate`, and at an aligned c = 524,288 a one-row block
+pads to eight sublanes and is double buffered to 29.9 MB of scoped
+VMEM against a 16 MB limit. They need a re-tiling (ROADMAP D1/S6).
+On the chip `kernel_backend="pallas"` raises the compiler's error.
+
+VMEM sizing: `pallas_fits` refuses a geometry whose rows cannot fit:
+3 rows of c f32 for encode and (r + 3) rows for the estimate/decode
+kernels (the scratch holds all r rotated rows of a chunk), each row
+counted as the compiler counts it — padded to eight sublanes. A
+geometry that does not fit RAISES when `kernel_backend="pallas"` was
+asked for: a user who asks for a backend gets it or is told.
 """
 from __future__ import annotations
 
@@ -67,9 +75,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Conservative per-kernel VMEM budget (bytes). TPU cores expose
-# ~16 MiB of VMEM; leave headroom for Pallas' pipelining buffers.
-PALLAS_VMEM_BUDGET = 14 * 1024 * 1024
+from commefficient_tpu.ops.kernels.vma import vary_together
+
+# The compiler's scoped-VMEM limit for one kernel (bytes), and the
+# sublanes a one-row f32 block is padded to.
+PALLAS_VMEM_BUDGET = 16 * 1024 * 1024
+_SUBLANES = 8
 
 # Strided-sample size target for the fused threshold decode — same
 # ~1M-point quantile estimator as ops/flat._TOPK_SAMPLE.
@@ -82,13 +93,16 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def pallas_fits(sk, kind: str) -> bool:
-    """Whether `kind` ('encode' | 'estimate') fits the VMEM budget at
-    this geometry. Static per geometry — a given CSVec takes one route
-    everywhere, so multihost bit-equality proofs compare like with
-    like."""
+def pallas_vmem_bytes(sk, kind: str) -> int:
+    """VMEM the `kind` ('encode' | 'estimate') kernel holds at this
+    geometry, rows padded to the sublanes the compiler pads them to."""
     rows = 3 if kind == "encode" else sk.r + 3
-    return rows * sk.c * 4 <= PALLAS_VMEM_BUDGET
+    return rows * _SUBLANES * sk.c * 4
+
+
+def pallas_fits(sk, kind: str) -> bool:
+    """Whether `kind` fits the VMEM budget at this geometry."""
+    return pallas_vmem_bytes(sk, kind) <= PALLAS_VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------
@@ -165,8 +179,11 @@ def pallas_encode(sk, vec: jax.Array) -> jax.Array:
     accumulate kernel. Bit-for-bit the same sum ORDER as the XLA
     static path (chunks accumulate in ascending order per row), so
     equivalence tests can demand tight tolerances."""
-    chunks = sk._padded_chunks(vec.astype(jnp.float32))       # [B, c]
     B = sk.n_chunks
+    vma, operands = vary_together(
+        jnp.asarray(sk._offsets), jnp.asarray(sk._delta),
+        sk._padded_chunks(vec.astype(jnp.float32)),           # [B, c]
+        jnp.asarray(sk._eps))
     kernel = functools.partial(_encode_kernel, c=sk.c)
     return pl.pallas_call(
         kernel,
@@ -178,10 +195,10 @@ def pallas_encode(sk, vec: jax.Array) -> jax.Array:
             pl.BlockSpec((1, sk.c), lambda j, b: (j, 0)),     # eps row
         ],
         out_specs=pl.BlockSpec((1, sk.c), lambda j, b: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((sk.r, sk.c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((sk.r, sk.c), jnp.float32,
+                                       vma=vma),
         interpret=_interpret(),
-    )(jnp.asarray(sk._offsets), jnp.asarray(sk._delta),
-      chunks, jnp.asarray(sk._eps))
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -207,6 +224,9 @@ def pallas_estimate_all(sk, table: jax.Array) -> jax.Array:
     superset of the XLA estimate_all contract, whose callers zero the
     tail themselves; zeros-for-zeros either way)."""
     B = sk.n_chunks
+    vma, operands = vary_together(
+        jnp.asarray(sk._offsets), jnp.asarray(sk._delta),
+        table.astype(jnp.float32), jnp.asarray(sk._eps))
     kernel = functools.partial(_estimate_kernel, r=sk.r, c=sk.c, d=sk.d)
     return pl.pallas_call(
         kernel,
@@ -218,11 +238,10 @@ def pallas_estimate_all(sk, table: jax.Array) -> jax.Array:
             pl.BlockSpec((1, sk.c), lambda b, j: (j, 0)),     # eps row
         ],
         out_specs=pl.BlockSpec((1, sk.c), lambda b, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, sk.c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, sk.c), jnp.float32, vma=vma),
         scratch_shapes=[pltpu.VMEM((sk.r, sk.c), jnp.float32)],
         interpret=_interpret(),
-    )(jnp.asarray(sk._offsets), jnp.asarray(sk._delta),
-      table.astype(jnp.float32), jnp.asarray(sk._eps))
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -283,10 +302,9 @@ def pallas_threshold_decode(sk, table: jax.Array, k: int) -> jax.Array:
     B = sk.n_chunks
     stride, ns = threshold_sample_geometry(sk)
     common = dict(r=sk.r, c=sk.c, d=sk.d)
-    offsets = jnp.asarray(sk._offsets)
-    delta = jnp.asarray(sk._delta)
-    eps = jnp.asarray(sk._eps)
-    table = table.astype(jnp.float32)
+    vma, (offsets, delta, table, eps) = vary_together(
+        jnp.asarray(sk._offsets), jnp.asarray(sk._delta),
+        table.astype(jnp.float32), jnp.asarray(sk._eps))
 
     sample = pl.pallas_call(
         functools.partial(_sample_kernel, stride=stride, ns=ns,
@@ -299,7 +317,7 @@ def pallas_threshold_decode(sk, table: jax.Array, k: int) -> jax.Array:
             pl.BlockSpec((1, sk.c), lambda b, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((1, ns), lambda b, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, ns), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, ns), jnp.float32, vma=vma),
         scratch_shapes=[pltpu.VMEM((sk.r, sk.c), jnp.float32)],
         interpret=_interpret(),
     )(offsets, delta, table, eps)
@@ -322,7 +340,7 @@ def pallas_threshold_decode(sk, table: jax.Array, k: int) -> jax.Array:
             pl.BlockSpec((1, sk.c), lambda b, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((1, sk.c), lambda b, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, sk.c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, sk.c), jnp.float32, vma=vma),
         scratch_shapes=[pltpu.VMEM((sk.r, sk.c), jnp.float32)],
         interpret=_interpret(),
     )(offsets, delta, thr.reshape(1), table, eps)

@@ -128,9 +128,8 @@ class CSVec:
     # while "pallas" routes encode / estimate_all / the threshold
     # decode through the fused kernels in ops/kernels/sketch_pallas
     # (interpret-mode off TPU, so CPU tests run the kernel bodies).
-    # Geometries past the kernels' VMEM gate (pallas_fits) fall back
-    # to the XLA route per method — static per geometry, so a given
-    # CSVec takes ONE route everywhere. The hash/gather paths
+    # A geometry past the kernels' VMEM gate (pallas_fits) raises:
+    # the route is the one asked for or none. The hash/gather paths
     # (estimate, encode_sparse) have no kernel: they are the
     # scatter/gather formulation the kernels exist to avoid.
     backend: str = "xla"
@@ -160,11 +159,23 @@ class CSVec:
 
     def _pallas(self, kind: str) -> bool:
         """Whether `kind` ('encode' | 'estimate') runs on the fused
-        Pallas kernel for this sketch (backend field + VMEM gate)."""
+        Pallas kernel for this sketch: the backend field decides. A
+        geometry the kernel's VMEM gate refuses raises — the XLA route
+        is never taken in silence for a method that was asked to run
+        on Pallas."""
         if self.backend != "pallas":
             return False
-        from commefficient_tpu.ops.kernels import pallas_fits
-        return pallas_fits(self, kind)
+        from commefficient_tpu.ops.kernels.sketch_pallas import (
+            PALLAS_VMEM_BUDGET, pallas_fits, pallas_vmem_bytes,
+        )
+        if not pallas_fits(self, kind):
+            raise ValueError(
+                f"kernel_backend='pallas': the {kind} kernel does not "
+                f"fit at d={self.d}, r={self.r}, c={self.c}: "
+                f"{pallas_vmem_bytes(self, kind)} bytes of VMEM against "
+                f"a budget of {PALLAS_VMEM_BUDGET}; use "
+                f"kernel_backend='xla' or a narrower table")
+        return True
 
     @property
     def table_shape(self) -> Tuple[int, int]:

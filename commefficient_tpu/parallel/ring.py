@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from commefficient_tpu.ops.attention import NEG_INF, online_softmax_fold
-from commefficient_tpu.parallel import compat
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -44,7 +43,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Call INSIDE shard_map/psum context where `axis_name` is manual.
     """
     B, H, Lc, Dh = q.shape
-    n = compat.axis_size(axis_name)    # static under shard_map
+    n = jax.lax.axis_size(axis_name)   # static under shard_map
     my = jax.lax.axis_index(axis_name)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(Dh)
 

@@ -32,7 +32,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from commefficient_tpu.analysis.domains import MODEL_AXIS
-from commefficient_tpu.parallel import compat
 
 # (path regex, spec) — first match wins; unmatched leaves replicate.
 # Paths are "/"-joined pytree key paths, e.g.
@@ -66,8 +65,8 @@ def constrain_params(params, mesh: Mesh,
     # the engine's partially-manual shard_map the clients axis is
     # Manual (and params arrive clients-varying via pcast), which the
     # concrete mesh — all-Auto axis types — cannot describe
-    am = compat.abstract_mesh()
-    target = am if am is not None and MODEL_AXIS in am.axis_names else mesh
+    am = jax.sharding.get_abstract_mesh()
+    target = am if MODEL_AXIS in am.axis_names else mesh
 
     def constrain(path, leaf):
         s = _path_str(path)

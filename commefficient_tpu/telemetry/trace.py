@@ -53,7 +53,7 @@ with the correlation tags:
     q       queue depth observed at enqueue (writer back-pressure
             gauge; summarize() surfaces the max per writer)
 
-The stage taxonomy (README "Tracing" has the full table): plan,
+The stage names (README "Tracing" has the full table): plan,
 plan_install, stage, gather, round_dispatch, scatter, dispatch,
 device_execute, collect, tier_spill, tier_restore, checkpoint,
 journal_write, plus the per-writer {journal,checkpoint,state-spill}

@@ -1,28 +1,40 @@
 """Persistent XLA compilation cache.
 
-BENCH_r02 paid 75 s compiling the 10-round scanned program; every
-driver restart and every (W, B, span) shape change pays again. JAX
-ships a disk-backed executable cache but leaves it OFF by default
-(`jax_compilation_cache_dir = None` in this image) — enabling it makes
-recompiles across process restarts a cache hit. Drivers and benches
-call this before building any jitted program.
+Compiling the flagship round programs takes minutes on a cold chip
+(PERF.md section 6: 322 s for the per-round set, 130 s scanned, 2-3 s
+from a warm cache), and every driver restart and every (W, B, span)
+shape change pays it again. JAX ships a disk-backed executable cache but leaves it off
+unless it is given a directory. Drivers and benches call this before
+building any jitted program.
+
+Where the cache lives: `JAX_COMPILATION_CACHE_DIR`, when the
+environment sets it — JAX reads that variable itself, so nothing is
+set in code. Otherwise `.jax_cache/` at the root of the checkout,
+resolved from this file's own location: the path is part of the
+cache's key, so it is the same whatever the working directory.
 """
 from __future__ import annotations
 
 import os
 
+# <checkout>/.jax_cache (listed in .gitignore)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 
 def enable_persistent_compilation_cache(path: str | None = None) -> str:
-    """Point JAX's persistent compilation cache at `path` (default
-    ~/.cache/commefficient_tpu/xla). Safe to call more than once."""
+    """Turn the persistent compilation cache on and return its
+    directory. An explicit `path` wins (tests); otherwise see the
+    module docstring. Safe to call more than once."""
     import jax
 
-    path = path or os.environ.get(
-        "COMMEFFICIENT_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     "commefficient_tpu", "xla"))
+    if path is None and os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        path = jax.config.jax_compilation_cache_dir
+    else:
+        path = path or DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
     # cache everything that took noticeable compile time; entry-size
     # floor stays 0 so the scanned round programs always qualify
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)

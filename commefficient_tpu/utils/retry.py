@@ -38,7 +38,7 @@ T = TypeVar("T")
 
 # lowercase substrings that mark an error message as transient — the
 # gRPC status names and socket-level strings the TPU coordination
-# service and PJRT tunnel surface during neighbor restarts
+# service and PJRT surface during neighbor restarts
 _TRANSIENT_MARKERS = (
     "deadline_exceeded",
     "deadline exceeded",

@@ -39,6 +39,55 @@ def test_pallas_kernel_matches_reference_interpret():
                                rtol=1e-5, atol=1e-5)
 
 
+def _kernel(q, k, v):
+    return A._flash_fwd_pallas(q, k, v, 1.0 / math.sqrt(q.shape[-1]),
+                               128, 128, interpret=True)
+
+
+def test_pallas_kernel_lse_layout_is_the_one_mosaic_accepts():
+    """lse leaves the kernel as [B*H, L, 1] in (1, block_q, 1) blocks
+    (a (1, block_q) block of [B*H, L] is refused by the TPU lowering;
+    tests/test_tpu_compile.py asks the compiler) and reaches the
+    caller as [B, H, L], numbers unchanged."""
+    q, k, v = qkv(L=256, Dh=64)
+    jaxpr = jax.make_jaxpr(_kernel)(q, k, v)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert [o.aval.shape for o in call.outvars] == [(4, 256, 64),
+                                                    (4, 256, 1)]
+    o, lse = _kernel(q, k, v)
+    assert lse.shape == (2, 2, 256)
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(A.reference_attention(q, k, v)),
+        rtol=2e-5, atol=2e-6)
+
+
+def test_pallas_kernel_inside_shard_map(mesh):
+    """The context the chip compiles and the CPU route never reaches:
+    the round's shard_map over `clients`. Under check_vma the kernel's
+    outputs must carry the operands' varying axes (a bare out_shape is
+    rejected), also when some operands are replicated. jax 0.9.0's
+    Pallas interpreter cannot EVALUATE there (it binds the kernel's
+    primitives with program ids that vary over nothing), so the typed
+    trace is checked with the check on and the numbers with it off."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    q, k, v = qkv(B=mesh.shape["clients"], L=256, Dh=64)
+    for in_specs in (P("clients"), (P("clients"), P(), P())):
+        mapped = shard_map(_kernel, mesh=mesh, in_specs=in_specs,
+                           out_specs=P("clients"))
+        if in_specs != P("clients"):
+            k, v = k[:1], v[:1]
+        o, lse = jax.eval_shape(mapped, q, k, v)
+        assert o.shape == q.shape and lse.shape == q.shape[:3]
+    q, k, v = qkv(B=mesh.shape["clients"], L=256, Dh=64)
+    o, _ = shard_map(_kernel, mesh=mesh, in_specs=P("clients"),
+                     out_specs=P("clients"), check_vma=False)(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(A.reference_attention(q, k, v)),
+        rtol=2e-5, atol=2e-6)
+
+
 def test_grad_matches_reference():
     q, k, v = qkv(L=128, Dh=16)
 

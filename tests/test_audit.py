@@ -148,8 +148,7 @@ def test_au001_host_callback_fires():
 
 
 def test_au002_f64_fires():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64).sum())(
             jnp.ones(4, jnp.float32))

@@ -273,20 +273,12 @@ def test_real_format_pickle_archive_feeds_real_reader(tmp_path):
     # a cifar-10-batches-py archive in the genuine on-disk format (5
     # data_batch pickles of CHW uint8 rows + test_batch) must load
     # through the REAL pickle reader — no synthetic_examples passed, so
-    # the fallback is unreachable (benchmarks/real_format_data.py runs
-    # this same path at the full 50k geometry)
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "real_format_data",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmarks",
-            "real_format_data.py"))
-    rfd = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rfd)
+    # the fallback is unreachable (chip_smoke.py runs this same path
+    # at the full 50k geometry)
+    from commefficient_tpu.data.cifar import write_cifar10_archive
 
     root = str(tmp_path)
-    rfd.write_cifar10_archive(root, n_per_batch=40)
+    write_cifar10_archive(root, n_per_batch=40)
     ds = FedCIFAR10(root, train=True)  # raises if the pickle path fails
     assert int(ds.data_per_client.sum()) == 200  # 5 x 40
     assert ds.num_val_images == 40

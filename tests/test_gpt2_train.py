@@ -4,17 +4,9 @@ gpt2 driver previously had no in-suite smoke and no resume path)."""
 import glob
 import os
 
-import jax
 import pytest
 
 from commefficient_tpu.training import gpt2_train
-
-# legacy jax (no top-level jax.shard_map): the (clients, model) TP mesh
-# compiles its eval program through experimental partial-auto
-# shard_map, which hangs XLA — see parallel/compat.py
-_needs_modern_tp = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="legacy jax hangs compiling the TP eval program")
 
 
 def run_main(tmp_path, *extra):
@@ -99,7 +91,6 @@ def test_smoke_scan_rounds(tmp_path):
                     "--scan_span", "2")
 
 
-@_needs_modern_tp
 def test_smoke_tensor_parallel(tmp_path):
     """--model_parallel 2 runs the same driver on a (clients, model)
     mesh (4x2 on the 8-device CPU test mesh)."""
@@ -107,7 +98,6 @@ def test_smoke_tensor_parallel(tmp_path):
                     "--model_parallel", "2")
 
 
-@_needs_modern_tp
 def test_smoke_tensor_parallel_multislice(tmp_path):
     """--model_parallel 2 --num_slices 2: TP on the slice-major
     (emulated DCN) clients layout (parallel/mesh.py)."""

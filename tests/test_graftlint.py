@@ -502,7 +502,7 @@ def test_gl010_shard_map_mesh_argument_not_scanned():
     names and must not false-positive; the axis_names KWARG is the
     sink."""
     src = """
-        from commefficient_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         def wire(f, registry, specs):
             return shard_map(f, registry.lookup("emu2"), *specs)

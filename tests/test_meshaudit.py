@@ -120,7 +120,7 @@ def test_collective_cost_hierarchical_ring_math():
     mesh = make_client_mesh(8)
     table = jnp.zeros((3, 256), jnp.float32)
 
-    from commefficient_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     fn = shard_map(lambda t: jax.lax.psum(t, "clients"), mesh=mesh,
@@ -165,7 +165,7 @@ def test_au007_replicated_client_rows_fire():
 def test_au008_population_length_psum_fires():
     """A psum whose payload carries the population sentinel — wire
     cost scaling with num_clients — fires AU008."""
-    from commefficient_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_client_mesh(8)

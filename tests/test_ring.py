@@ -23,7 +23,7 @@ def make_ring_fn(mesh):
     def shard_fn(q, k, v):
         return ring_attention(q, k, v, axis_name="seq")
 
-    from commefficient_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     # sequence axis (dim 2) sharded over the mesh
     return jax.jit(shard_map(

@@ -179,7 +179,7 @@ def test_sketch_jits_and_psum_linearity(mesh):
     fed_worker.py:138)."""
     from jax.sharding import PartitionSpec as P
 
-    from commefficient_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     s = CSVec(d=256, c=64, r=3, num_blocks=2)
     n = len(jax.devices())

@@ -80,11 +80,6 @@ def test_tp_round_matches_dp_round():
     assert float(jnp.abs(tp.ps_weights).sum()) > 0
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="legacy jax: partial-auto shard_map + GSPMD model axis "
-           "hangs XLA compile on the eval program (train compiles; "
-           "see parallel/compat.py)")
 def test_tp_eval_matches_dp_eval():
     if len(jax.devices()) < 8:
         pytest.skip("needs the 8-device CPU mesh")
