@@ -14,10 +14,12 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from commefficient_tpu.telemetry.trace import TRACE
 from commefficient_tpu.utils.atomic_io import atomic_write_text
 
 
@@ -38,6 +40,7 @@ class FedDataset:
         self.dataset_dir = dataset_dir
         self.dataset_name = dataset_name
         self.transform = transform
+        self.transform_s = 0.0
         self.do_iid = do_iid
         self._num_clients = num_clients
         self.train = train
@@ -162,7 +165,14 @@ class FedDataset:
         within = flat - self._nat_cumsum[nat]
         batch = self._gather_train(nat, within)
         if self.transform is not None:
-            batch = self.transform(*batch)
+            if TRACE.enabled:
+                # seconds inside the transform, for FedLoader's
+                # `load_fetch` span (summed only while tracing)
+                t0 = time.monotonic()
+                batch = self.transform(*batch)
+                self.transform_s += time.monotonic() - t0
+            else:
+                batch = self.transform(*batch)
         return batch
 
     def get_val_batch(self, idxs: np.ndarray) -> Tuple[np.ndarray, ...]:

@@ -15,6 +15,7 @@ import numpy as np
 
 from commefficient_tpu.data.fed_dataset import FedDataset
 from commefficient_tpu.data.sampler import FedSampler, RoundIndices, ValSampler
+from commefficient_tpu.telemetry.trace import TRACE
 
 
 class FedLoader:
@@ -51,10 +52,33 @@ class FedLoader:
         O(1)-per-round mid-epoch resume fast-forward (the sampler's RNG
         state still advances identically to a full epoch)."""
         B = self.sampler.round_batch_size
-        for r in self.sampler.epoch():
-            if skip > 0:
-                skip -= 1
-                continue
+        rounds = self.sampler.epoch()
+        seq = 0
+        while True:
+            # graftscope: `load` is what one batch cost (`seq`: batches
+            # yielded before it), made of `load_sample` (the sampler's
+            # index math; an epoch's first holds its permutations),
+            # `load_fetch` (the per-client fetch-and-transform loop)
+            # and `load_assemble` (buffer allocation and copies). Every
+            # span closes before the yield, so none holds the
+            # consumer's time; an epoch's last `load` holds only the
+            # `load_sample` that found the epoch over.
+            with TRACE.span("load", seq=seq):
+                with TRACE.span("load_sample"):
+                    r = next(rounds, None)
+                    while r is not None and skip > 0:
+                        skip -= 1
+                        r = next(rounds, None)
+                batch = None if r is None else self._assemble(r, B)
+            if batch is None:
+                return
+            seq += 1
+            yield batch
+
+    def _assemble(self, r: RoundIndices, B: int):
+        """One round's (client_ids, data, mask) from its indices."""
+        with TRACE.span("load_fetch") as fetch:
+            transform_s0 = self.dataset.transform_s
             W = len(r.client_ids)
             rows = (range(W) if self.feed_slice is None
                     else range(*self.feed_slice.indices(W)))
@@ -74,6 +98,9 @@ class FedLoader:
                     int(r.client_ids[w]), r.idx_within[w, :n_valid])
                     if n_valid else None)
                 per_client.append((n_valid, got))
+            fetch.tag(clients=len(rows), transform_s=round(
+                self.dataset.transform_s - transform_s0, 6))
+        with TRACE.span("load_assemble") as assemble:
             # allocate static [W_local, B, ...] buffers from the first
             # real fetch (slot 0 is always active in single-controller
             # runs — the scheduler selects at least one participant)
@@ -95,7 +122,8 @@ class FedLoader:
                     buf[i, :n_valid] = g
             mask = (r.mask if self.feed_slice is None
                     else r.mask[self.feed_slice])
-            yield r.client_ids, data, mask
+            assemble.tag(bytes=sum(buf.nbytes for buf in data))
+        return r.client_ids, data, mask
 
 
 class FedValLoader:

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from commefficient_tpu.config import Config
+from commefficient_tpu.scopes import scope
 
 DEQUE_MAXLEN_MULT = 10  # (reference fed_aggregator.py:21)
 
@@ -77,14 +78,15 @@ def pack_change_bits(update: jax.Array) -> jax.Array:
     (D/32 elements) remains."""
     d = update.shape[0]
     n_words = -(-d // 32)
-    bits = jnp.not_equal(update, 0.0)
-    bits = jnp.pad(bits, (0, n_words * 32 - d))
-    halves = bits.reshape(n_words, 2, 16).astype(jnp.float32)
-    w16 = jnp.asarray(2.0, jnp.float32) ** jnp.arange(16)
-    packed = halves @ w16                                 # [n_words, 2]
-    lo = packed[:, 0].astype(jnp.uint32)
-    hi = packed[:, 1].astype(jnp.uint32)
-    return lo | (hi << jnp.uint32(16))
+    with scope("pack_change_bits"):
+        bits = jnp.not_equal(update, 0.0)
+        bits = jnp.pad(bits, (0, n_words * 32 - d))
+        halves = bits.reshape(n_words, 2, 16).astype(jnp.float32)
+        w16 = jnp.asarray(2.0, jnp.float32) ** jnp.arange(16)
+        packed = halves @ w16                             # [n_words, 2]
+        lo = packed[:, 0].astype(jnp.uint32)
+        hi = packed[:, 1].astype(jnp.uint32)
+        return lo | (hi << jnp.uint32(16))
 
 
 def _popcount(words: np.ndarray) -> int:

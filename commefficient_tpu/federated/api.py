@@ -1405,11 +1405,15 @@ class FedModel:
             if metrics.contributors is not None:
                 surv_bill = np.asarray(
                     jax.device_get(metrics.contributors), np.float32)
+            prev_words = self._prev_change_words
+            if prev_words is not None:
+                # the lagged read: ready when asked for unless the
+                # device is a round behind, and then the host waits
+                # here
+                with TRACE.span("device_wait"):
+                    prev_words = np.asarray(prev_words)
             download, upload = self.accountant.record_round(
-                staged.client_ids,
-                None if self._prev_change_words is None
-                else np.asarray(self._prev_change_words),
-                survivors=surv_bill)
+                staged.client_ids, prev_words, survivors=surv_bill)
         self._prev_change_words = bits
         n_screened = None
         if metrics.admitted is not None and staged.survivors is not None:
@@ -1497,7 +1501,10 @@ class FedModel:
         carry ONLY this process's rows (FedLoader feed_slice →
         multihost.local_row_slice): per-process batch feeding — no host
         materializes the global batch."""
-        return self.commit_staged(self.stage_round(batch))
+        # graftscope: the parent of this round's plan, stage,
+        # tier_motion, dispatch and collect spans, which inherit its id
+        with TRACE.span("round", round=self._rounds_staged):
+            return self.commit_staged(self.stage_round(batch))
 
     def run_rounds(self, client_ids, data, mask, lrs, account: bool = True):
         """Run N federated rounds as ONE device program (scanned; see

@@ -27,6 +27,7 @@ import numpy as np
 from commefficient_tpu import compress
 from commefficient_tpu.config import Config
 from commefficient_tpu.ops.flat import clip_to_l2, dp_noise, global_norm_clip
+from commefficient_tpu.scopes import scope
 
 # loss_fn contract (the workload callback, analogous to the reference's
 # compute_loss(model, batch, args) -> (loss, *metrics) at
@@ -134,6 +135,29 @@ def forward_grad(flat_grad_fn, weights: jax.Array, batch, mask: jax.Array,
     loss-only callable returning (loss, metrics) — see
     make_flat_loss_fn — so the traced program has no backward pass.
     """
+    with scope("fwdbwd"):
+        grad, loss, metrics, total = _mean_grad(
+            flat_grad_fn, weights, batch, mask, cfg, key, compute_grad,
+            grad_mask)
+    if not compute_grad:
+        return None, loss, metrics, total
+
+    # per-mode compression (reference fed_worker.py:311-335), delegated
+    # to the mode's Compressor plugin (ISSUE 19): the sketch-like
+    # plugins encode the [r, c] table here; dense plugins pass the
+    # gradient through untouched (sparsification happens later —
+    # server for true_topk, the residual seam for local_topk/powersgd)
+    with scope("encode"):
+        g = compress.get_compressor(cfg.mode).encode(cfg, grad, key)
+
+    return g, loss, metrics, total
+
+
+def _mean_grad(flat_grad_fn, weights, batch, mask, cfg: Config, key,
+               compute_grad: bool, grad_mask):
+    """forward_grad up to the compression seam: the masked-mean
+    gradient with frozen-coordinate masking, clipping, weight decay
+    and worker-side DP folded in (None without compute_grad)."""
     B = mask.shape[0]
     n_mb, mb = _microbatch_shape(B, cfg.microbatch_size)
     mbatch, mmask = _reshape_microbatches(batch, mask, n_mb, mb)
@@ -213,14 +237,7 @@ def forward_grad(flat_grad_fn, weights: jax.Array, batch, mask: jax.Array,
         if grad_mask is not None:
             grad = grad * grad_mask  # DP noise lands only on live coords
 
-    # per-mode compression (reference fed_worker.py:311-335), delegated
-    # to the mode's Compressor plugin (ISSUE 19): the sketch-like
-    # plugins encode the [r, c] table here; dense plugins pass the
-    # gradient through untouched (sparsification happens later —
-    # server for true_topk, the residual seam for local_topk/powersgd)
-    g = compress.get_compressor(cfg.mode).encode(cfg, grad, key)
-
-    return g, loss, metrics, total
+    return grad, loss, metrics, total
 
 
 def fused_shard_grads(flat_loss_fn, weights, batch, mask,
@@ -261,17 +278,18 @@ def fused_shard_grads(flat_loss_fn, weights, batch, mask,
         total = (losses * counts).sum()
         return total, (losses, metrics, counts)
 
-    (_, (losses, metrics, counts)), grad_sum = jax.value_and_grad(
-        objective, has_aux=True)(weights)
+    with scope("fwdbwd"):
+        (_, (losses, metrics, counts)), grad_sum = jax.value_and_grad(
+            objective, has_aux=True)(weights)
 
-    if grad_mask is not None:
-        grad_sum = grad_sum * grad_mask
-    if cfg.weight_decay != 0:
-        wd_term = (cfg.weight_decay / cfg.num_workers) * weights \
-            * counts.sum()
         if grad_mask is not None:
-            wd_term = wd_term * grad_mask
-        grad_sum = grad_sum + wd_term
+            grad_sum = grad_sum * grad_mask
+        if cfg.weight_decay != 0:
+            wd_term = (cfg.weight_decay / cfg.num_workers) * weights \
+                * counts.sum()
+            if grad_mask is not None:
+                wd_term = wd_term * grad_mask
+            grad_sum = grad_sum + wd_term
     return grad_sum, losses, metrics, counts
 
 
@@ -283,25 +301,27 @@ def local_step(flat_grad_fn, weights, batch, mask, error, velocity,
     g, loss, metrics, count = forward_grad(
         flat_grad_fn, weights, batch, mask, cfg, key, grad_mask=grad_mask)
 
-    # transmit sums over examples; server divides by the global batch
-    # size (reference fed_worker.py:190)
-    g = g * count
+    with scope("residual"):
+        # transmit sums over examples; server divides by the global
+        # batch size (reference fed_worker.py:190)
+        g = g * count
 
-    if cfg.local_momentum > 0:
-        velocity = g + cfg.local_momentum * velocity
+        if cfg.local_momentum > 0:
+            velocity = g + cfg.local_momentum * velocity
 
-    if cfg.error_type == "local":
-        error = error + (velocity if cfg.local_momentum > 0 else g)
-        to_transmit = error
-    else:
-        to_transmit = velocity if cfg.local_momentum > 0 else g
+        if cfg.error_type == "local":
+            error = error + (velocity if cfg.local_momentum > 0 else g)
+            to_transmit = error
+        else:
+            to_transmit = velocity if cfg.local_momentum > 0 else g
 
-    # residual seam (ISSUE 19): the plugin turns the accumulated
-    # quantity into the final wire payload plus new error/velocity
-    # carries — local_topk's sparsify-and-mask, powersgd's low-rank
-    # factorization, dp_sketch's sensitivity clip; identity elsewhere
-    to_transmit, error, velocity = compress.get_compressor(
-        cfg.mode).residual(cfg, to_transmit, error, velocity, key)
+        # residual seam (ISSUE 19): the plugin turns the accumulated
+        # quantity into the final wire payload plus new error/velocity
+        # carries — local_topk's sparsify-and-mask, powersgd's
+        # low-rank factorization, dp_sketch's sensitivity clip;
+        # identity elsewhere
+        to_transmit, error, velocity = compress.get_compressor(
+            cfg.mode).residual(cfg, to_transmit, error, velocity, key)
 
     return ClientResult(to_transmit, error, velocity, loss, metrics, count)
 
@@ -372,8 +392,9 @@ def fedavg_step(flat_grad_fn, weights, batch, mask, cfg: Config,
         return (w, step + 1.0), (loss, metrics, live)
 
     zero = jnp.zeros_like(mask, shape=())
-    (w_final, _), outs = jax.lax.scan(
-        body, (weights + zero, zero), (step_batch, step_mask))
+    with scope("fwdbwd"):
+        (w_final, _), outs = jax.lax.scan(
+            body, (weights + zero, zero), (step_batch, step_mask))
 
     if work is None:
         losses, metrics_seq = outs

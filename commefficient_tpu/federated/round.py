@@ -50,6 +50,7 @@ from commefficient_tpu.config import Config
 from commefficient_tpu.federated import client as fclient
 from commefficient_tpu.federated import server as fserver
 from commefficient_tpu.ops.flat import masked_topk
+from commefficient_tpu.scopes import scope
 from commefficient_tpu.telemetry import metrics as tmetrics
 from commefficient_tpu.telemetry.trace import TRACE
 
@@ -801,16 +802,17 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
                     # way to stay bit-identical under the deferred
                     # shard-sum encode below.)
                     txa = tx
-                    if cfg.defer_sketch_encode:
-                        txa = jax.vmap(
-                            fserver.args2sketch(cfg).encode)(txa)
-                    if (cfg.mode == "sketch"
-                            and cfg.sketch_table_dtype != "f32"):
-                        from commefficient_tpu.ops.kernels import (
-                            wire_roundtrip,
-                        )
-                        txa = wire_roundtrip(txa,
-                                             cfg.sketch_table_dtype)
+                    with scope("encode"):
+                        if cfg.defer_sketch_encode:
+                            txa = jax.vmap(
+                                fserver.args2sketch(cfg).encode)(txa)
+                        if (cfg.mode == "sketch"
+                                and cfg.sketch_table_dtype != "f32"):
+                            from commefficient_tpu.ops.kernels import (
+                                wire_roundtrip,
+                            )
+                            txa = wire_roundtrip(
+                                txa, cfg.sketch_table_dtype)
                     leaves_a, treedef_a = jax.tree.flatten(txa)
                     Wl = leaves_a[0].shape[0]
                     V = jnp.concatenate(
@@ -897,25 +899,29 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
                     # the local sum (NaN * 0 is NaN) — this is also
                     # what makes a screened client bit-identical to a
                     # dropped one
-                    local_sum = jax.tree.map(
-                        lambda t: jnp.where(
-                            surv_eff.reshape(
-                                surv_eff.shape
-                                + (1,) * (t.ndim - 1)) > 0,
-                            t, jnp.zeros_like(t)).sum(axis=0),
-                        tx)
+                    with scope("aggregate"):
+                        local_sum = jax.tree.map(
+                            lambda t: jnp.where(
+                                surv_eff.reshape(
+                                    surv_eff.shape
+                                    + (1,) * (t.ndim - 1)) > 0,
+                                t, jnp.zeros_like(t)).sum(axis=0),
+                            tx)
             elif surv is not None:
                 # zero dropped clients' uploads BEFORE the local sum —
                 # the psum'd aggregate and the divide-by-total see
                 # survivors only (survivor-count reweighting)
-                local_sum = jax.tree.map(
-                    lambda t: (t * surv.reshape(
-                        surv.shape + (1,) * (t.ndim - 1))).sum(axis=0),
-                    results.transmit)
-                counts = results.num_examples * surv
+                with scope("aggregate"):
+                    local_sum = jax.tree.map(
+                        lambda t: (t * surv.reshape(
+                            surv.shape
+                            + (1,) * (t.ndim - 1))).sum(axis=0),
+                        results.transmit)
+                    counts = results.num_examples * surv
             else:
-                local_sum = jax.tree.map(
-                    lambda t: t.sum(axis=0), results.transmit)
+                with scope("aggregate"):
+                    local_sum = jax.tree.map(
+                        lambda t: t.sum(axis=0), results.transmit)
                 counts = results.num_examples
             losses, metrics = results.loss, results.metrics
             new_err, new_vel = results.error, results.velocity
@@ -927,7 +933,8 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
             # identical value, so no psum is needed); `total` still
             # reports the admitted example mass for the round_step
             # alive gate and telemetry parity
-            total = jax.lax.psum(counts.sum(), "clients")
+            with scope("aggregate"):
+                total = jax.lax.psum(counts.sum(), "clients")
             out = (robust_tx, total, new_err, new_vel, new_w_rows,
                    losses, metrics, counts, admitted, contrib,
                    agg_stats)
@@ -938,7 +945,8 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
             # docstring). The psum below then moves the [r, c] table —
             # upload compression on the wire, exactly like the
             # reference's NCCL reduce of sketch tables.
-            local_sum = fserver.args2sketch(cfg).encode(local_sum)
+            with scope("encode"):
+                local_sum = fserver.args2sketch(cfg).encode(local_sum)
         if cfg.mode == "sketch" and cfg.sketch_table_dtype != "f32":
             # quantized sketch transport (--sketch_table_dtype): the
             # shard's client-sum table rides the wire at bf16/int8 —
@@ -951,10 +959,12 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
             # (ops/kernels/quant.py); the accountant bills the wire
             # bytes (Config.upload_bytes).
             from commefficient_tpu.ops.kernels import wire_roundtrip
-            local_sum = wire_roundtrip(local_sum,
-                                       cfg.sketch_table_dtype)
-        transmit = jax.lax.psum(local_sum, "clients")
-        total = jax.lax.psum(counts.sum(), "clients")
+            with scope("encode"):
+                local_sum = wire_roundtrip(local_sum,
+                                           cfg.sketch_table_dtype)
+        with scope("aggregate"):
+            transmit = jax.lax.psum(local_sum, "clients")
+            total = jax.lax.psum(counts.sum(), "clients")
         out = (transmit, total, new_err, new_vel, new_w_rows,
                losses, metrics, counts)
         if pois is not None:
@@ -1065,13 +1075,15 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
         rejects one buffer donated twice) that keep the shard_map
         operand count static; they are never read."""
         W = ids.shape[0]
-        return CohortState(
-            errors=(clients.errors[ids] if _has_errors(cfg)
-                    else jnp.zeros((W,))),
-            velocities=(clients.velocities[ids] if _has_velocities(cfg)
+        with scope("gather_cohort"):
+            return CohortState(
+                errors=(clients.errors[ids] if _has_errors(cfg)
                         else jnp.zeros((W,))),
-            weights=(clients.weights[ids] if cfg.do_topk_down
-                     else jnp.zeros((W,))))
+                velocities=(clients.velocities[ids]
+                            if _has_velocities(cfg)
+                            else jnp.zeros((W,))),
+                weights=(clients.weights[ids] if cfg.do_topk_down
+                         else jnp.zeros((W,))))
 
     def scatter_back(clients: ClientState, ids,
                      cohort: CohortState) -> ClientState:
@@ -1081,16 +1093,19 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
         back), so this is an unconditional per-slot write; untracked
         placeholder fields pass through."""
         new_clients = clients
-        if _has_errors(cfg):
-            new_clients = new_clients._replace(
-                errors=new_clients.errors.at[ids].set(cohort.errors))
-        if _has_velocities(cfg):
-            new_clients = new_clients._replace(
-                velocities=new_clients.velocities.at[ids].set(
-                    cohort.velocities))
-        if cfg.do_topk_down:
-            new_clients = new_clients._replace(
-                weights=new_clients.weights.at[ids].set(cohort.weights))
+        with scope("scatter_back"):
+            if _has_errors(cfg):
+                new_clients = new_clients._replace(
+                    errors=new_clients.errors.at[ids].set(
+                        cohort.errors))
+            if _has_velocities(cfg):
+                new_clients = new_clients._replace(
+                    velocities=new_clients.velocities.at[ids].set(
+                        cohort.velocities))
+            if cfg.do_topk_down:
+                new_clients = new_clients._replace(
+                    weights=new_clients.weights.at[ids].set(
+                        cohort.weights))
         return new_clients
 
     # ---------------- full train round ----------------------------------
@@ -1193,11 +1208,12 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
         # calibrated Gaussian noise here, on the "dp" domain of the
         # round key; the identity (zero traced ops) for every other
         # plugin, so default programs are byte-unchanged
-        transmit = comp.post_aggregate(cfg, transmit, round_key)
-        if cfg.robust_aggregation and pois is not None:
-            gradient = transmit
-        else:
-            gradient = transmit / jnp.maximum(total, 1.0)
+        with scope("aggregate"):
+            transmit = comp.post_aggregate(cfg, transmit, round_key)
+            if cfg.robust_aggregation and pois is not None:
+                gradient = transmit
+            else:
+                gradient = transmit / jnp.maximum(total, 1.0)
 
         # server aggregation + decompression
         upd = fserver.get_server_update(
@@ -1205,51 +1221,52 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
             key=jax.random.fold_in(round_key, num_workers),
             alive=alive)
 
-        if alive is None:
-            new_ps = server.ps_weights - upd.update
-        else:
-            # `where` (not `- 0.0`) so a dead round is bit-exact
-            new_ps = jnp.where(alive, server.ps_weights - upd.update,
-                               server.ps_weights)
-        # round_idx advances even on a zero-survivor round: it indexes
-        # the PRNG stream (round_key above), and a frozen index would
-        # replay the identical dropout draw forever
-        new_server = ServerState(new_ps, upd.Vvelocity, upd.Verror,
-                                 server.round_idx + 1)
+        with scope("server_state"):
+            if alive is None:
+                new_ps = server.ps_weights - upd.update
+            else:
+                # `where` (not `- 0.0`) so a dead round is bit-exact
+                new_ps = jnp.where(alive, server.ps_weights - upd.update,
+                                   server.ps_weights)
+            # round_idx advances even on a zero-survivor round: it indexes
+            # the PRNG stream (round_key above), and a frozen index would
+            # replay the identical dropout draw forever
+            new_server = ServerState(new_ps, upd.Vvelocity, upd.Verror,
+                                     server.round_idx + 1)
 
-        # merge the updated participant rows with the gathered ones: a
-        # dropped client's rows come through as their GATHERED values,
-        # i.e. the scatter-back lands them bit-untouched (its error
-        # feedback simply waits for the next round it completes). The
-        # merged CohortState is this program's carried row output —
-        # the scatter-back state-motion program writes it into the
-        # population blocks after dispatch.
-        # the EFFECTIVE mask: host survivors x device admission —
-        # identical to surv outside the screened family, so the three
-        # default programs trace byte-identically
-        eff = admitted if admitted is not None else surv
-        keep = None if eff is None else eff[:, None] > 0
-        new_cohort = cohort
-        if _has_errors(cfg):
-            if keep is not None:
-                new_err = jnp.where(keep, new_err, err_rows)
-            new_cohort = new_cohort._replace(errors=new_err)
-        if _has_velocities(cfg):
-            if upd.velocity_mask is not None:
-                # true_topk momentum factor masking (fixes ref D6)
-                new_vel = new_vel * upd.velocity_mask[None, :]
-            if keep is not None:
-                new_vel = jnp.where(keep, new_vel, vel_rows)
-            new_cohort = new_cohort._replace(velocities=new_vel)
-        if cfg.do_topk_down:
-            # persist each participant's post-download weights so its
-            # staleness is tracked (the reference computes but never
-            # stores these — deliberate fix, see module docstring);
-            # a dropped client never received the download, so its
-            # stale-weight row is kept too
-            if keep is not None:
-                new_w = jnp.where(keep, new_w, w_rows)
-            new_cohort = new_cohort._replace(weights=new_w)
+            # merge the updated participant rows with the gathered ones: a
+            # dropped client's rows come through as their GATHERED values,
+            # i.e. the scatter-back lands them bit-untouched (its error
+            # feedback simply waits for the next round it completes). The
+            # merged CohortState is this program's carried row output —
+            # the scatter-back state-motion program writes it into the
+            # population blocks after dispatch.
+            # the EFFECTIVE mask: host survivors x device admission —
+            # identical to surv outside the screened family, so the three
+            # default programs trace byte-identically
+            eff = admitted if admitted is not None else surv
+            keep = None if eff is None else eff[:, None] > 0
+            new_cohort = cohort
+            if _has_errors(cfg):
+                if keep is not None:
+                    new_err = jnp.where(keep, new_err, err_rows)
+                new_cohort = new_cohort._replace(errors=new_err)
+            if _has_velocities(cfg):
+                if upd.velocity_mask is not None:
+                    # true_topk momentum factor masking (fixes ref D6)
+                    new_vel = new_vel * upd.velocity_mask[None, :]
+                if keep is not None:
+                    new_vel = jnp.where(keep, new_vel, vel_rows)
+                new_cohort = new_cohort._replace(velocities=new_vel)
+            if cfg.do_topk_down:
+                # persist each participant's post-download weights so its
+                # staleness is tracked (the reference computes but never
+                # stores these — deliberate fix, see module docstring);
+                # a dropped client never received the download, so its
+                # stale-weight row is kept too
+                if keep is not None:
+                    new_w = jnp.where(keep, new_w, w_rows)
+                new_cohort = new_cohort._replace(weights=new_w)
 
         # on-device telemetry (telemetry/metrics.py): pure observation
         # of values already computed — reads the applied delta and the
@@ -1257,12 +1274,13 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
         # outputs above are bit-identical with cfg.telemetry off (the
         # zero-size placeholder keeps the treedef stable per config)
         if cfg.telemetry:
-            tele = tmetrics.round_vector(
-                losses=losses, counts=counts,
-                delta=new_ps - server.ps_weights,
-                verror=upd.Verror, vvelocity=upd.Vvelocity,
-                survivors=(jnp.float32(num_workers) if eff is None
-                           else eff.sum()))
+            with scope("telemetry"):
+                tele = tmetrics.round_vector(
+                    losses=losses, counts=counts,
+                    delta=new_ps - server.ps_weights,
+                    verror=upd.Verror, vvelocity=upd.Vvelocity,
+                    survivors=(jnp.float32(num_workers) if eff is None
+                               else eff.sum()))
         else:
             tele = tmetrics.empty_vector()
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from commefficient_tpu.config import Config
 from commefficient_tpu.ops.flat import dp_noise, masked_topk
 from commefficient_tpu.ops.sketch import CSVec
+from commefficient_tpu.scopes import scope
 
 
 class ServerUpdate(NamedTuple):
@@ -78,61 +79,72 @@ def get_server_update(gradient: jax.Array, Vvelocity: jax.Array,
         cfg, gradient, Vvelocity, Verror, lr, key)
     if alive is None:
         return upd
-    return ServerUpdate(
-        update=jnp.where(alive, upd.update, jnp.zeros_like(upd.update)),
-        Vvelocity=jnp.where(alive, upd.Vvelocity, Vvelocity),
-        Verror=jnp.where(alive, upd.Verror, Verror),
-        # a dead round transmits nothing, so no client velocity
-        # coordinate may be factor-masked either
-        velocity_mask=(None if upd.velocity_mask is None
-                       else jnp.where(alive, upd.velocity_mask,
-                                      jnp.ones_like(upd.velocity_mask))),
-    )
+    with scope("server_state"):
+        return ServerUpdate(
+            update=jnp.where(alive, upd.update,
+                             jnp.zeros_like(upd.update)),
+            Vvelocity=jnp.where(alive, upd.Vvelocity, Vvelocity),
+            Verror=jnp.where(alive, upd.Verror, Verror),
+            # a dead round transmits nothing, so no client velocity
+            # coordinate may be factor-masked either
+            velocity_mask=(None if upd.velocity_mask is None
+                           else jnp.where(
+                               alive, upd.velocity_mask,
+                               jnp.ones_like(upd.velocity_mask))),
+        )
 
 
 def _fedavg(avg_update, Vvelocity, Verror, cfg: Config, lr, key) -> ServerUpdate:
     # (reference fed_aggregator.py:483-495) — lr is forced to 1 by the
     # optimizer for fedavg; clients already applied the real LR locally.
     rho = cfg.virtual_momentum
-    Vvelocity = avg_update + rho * Vvelocity
+    with scope("server_state"):
+        Vvelocity = avg_update + rho * Vvelocity
     return ServerUpdate(Vvelocity, Vvelocity, Verror, None)
 
 
 def _uncompressed(gradient, Vvelocity, Verror, cfg: Config, lr, key) -> ServerUpdate:
     # (reference fed_aggregator.py:497-509)
     rho = cfg.virtual_momentum
-    Vvelocity = gradient + rho * Vvelocity
-    grad = Vvelocity
-    if cfg.do_dp and cfg.dp_mode == "server":
-        grad = grad + dp_noise(key, grad.shape, cfg.noise_multiplier)
-    return ServerUpdate(grad * lr, Vvelocity, Verror, None)
+    with scope("server_state"):
+        Vvelocity = gradient + rho * Vvelocity
+        grad = Vvelocity
+        if cfg.do_dp and cfg.dp_mode == "server":
+            grad = grad + dp_noise(key, grad.shape, cfg.noise_multiplier)
+        return ServerUpdate(grad * lr, Vvelocity, Verror, None)
 
 
 def _true_topk(gradient, Vvelocity, Verror, cfg: Config, lr, key) -> ServerUpdate:
     # (reference fed_aggregator.py:511-542)
     rho = cfg.virtual_momentum
-    Vvelocity = gradient + rho * Vvelocity
-    Verror = Verror + Vvelocity
+    with scope("server_state"):
+        Vvelocity = gradient + rho * Vvelocity
+        Verror = Verror + Vvelocity
 
-    update = masked_topk(Verror, k=cfg.k)
-    not_sent = (update == 0).astype(Verror.dtype)
+    with scope("select"):
+        update = masked_topk(Verror, k=cfg.k)
 
-    # error feedback + momentum factor masking at transmitted coords
-    Verror = Verror * not_sent
-    Vvelocity = Vvelocity * not_sent
+    with scope("server_state"):
+        not_sent = (update == 0).astype(Verror.dtype)
 
-    # clients' local velocities are masked at the same coords; the
-    # round engine applies this to participating rows only.
-    vel_mask = not_sent if cfg.local_momentum > 0 else None
-    return ServerUpdate(update * lr, Vvelocity, Verror, vel_mask)
+        # error feedback + momentum factor masking at transmitted
+        # coords
+        Verror = Verror * not_sent
+        Vvelocity = Vvelocity * not_sent
+
+        # clients' local velocities are masked at the same coords; the
+        # round engine applies this to participating rows only.
+        vel_mask = not_sent if cfg.local_momentum > 0 else None
+        return ServerUpdate(update * lr, Vvelocity, Verror, vel_mask)
 
 
 def _local_topk(local_topk_grad, Vvelocity, Verror, cfg: Config, lr, key) -> ServerUpdate:
     # (reference fed_aggregator.py:544-566): virtual momentum over the
     # *already sparsified* summed gradient; no virtual error possible.
     rho = cfg.virtual_momentum
-    Vvelocity = local_topk_grad + rho * Vvelocity
-    return ServerUpdate(Vvelocity * lr, Vvelocity, Verror, None)
+    with scope("server_state"):
+        Vvelocity = local_topk_grad + rho * Vvelocity
+        return ServerUpdate(Vvelocity * lr, Vvelocity, Verror, None)
 
 
 def _sketched(sketched_grad, Vvelocity, Verror, cfg: Config, lr, key) -> ServerUpdate:
@@ -147,39 +159,46 @@ def _sketched(sketched_grad, Vvelocity, Verror, cfg: Config, lr, key) -> ServerU
     # (fed_worker.py:221-222 asserts it away — the server-side alias at
     # fed_aggregator.py:579-580 is unreachable there too, so there is
     # no local-error branch to carry).
-    Vvelocity = sketched_grad + rho * Vvelocity
-    if cfg.error_type == "virtual":
-        Verror = Verror + Vvelocity
-        decode_table = Verror
-    else:  # "none": decode straight from the momentum table.
-        # (the reference would unsketch an all-zero Verror here and
-        # silently produce a zero update — drift note D-class, not
-        # replicated)
-        decode_table = Vvelocity
+    with scope("server_state"):
+        Vvelocity = sketched_grad + rho * Vvelocity
+        if cfg.error_type == "virtual":
+            Verror = Verror + Vvelocity
+            decode_table = Verror
+        else:  # "none": decode straight from the momentum table.
+            # (the reference would unsketch an all-zero Verror here
+            # and silently produce a zero update — drift note D-class,
+            # not replicated)
+            decode_table = Vvelocity
 
-    if sketch._threshold_decode:
-        # large-d route: sampled-threshold heavy-hitter recovery (one
-        # mask, no big sort/gather/scatter — ops/sketch.py docs) and a
-        # contiguous dense re-encode
-        update = sketch.decode_topk_dense(decode_table, k=cfg.k)
-        sketched_update = sketch.encode(update)
-    else:
-        idx, vals = sketch.decode_topk_sparse(decode_table, k=cfg.k)
-        update = jnp.zeros(cfg.grad_size, jnp.float32).at[idx].set(
-            vals, mode="drop")
-        # encode_k_sparse picks the faster of the scatter-add /
-        # dense-rotation routes per geometry and backend (CSVec owns
-        # that heuristic)
-        sketched_update = sketch.encode_k_sparse(idx, vals, dense=update)
+    # large-d route: sampled-threshold heavy-hitter recovery (one
+    # mask, no big sort/gather/scatter — ops/sketch.py docs) and a
+    # contiguous dense re-encode; otherwise the sparse top-k
+    with scope("select"):
+        if sketch._threshold_decode:
+            update = sketch.decode_topk_dense(decode_table, k=cfg.k)
+        else:
+            idx, vals = sketch.decode_topk_sparse(decode_table, k=cfg.k)
 
-    # virtual error feedback: re-sketch the k-sparse update and zero
-    # the error/momentum tables wherever the re-sketch landed
-    # (reference fed_aggregator.py:593-611; note the reference
-    # deliberately zeroes rather than subtracts — subtracting diverges
-    # per its own comment at :596-599).
-    not_sent = (sketched_update == 0).astype(Vvelocity.dtype)
-    if cfg.error_type == "virtual":
-        Verror = Verror * not_sent
-    Vvelocity = Vvelocity * not_sent
+    with scope("server_state"):
+        if sketch._threshold_decode:
+            sketched_update = sketch.encode(update)
+        else:
+            update = jnp.zeros(cfg.grad_size, jnp.float32).at[idx].set(
+                vals, mode="drop")
+            # encode_k_sparse picks the faster of the scatter-add /
+            # dense-rotation routes per geometry and backend (CSVec
+            # owns that heuristic)
+            sketched_update = sketch.encode_k_sparse(idx, vals,
+                                                     dense=update)
 
-    return ServerUpdate(update * lr, Vvelocity, Verror, None)
+        # virtual error feedback: re-sketch the k-sparse update and
+        # zero the error/momentum tables wherever the re-sketch landed
+        # (reference fed_aggregator.py:593-611; note the reference
+        # deliberately zeroes rather than subtracts — subtracting
+        # diverges per its own comment at :596-599).
+        not_sent = (sketched_update == 0).astype(Vvelocity.dtype)
+        if cfg.error_type == "virtual":
+            Verror = Verror * not_sent
+        Vvelocity = Vvelocity * not_sent
+
+        return ServerUpdate(update * lr, Vvelocity, Verror, None)

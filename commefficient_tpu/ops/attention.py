@@ -158,6 +158,7 @@ def _flash_fwd_pallas(q, k, v, sm_scale, block_q, block_k,
     # array's, which a (1, block_q) block of [B*H, L] is not
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B * H, L // block_q, L // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, Dh), lambda b, i, j: (b, i, 0)),

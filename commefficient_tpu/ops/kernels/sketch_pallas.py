@@ -187,6 +187,7 @@ def pallas_encode(sk, vec: jax.Array) -> jax.Array:
     kernel = functools.partial(_encode_kernel, c=sk.c)
     return pl.pallas_call(
         kernel,
+        name="sketch_encode",
         grid=(sk.r, B),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),            # offsets
@@ -230,6 +231,7 @@ def pallas_estimate_all(sk, table: jax.Array) -> jax.Array:
     kernel = functools.partial(_estimate_kernel, r=sk.r, c=sk.c, d=sk.d)
     return pl.pallas_call(
         kernel,
+        name="sketch_estimate_all",
         grid=(B, sk.r),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),            # offsets
@@ -309,6 +311,7 @@ def pallas_threshold_decode(sk, table: jax.Array, k: int) -> jax.Array:
     sample = pl.pallas_call(
         functools.partial(_sample_kernel, stride=stride, ns=ns,
                           **common),
+        name="sketch_threshold_sample",
         grid=(B, sk.r),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -331,6 +334,7 @@ def pallas_threshold_decode(sk, table: jax.Array, k: int) -> jax.Array:
 
     masked = pl.pallas_call(
         functools.partial(_mask_kernel, **common),
+        name="sketch_threshold_mask",
         grid=(B, sk.r),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

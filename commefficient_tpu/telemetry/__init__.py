@@ -57,6 +57,14 @@ __all__ = [
 # adds no device work.
 WATCHED_METRICS = ("update_l2", "error_l2")
 
+# the least time between two `trace` journal writes of a session
+# whose rings are under half full (TelemetrySession._flush_trace).
+# A quarter of a second and not a whole one: what reads the journal
+# while the run goes on (the benchmark's `host_api_ms`, right after
+# its window) sees spans at most this old, and its own test window
+# is half a second long.
+TRACE_FLUSH_S = 0.25
+
 
 class NumericTripError(RuntimeError):
     """A watched telemetry metric went non-finite: value corruption
@@ -189,6 +197,7 @@ class TelemetrySession:
             TRACE.enable(controller=controller)
         self._materialize = materialize
         self._clock = clock
+        self._trace_flushed = clock()
         self._spans = parse_profile_spans(profile_spans)
         self._profile_dir = profile_dir
         self._profiling = False
@@ -229,15 +238,24 @@ class TelemetrySession:
         if self.journal is not None:
             self._safe_write(lambda: self.journal.event(kind, **fields))
 
-    def _flush_trace(self) -> None:
+    def _flush_trace(self, force: bool = False) -> None:
         """Drain the graftscope rings into ONE batched `trace` journal
-        event (span-boundary flush cadence: one append+fsync per
-        flush, not per span). Without a journal (non-coordinator
-        processes) the drain still runs so the rings stay bounded —
-        the spans are simply discarded, like every other
-        coordinator-only record."""
+        event. Called at every round and span boundary, but it writes
+        only when TRACE_FLUSH_S of monotonic time have passed since
+        the last write or a ring is half full: an append and fsync
+        per round sat on the dispatching thread, where every host
+        millisecond is a round millisecond. `force` (flush(), and
+        with it close()) writes whatever is buffered. Without a
+        journal (non-coordinator processes) the drain still runs so
+        the rings stay bounded — the spans are simply discarded, like
+        every other coordinator-only record."""
         if not TRACE.enabled:
             return
+        now = self._clock()
+        if (not force and now - self._trace_flushed < TRACE_FLUSH_S
+                and TRACE.fill() < 0.5):
+            return
+        self._trace_flushed = now
         spans, dropped = TRACE.drain()
         if not spans and not dropped:
             return
@@ -314,14 +332,17 @@ class TelemetrySession:
 
     def _emit_round(self, rec, seconds: Optional[float]) -> None:
         round_idx, ids, vec, counts, _, comm, scheduled = rec
-        counts_h = np.asarray(self._materialize(counts))
+        # the host blocks here when the buffered round has not
+        # finished on the device: time this work waited for it
+        with TRACE.span("device_wait", of=round_idx):
+            counts_h = np.asarray(self._materialize(counts))
+            vec_h = (None if vec is None else np.asarray(
+                self._materialize(vec), np.float32))
         if (self.tracker is not None and seconds is not None
                 and seconds > 0):
             self.tracker.update_round(ids, counts_h, seconds,
                                       scheduled=scheduled)
-        named = tmetrics.named(
-            None if vec is None else np.asarray(
-                self._materialize(vec), np.float32))
+        named = tmetrics.named(vec_h)
         if self.journal is not None:
             fields = {"round": round_idx}
             if named:
@@ -376,7 +397,7 @@ class TelemetrySession:
         prev, self._pending = self._pending, None
         if prev is not None:
             self._emit_round(prev, None)
-        self._flush_trace()
+        self._flush_trace(force=True)
         if self.journal is not None:
             self._safe_write(self.journal.flush)
 

@@ -29,9 +29,17 @@ Design constraints, in order:
     monotonic trace time back onto wall time for export.
   * BEST-EFFORT, BOUNDED. Spans buffer in per-thread rings (bounded;
     overflow drops-and-counts, never blocks) and flush as batched
-    `trace` journal events at span boundaries — one fsync per flush,
-    torn-tail rules intact, I/O failures warn-once like all
-    telemetry (TelemetrySession._safe_write).
+    `trace` journal events at span boundaries, at most four a second
+    unless a ring is half full (TelemetrySession._flush_trace; close
+    forces the rest out) — one fsync per flush, torn-tail rules
+    intact, I/O failures warn-once like all telemetry
+    (TelemetrySession._safe_write).
+  * ONE CLOCK WITH THE PROFILER. While enabled, every `span()` also
+    holds open a `jax.profiler.TraceAnnotation` named `fed:<stage>`
+    carrying the span's `round`, so a profiler capture shows the
+    program's own spans beside the device ops on the profiler's
+    clock. `record()` and `instant()` have no interval of their own
+    to bracket and stay journal-only.
 
 Span records are small dicts:
 
@@ -53,10 +61,11 @@ with the correlation tags:
     q       queue depth observed at enqueue (writer back-pressure
             gauge; summarize() surfaces the max per writer)
 
-The stage names (README "Tracing" has the full table): plan,
+The stage names (README "Tracing" has the full table): round, plan,
 plan_install, stage, gather, round_dispatch, scatter, dispatch,
-device_execute, collect, tier_spill, tier_restore, checkpoint,
-journal_write, plus the per-writer {journal,checkpoint,state-spill}
+device_execute, collect, device_wait, load, load_sample, load_fetch,
+load_assemble, tier_spill, tier_restore, checkpoint, journal_write,
+plus the per-writer {journal,checkpoint,state-spill}
 _enqueue/_qwait/_write families.
 
 Nested spans inherit their enclosing span's `round`/`span` tags
@@ -83,6 +92,8 @@ __all__ = ["TRACE", "Tracer", "device_busy_wall", "overlap_efficiency",
 # tags inherited by nested spans / instants from the innermost open
 # span on the same thread (correlation keys, not payload)
 _INHERITED_TAGS = ("round", "span")
+# a span's name in a profiler capture: `fed:<stage>`
+ANNOTATION_PREFIX = "fed:"
 
 
 class _NullSpan:
@@ -97,6 +108,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def tag(self, **tags) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -104,22 +118,40 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One open span: context manager that commits its record on exit.
     Pushed on the owning thread's open-span stack so nested spans and
-    instants inherit its correlation tags."""
+    instants inherit its correlation tags. It also holds open a
+    `jax.profiler.TraceAnnotation` named `fed:<stage>` with the span's
+    `round` tag, so a profiler capture shows every program span on the
+    profiler's own clock beside the device ops (a TraceAnnotation
+    outside a capture records nothing)."""
 
-    __slots__ = ("_tracer", "rec", "_stack")
+    __slots__ = ("_tracer", "rec", "_stack", "_annotation")
 
     def __init__(self, tracer: "Tracer", rec: dict, stack: list):
         self._tracer = tracer
         self.rec = rec
         self._stack = stack
+        self._annotation = None
 
     def __enter__(self):
+        make = self._tracer._annotation
+        if make is not None:
+            rec = self.rec
+            tags = {"round": rec["round"]} if "round" in rec else {}
+            self._annotation = make(ANNOTATION_PREFIX + rec["name"], **tags)
+            self._annotation.__enter__()
         self.rec["t0"] = self._tracer._clock()
         self._stack.append(self.rec)
         return self
 
+    def tag(self, **tags) -> None:
+        """Payload learned while the span is open (a byte count, a
+        sub-total); lands on its record."""
+        self.rec.update(tags)
+
     def __exit__(self, *exc):
         t1 = self._tracer._clock()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         stack = self._stack
         if stack and stack[-1] is self.rec:
             stack.pop()
@@ -142,10 +174,13 @@ class Tracer:
     def __init__(self, enabled: bool = False, controller: int = 0,
                  ring_size: int = 4096,
                  clock: Callable[[], float] = time.monotonic):
-        self.enabled = bool(enabled)
+        self.enabled = False
         self.controller = int(controller)
         self.ring_size = int(ring_size)
         self._clock = clock
+        # jax.profiler.TraceAnnotation while enabled (imported at
+        # enable(), so a disabled tracer never touches the profiler)
+        self._annotation = None
         self._lock = threading.Lock()
         # thread ident -> list of committed span records (the ring)
         self._rings: Dict[int, List[dict]] = {}
@@ -153,6 +188,8 @@ class Tracer:
         # per-thread stack of OPEN span records (tag inheritance);
         # thread-local so no lock is needed on the span enter/exit path
         self._open = threading.local()
+        if enabled:
+            self.enable()
 
     # ---------------- recording ------------------------------------------
     def _thread_stack(self) -> list:
@@ -252,12 +289,24 @@ class Tracer:
     def enable(self, controller: Optional[int] = None) -> None:
         if controller is not None:
             self.controller = int(controller)
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.enabled = True
+
+    def fill(self) -> float:
+        """The fullest ring's share of `ring_size` (the flush cadence
+        writes early once a ring is half full)."""
+        with self._lock:
+            longest = max((len(r) for r in self._rings.values()),
+                          default=0)
+        return longest / max(self.ring_size, 1)
 
     def disable(self) -> None:
         """Turn tracing off and discard anything buffered (the session
         drains before disabling on a clean close)."""
         self.enabled = False
+        self._annotation = None
         with self._lock:
             self._rings.clear()
             self._dropped = 0

@@ -106,3 +106,61 @@ def test_default_dir_is_fixed_under_the_checkout(tmp_path):
     paths = {_run_script(env, cwd, script)["path"]
              for cwd in (REPO_ROOT, str(other))}
     assert paths == {os.path.join(REPO_ROOT, ".jax_cache")}
+
+
+KEY_SCRIPT = textwrap.dedent("""
+    import os, re, sys
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    sys.path.insert(0, os.environ["REPO_ROOT"])
+    sys.path.insert(0, os.environ["PROG_DIR"])
+    from commefficient_tpu.utils.cache import (
+        enable_persistent_compilation_cache,
+    )
+    enable_persistent_compilation_cache(os.environ["CACHE_DIR"])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    import jax.numpy as jnp
+    import prog
+    hits = []
+    jax.monitoring.register_event_listener(
+        lambda name, **kw: hits.append(name)
+        if name == "/jax/compilation_cache/cache_hits" else None)
+    x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+    text = prog.f.lower(x).compile().as_text()
+    print(f"hits={len(hits)}")
+    print("scoped=%d" % sum(
+        "fed_" in n for n in re.findall(r'op_name="([^"]*)"', text)))
+""")
+
+PROG = textwrap.dedent("""
+    import jax, jax.numpy as jnp
+    from commefficient_tpu.scopes import scope
+
+    @jax.jit
+    def f(x):
+        with scope("SCOPE"):
+            return jnp.tanh(x @ x).sum()
+""")
+
+
+def test_cache_key_holds_the_layer_names(tmp_path):
+    """The layer scopes live in op metadata and a device trace reads
+    them back, so the cache is keyed with it: the same program under
+    another scope name compiles anew (JAX's default key strips names
+    and would hand back the old executable with the old names), the
+    same program again hits, and the compiled ops carry the scope in
+    their `op_name` with the cache's settings on."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "REPO_ROOT": REPO_ROOT,
+           "CACHE_DIR": str(tmp_path / "xla"), "PROG_DIR": str(tmp_path)}
+
+    def run(scope_name):
+        (tmp_path / "prog.py").write_text(
+            PROG.replace("SCOPE", scope_name))
+        vals = _run_script(env, str(tmp_path), KEY_SCRIPT)
+        assert int(vals["scoped"]) > 0
+        return int(vals["hits"])
+
+    assert run("encode") == 0            # cold
+    assert run("encode") == 1            # the same program: hit
+    assert run("select") == 0            # renamed layer: miss
+    assert run("select") == 1

@@ -209,19 +209,19 @@ def test_nu004_promise_in_bounds_scatter_fires():
 # aggregation) and its NaN-unsafe PR-16-class rewrite; textual swap so
 # the fixture rots loudly if the shipped idiom is refactored
 _SHIPPED_WHERE = """\
-                    local_sum = jax.tree.map(
-                        lambda t: jnp.where(
-                            surv_eff.reshape(
-                                surv_eff.shape
-                                + (1,) * (t.ndim - 1)) > 0,
-                            t, jnp.zeros_like(t)).sum(axis=0),
-                        tx)"""
+                        local_sum = jax.tree.map(
+                            lambda t: jnp.where(
+                                surv_eff.reshape(
+                                    surv_eff.shape
+                                    + (1,) * (t.ndim - 1)) > 0,
+                                t, jnp.zeros_like(t)).sum(axis=0),
+                            tx)"""
 _MASK_MUL = """\
-                    local_sum = jax.tree.map(
-                        lambda t: (t * (surv_eff.reshape(
-                            surv_eff.shape
-                            + (1,) * (t.ndim - 1)) > 0)).sum(axis=0),
-                        tx)"""
+                        local_sum = jax.tree.map(
+                            lambda t: (t * (surv_eff.reshape(
+                                surv_eff.shape
+                                + (1,) * (t.ndim - 1)) > 0)).sum(axis=0),
+                            tx)"""
 
 _RED_DRIVER = """\
 import json

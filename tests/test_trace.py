@@ -449,3 +449,252 @@ def test_trace_export_empty_journal_fails_loud(tmp_path):
     RunJournal(p).event("run_start")
     te = _load_script("trace_export")
     assert te.main([p, "-o", str(tmp_path / "o.json")]) == 1
+
+
+# ---------------- ISSUE 27: spans on the profiler's clock ------------------
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records what was
+    constructed and that each was entered and left once."""
+    made = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+        self.entered = self.left = 0
+        _FakeAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.left += 1
+        return False
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    import jax.profiler
+    _FakeAnnotation.made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation
+
+
+def test_span_opens_trace_annotation_only_while_enabled(fake_annotation):
+    tr = Tracer(enabled=False)
+    with tr.span("dispatch", round=3):
+        pass
+    tr.record("device_execute", 0.0, 1.0)
+    tr.instant("mark")
+    # disabled: the class is never constructed (nor looked up)
+    assert fake_annotation.made == [] and tr._annotation is None
+    tr.enable()
+    with tr.span("dispatch", round=3):
+        with tr.span("gather"):
+            pass
+    with tr.span("load"):
+        pass
+    # record() and instant() stay journal-only
+    tr.record("device_execute", 0.0, 1.0, round=3)
+    tr.instant("mark", round=3)
+    made = fake_annotation.made
+    assert [(a.name, a.kwargs) for a in made] == [
+        ("fed:dispatch", {"round": 3}),
+        ("fed:gather", {"round": 3}),     # the inherited tag
+        ("fed:load", {})]
+    assert all(a.entered == 1 and a.left == 1 for a in made)
+    tr.disable()
+    with tr.span("dispatch", round=4):
+        pass
+    assert len(fake_annotation.made) == 3
+
+
+def test_trace_module_does_not_import_the_profiler_at_import():
+    import subprocess
+    import sys
+    code = ("import sys, importlib.util as u; "
+            "s = u.spec_from_file_location('t', sys.argv[1]); "
+            "m = u.module_from_spec(s); s.loader.exec_module(m); "
+            "m.Tracer(enabled=False).span('x'); "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    import commefficient_tpu.telemetry.trace as tmod
+    proc = subprocess.run([sys.executable, "-c", code, tmod.__file__],
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def _traced_model(tmp_path, name="j.jsonl", **session_kw):
+    model, _ = _fed_model()
+    jpath = str(tmp_path / name)
+    sess = TelemetrySession(journal=RunJournal(jpath), trace=True,
+                            **session_kw)
+    model.attach_telemetry(sess)
+    return model, sess, jpath
+
+
+def _journal_spans(jpath):
+    records, problems = validate_journal(jpath)
+    assert problems == []
+    return [s for r in records if r["event"] == "trace"
+            for s in r["spans"]]
+
+
+def test_round_span_parents_the_stages(tmp_path):
+    model, sess, jpath = _traced_model(tmp_path)
+    for batch in _rounds(3):
+        model(batch)
+    sess.close()
+    spans = _journal_spans(jpath)
+    rounds = [s for s in spans if s["name"] == "round"]
+    assert [s["round"] for s in rounds] == [0, 1, 2]
+    for parent in rounds:
+        lo, hi = parent["t0"], parent["t0"] + parent["dur"]
+        for stage in ("plan", "stage", "dispatch", "collect"):
+            (child,) = [s for s in spans if s["name"] == stage
+                        and s["round"] == parent["round"]]
+            # rounded to the microsecond on both sides
+            assert lo - 2e-6 <= child["t0"]
+            assert child["t0"] + child["dur"] <= hi + 2e-6
+    # tier_motion, the fifth stage, runs only with a tiered store; the
+    # host dispatch spans nested under `dispatch` inherit the id too
+    assert {s["round"] for s in spans
+            if s["name"] == "round_dispatch"} == {0, 1, 2}
+
+
+def test_device_wait_in_collect_and_in_emit_round(tmp_path):
+    model, sess, jpath = _traced_model(tmp_path)
+    for batch in _rounds(3):
+        model(batch)
+    sess.close()
+    spans = _journal_spans(jpath)
+    waits = [s for s in spans if s["name"] == "device_wait"]
+    collects = {s["round"]: s for s in spans if s["name"] == "collect"}
+    in_collect = [w for w in waits if "of" not in w]
+    in_emit = [w for w in waits if "of" in w]
+    # the lagged change-bit read: rounds 1 and 2 read the round before
+    assert [w["round"] for w in in_collect] == [1, 2]
+    for w in in_collect:
+        c = collects[w["round"]]
+        assert c["t0"] - 2e-6 <= w["t0"]
+        assert w["t0"] + w["dur"] <= c["t0"] + c["dur"] + 2e-6
+    # _emit_round materialises round r-1 inside round r's call, and
+    # the last round at close (under no round span)
+    assert [(w["of"], w.get("round")) for w in in_emit] == [
+        (0, 1), (1, 2), (2, None)]
+
+
+def test_loader_same_batches_and_load_spans_closed_before_yield(tmp_path):
+    from commefficient_tpu.data import FedCIFAR10, FedLoader
+    from commefficient_tpu.data.transforms import cifar10_transforms
+
+    def batches(trace_on):
+        ds = FedCIFAR10(str(tmp_path), synthetic_examples=(200, 20))
+        ds.transform = cifar10_transforms()[1]   # the deterministic one
+        loader = FedLoader(ds, num_workers=4, local_batch_size=8, seed=7)
+        out, open_at_yield = [], []
+        if trace_on:
+            TRACE.enable()
+        for ids, data, mask in loader.epoch():
+            open_at_yield.append(list(TRACE._thread_stack()))
+            out.append((ids.copy(), [d.copy() for d in data],
+                        mask.copy()))
+        spans, _ = TRACE.drain()
+        TRACE.disable()
+        return out, spans, open_at_yield, ds
+
+    off, no_spans, _, _ = batches(False)
+    on, spans, open_at_yield, ds = batches(True)
+    assert no_spans == [] and len(off) == len(on) > 1
+    for (i0, d0, m0), (i1, d1, m1) in zip(off, on):
+        np.testing.assert_array_equal(i0, i1)
+        np.testing.assert_array_equal(m0, m1)
+        for a, b in zip(d0, d1):
+            np.testing.assert_array_equal(a, b)
+    # every span closes before the yield
+    assert all(stack == [] for stack in open_at_yield)
+    loads = [s for s in spans if s["name"] == "load"]
+    # one `load` a batch, then the one that found the epoch over
+    assert [s["seq"] for s in loads] == list(range(len(on) + 1))
+    for name in ("load_fetch", "load_assemble"):
+        assert len([s for s in spans if s["name"] == name]) == len(on)
+    assert len([s for s in spans
+                if s["name"] == "load_sample"]) == len(on) + 1
+    for parent in loads[:-1]:
+        lo, hi = parent["t0"], parent["t0"] + parent["dur"]
+        kids = [s for s in spans if s["name"].startswith("load_")
+                and lo - 2e-6 <= s["t0"] <= hi + 2e-6]
+        assert [k["name"] for k in kids] == [
+            "load_sample", "load_fetch", "load_assemble"]
+        assert sum(k["dur"] for k in kids) <= parent["dur"] + 5e-6
+    fetch = [s for s in spans if s["name"] == "load_fetch"]
+    assert all(s["clients"] == 4 and 0 < s["transform_s"] <= s["dur"]
+               + 1e-6 for s in fetch)
+    nbytes = sum(d.nbytes for d in on[0][1])
+    assert all(s["bytes"] == nbytes for s in spans
+               if s["name"] == "load_assemble")
+    assert ds.transform_s == pytest.approx(
+        sum(s["transform_s"] for s in fetch), abs=1e-5)
+
+
+def test_trace_flush_cadence_injected_clock(tmp_path):
+    """`_flush_trace` writes at most once per TRACE_FLUSH_S of the
+    session's monotonic clock, earlier once a ring is half full, and
+    close() writes what is left."""
+    from commefficient_tpu.telemetry import TRACE_FLUSH_S
+    t = [100.0]
+    jpath = str(tmp_path / "j.jsonl")
+    sess = TelemetrySession(journal=RunJournal(jpath), trace=True,
+                            clock=lambda: t[0])
+    TRACE.ring_size = 8
+
+    def trace_events():
+        if not os.path.isfile(jpath):
+            return []
+        with open(jpath) as f:
+            return [json.loads(line) for line in f
+                    if '"event": "trace"' in line]
+
+    try:
+        with TRACE.span("a"):
+            pass
+        sess._flush_trace()
+        assert trace_events() == []            # no time has passed
+        t[0] = 100.0 + 0.9 * TRACE_FLUSH_S
+        sess._flush_trace()
+        assert trace_events() == []
+        t[0] = 100.0 + TRACE_FLUSH_S
+        sess._flush_trace()                    # the interval has passed
+        assert [len(e["spans"]) for e in trace_events()] == [1]
+        for _ in range(3):
+            with TRACE.span("b"):
+                pass
+        sess._flush_trace()
+        assert len(trace_events()) == 1        # 3 of 8: under half
+        with TRACE.span("b"):
+            pass
+        sess._flush_trace()                    # 4 of 8: half full
+        assert [len(e["spans"]) for e in trace_events()] == [1, 4]
+        with TRACE.span("c"):
+            pass
+        sess._flush_trace()
+        assert len(trace_events()) == 2
+        sess.close()                           # forces the rest
+        assert [len(e["spans"]) for e in trace_events()] == [1, 4, 1]
+    finally:
+        TRACE.ring_size = 4096
+        sess.close()
+
+
+def test_per_round_trace_fsync_is_gone(tmp_path):
+    """Twenty traced rounds inside one flush interval of the session's
+    clock write no `trace` event until close."""
+    t = [5.0]
+    model, sess, jpath = _traced_model(tmp_path, clock=lambda: t[0])
+    for batch in _rounds(20):
+        model(batch)
+        t[0] += 0.01
+    with open(jpath) as f:
+        assert not any('"event": "trace"' in line for line in f)
+    sess.close()
+    assert len([s for s in _journal_spans(jpath)
+                if s["name"] == "round"]) == 20
