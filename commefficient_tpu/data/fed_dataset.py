@@ -12,6 +12,7 @@ round batches.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import time
@@ -147,33 +148,83 @@ class FedDataset:
         return self.num_val_images
 
     # ---- fetch ----------------------------------------------------------
-    def client_flat_indices(self, client_id: int,
-                            idx_within: np.ndarray) -> np.ndarray:
-        """Map (client, local index) to flat dataset indices."""
-        dpc_cumsum = np.concatenate([[0], np.cumsum(self.data_per_client)])
-        flat = dpc_cumsum[client_id] + idx_within
+    @functools.cached_property
+    def _client_offsets(self) -> np.ndarray:
+        return np.concatenate([[0], np.cumsum(self.data_per_client)])
+
+    def client_flat_indices(self, client_id, idx_within: np.ndarray
+                            ) -> np.ndarray:
+        """Map (client, local index) to flat dataset indices; one
+        client for all of `idx_within`, or one per index."""
+        flat = self._client_offsets[client_id] + idx_within
         if self.do_iid:
             flat = self.iid_shuffle[flat]
         return flat
 
-    def get_client_batch(self, client_id: int,
-                         idx_within: np.ndarray) -> Tuple[np.ndarray, ...]:
-        """Fetch one client's (transformed) examples by local index."""
+    def _fetch(self, client_id, idx_within: np.ndarray
+               ) -> Tuple[np.ndarray, ...]:
+        """Stored examples by (client, local index), in the order
+        asked, with one read per natural unit they fall in."""
         flat = self.client_flat_indices(client_id, np.asarray(idx_within))
         # flat index -> (natural client, index within natural client)
         nat = np.searchsorted(self._nat_cumsum, flat, side="right") - 1
-        within = flat - self._nat_cumsum[nat]
-        batch = self._gather_train(nat, within)
+        return self._gather_train(nat, flat - self._nat_cumsum[nat])
+
+    def _transformed(self, transform, *args):
+        if not TRACE.enabled:
+            return transform(*args)
+        # seconds inside the transform, for FedLoader's `load_fetch`
+        # span (summed only while tracing)
+        t0 = time.monotonic()
+        try:
+            return transform(*args)
+        finally:
+            self.transform_s += time.monotonic() - t0
+
+    def get_client_batch(self, client_id: int,
+                         idx_within: np.ndarray) -> Tuple[np.ndarray, ...]:
+        """Fetch one client's (transformed) examples by local index."""
+        batch = self._fetch(client_id, idx_within)
         if self.transform is not None:
-            if TRACE.enabled:
-                # seconds inside the transform, for FedLoader's
-                # `load_fetch` span (summed only while tracing)
-                t0 = time.monotonic()
-                batch = self.transform(*batch)
-                self.transform_s += time.monotonic() - t0
-            else:
-                batch = self.transform(*batch)
+            batch = self._transformed(self.transform, *batch)
         return batch
+
+    def example_protos(self) -> Tuple[np.ndarray, ...]:
+        """Zero-length arrays shaped and typed like a client's
+        transformed batch: what FedLoader sizes its rounds' buffers
+        from. A batch of no examples draws nothing from a transform's
+        random stream."""
+        batch = self._get_train_batch(0, np.zeros(0, np.int64))
+        if self.transform is not None:
+            batch = self.transform(*batch)
+        return tuple(batch)
+
+    def get_round_batch(self, client_ids: np.ndarray,
+                        idx_within: np.ndarray, n_valid: np.ndarray,
+                        out) -> bool:
+        """Fetch and transform a round's rows: row i is client
+        `client_ids[i]`'s examples `idx_within[i, :n_valid[i]]`,
+        written into the views `out[i]`, one per array of an example;
+        rows with no valid example are left alone. Where the transform
+        has a cohort form (`transform.cohort`, data/transforms.py) all
+        rows are fetched together and transformed in one call, and
+        the result is True; otherwise client by client through
+        `get_client_batch`, and False."""
+        active = np.flatnonzero(n_valid)
+        cohort = getattr(self.transform, "cohort", None)
+        if cohort is None:
+            for i in active:
+                got = self.get_client_batch(
+                    int(client_ids[i]), idx_within[i, :n_valid[i]])
+                for dst, g in zip(out[i], got):
+                    dst[...] = g
+            return False
+        valid = np.arange(idx_within.shape[1]) < n_valid[:, None]
+        batch = self._fetch(np.repeat(client_ids, n_valid),
+                            idx_within[valid])
+        self._transformed(cohort, *batch, n_valid[active],
+                          [out[i] for i in active])
+        return True
 
     def get_val_batch(self, idxs: np.ndarray) -> Tuple[np.ndarray, ...]:
         batch = self._get_val_batch(np.asarray(idxs))

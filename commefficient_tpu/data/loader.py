@@ -39,6 +39,7 @@ class FedLoader:
                                   local_batch_size, seed=seed,
                                   max_local_batch=max_local_batch)
         self.feed_slice = feed_slice
+        self._protos = None     # dataset.example_protos(), at first use
 
     @property
     def steps_per_epoch(self) -> int:
@@ -58,11 +59,12 @@ class FedLoader:
             # graftscope: `load` is what one batch cost (`seq`: batches
             # yielded before it), made of `load_sample` (the sampler's
             # index math; an epoch's first holds its permutations),
-            # `load_fetch` (the per-client fetch-and-transform loop)
-            # and `load_assemble` (buffer allocation and copies). Every
-            # span closes before the yield, so none holds the
-            # consumer's time; an epoch's last `load` holds only the
-            # `load_sample` that found the epoch over.
+            # `load_assemble` (the round's zeroed buffers) and
+            # `load_fetch` (the dataset's fetch and transform of the
+            # cohort, written into them). Every span closes before the
+            # yield, so none holds the consumer's time; an epoch's last
+            # `load` holds only the `load_sample` that found the epoch
+            # over.
             with TRACE.span("load", seq=seq):
                 with TRACE.span("load_sample"):
                     r = next(rounds, None)
@@ -77,52 +79,41 @@ class FedLoader:
 
     def _assemble(self, r: RoundIndices, B: int):
         """One round's (client_ids, data, mask) from its indices."""
+        rows = slice(None) if self.feed_slice is None else self.feed_slice
+        client_ids, idx_within, mask = (
+            r.client_ids[rows], r.idx_within[rows], r.mask[rows])
+        if len(client_ids) == 0:
+            raise NotImplementedError(
+                "this process owns no rows of the clients axis; "
+                "zero-row feeding is not supported — use a mesh "
+                "layout that gives every process client shards")
+        n_valid = mask.sum(axis=1).astype(np.int64)
+        if not n_valid.any():
+            raise NotImplementedError(
+                "every row this process feeds is an idle (zero-mask) "
+                "slot — scheduler over-provisioning is single-"
+                "controller only (Config.validate enforces this)")
+        with TRACE.span("load_assemble") as assemble:
+            # static [W_local, B, ...] buffers, written once by the
+            # fetch through a view per row. Idle slots (a scheduler
+            # that over-provisioned fewer than num_workers pads with
+            # zero-mask rows) fetch nothing: their rows stay zeros and
+            # the round engine sees them as survivor-0 dead slots
+            if self._protos is None:
+                self._protos = self.dataset.example_protos()
+            data = tuple(
+                np.zeros((len(client_ids), B) + p.shape[1:], p.dtype)
+                for p in self._protos)
+            out = [tuple(buf[i, :n] for buf in data)
+                   for i, n in enumerate(n_valid)]
+            assemble.tag(bytes=sum(buf.nbytes for buf in data))
         with TRACE.span("load_fetch") as fetch:
             transform_s0 = self.dataset.transform_s
-            W = len(r.client_ids)
-            rows = (range(W) if self.feed_slice is None
-                    else range(*self.feed_slice.indices(W)))
-            if len(rows) == 0:
-                raise NotImplementedError(
-                    "this process owns no rows of the clients axis; "
-                    "zero-row feeding is not supported — use a mesh "
-                    "layout that gives every process client shards")
-            per_client = []
-            for w in rows:
-                n_valid = int(r.mask[w].sum())
-                # idle slots (a scheduler that over-provisioned fewer
-                # than num_workers pads with zero-mask rows) fetch
-                # nothing: their buffer rows stay zeros and the round
-                # engine sees them as survivor-0 dead slots
-                got = (self.dataset.get_client_batch(
-                    int(r.client_ids[w]), r.idx_within[w, :n_valid])
-                    if n_valid else None)
-                per_client.append((n_valid, got))
-            fetch.tag(clients=len(rows), transform_s=round(
-                self.dataset.transform_s - transform_s0, 6))
-        with TRACE.span("load_assemble") as assemble:
-            # allocate static [W_local, B, ...] buffers from the first
-            # real fetch (slot 0 is always active in single-controller
-            # runs — the scheduler selects at least one participant)
-            protos = next((got for _, got in per_client
-                           if got is not None), None)
-            if protos is None:
-                raise NotImplementedError(
-                    "every row this process feeds is an idle "
-                    "(zero-mask) slot; feeding cannot derive batch "
-                    "shapes — scheduler over-provisioning is single-"
-                    "controller only (Config.validate enforces this)")
-            data = tuple(
-                np.zeros((len(rows), B) + p.shape[1:], p.dtype)
-                for p in protos)
-            for i, (n_valid, got) in enumerate(per_client):
-                if got is None:
-                    continue
-                for buf, g in zip(data, got):
-                    buf[i, :n_valid] = g
-            mask = (r.mask if self.feed_slice is None
-                    else r.mask[self.feed_slice])
-            assemble.tag(bytes=sum(buf.nbytes for buf in data))
+            cohort = self.dataset.get_round_batch(
+                client_ids, idx_within, n_valid, out)
+            fetch.tag(clients=len(client_ids), cohort=int(cohort),
+                      transform_s=round(
+                          self.dataset.transform_s - transform_s0, 6))
         return r.client_ids, data, mask
 
 

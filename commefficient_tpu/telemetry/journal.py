@@ -996,7 +996,10 @@ def summarize(records: List[dict], corrupt_lines: int = 0) -> dict:
     writer queue-depth gauges (`writer_queue_max`, from the enqueue
     spans' `q` tags), and the pipeline overlap-efficiency metric
     (`overlap_efficiency` = device-busy / wall over the
-    device_execute spans). Independently, round events carrying the
+    device_execute spans) and, where FedLoader's `load_fetch` spans
+    are among them, `loader_cohort_share` (the share of its rounds
+    fetched and transformed as one cohort, from their `cohort` tags).
+    Independently, round events carrying the
     `mono` timestamp yield the inter-round `cadence` block
     (p50/p95 + histogram) — deltas are taken on the MONOTONIC clock,
     reset at every run_start (each process has its own mono base,
@@ -1197,6 +1200,14 @@ def summarize(records: List[dict], corrupt_lines: int = 0) -> dict:
         # within-process interval); busy/wall sums PER segment.
         out["trace_spans"] = len(trace_spans)
         out["trace_stages"] = stage_stats(trace_spans)
+        fetches = [sp["cohort"] for sp in trace_spans
+                   if sp.get("name") == "load_fetch" and "cohort" in sp]
+        if fetches:
+            # beside the loader's stages: the share of FedLoader's
+            # rounds that went through the transform's cohort form
+            # (the rest were fetched and transformed client by client)
+            out["loader_cohort_share"] = round(
+                sum(fetches) / len(fetches), 4)
         busy = wall = 0.0
         for seg in trace_segments:
             bw = device_busy_wall(seg)

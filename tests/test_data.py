@@ -306,3 +306,153 @@ def test_synthetic_resize_invalidates_cache(tmp_path):
     after = os.path.getmtime(
         os.path.join(str(tmp_path), "CIFAR10", "stats.json"))
     assert before == after
+
+
+# ---- the cohort form against a call per client --------------------------
+# The per-client transforms as they stood before FedLoader fetched a
+# round at once, kept here as the reference: same draws in the same
+# order, same float32 operation per element.
+
+def _ref_normalize(images, mean, std):
+    x = (images.astype(np.float32) / 255.0 if images.dtype == np.uint8
+         else images.astype(np.float32))
+    return (x - mean) / std
+
+
+def _ref_random_crop(padded, h, w, pad, rng):
+    n = len(padded)
+    ys = rng.randint(0, 2 * pad + 1, size=n)
+    xs = rng.randint(0, 2 * pad + 1, size=n)
+    yy = ys[:, None] + np.arange(h)[None, :]
+    out = padded[np.arange(n)[:, None], yy]
+    xx = xs[:, None] + np.arange(w)[None, :]
+    return out[np.arange(n)[:, None, None],
+               np.arange(h)[None, :, None], xx[:, None, :]]
+
+
+def _ref_random_hflip(images, rng):
+    flip = rng.rand(images.shape[0]) < 0.5
+    out = images.copy()
+    out[flip] = out[flip, :, ::-1]
+    return out
+
+
+def _reference_train_transform(name, seed):
+    from commefficient_tpu.data import transforms as T
+    rng = np.random.RandomState(seed)
+
+    def margins(pad):
+        return ((0, 0), (pad, pad), (pad, pad), (0, 0))
+
+    def cifar(mean, std):
+        def train(images, labels):
+            _, h, w, _ = images.shape
+            x = _ref_random_crop(
+                np.pad(images, margins(4), mode="reflect"), h, w, 4, rng)
+            x = _ref_random_hflip(x, rng)
+            return _ref_normalize(x, mean, std), labels.astype(np.int32)
+        return train
+
+    def femnist(images, labels):
+        _, h, w, _ = images.shape
+        x = np.pad(images.astype(np.float32) / 255.0, margins(2),
+                   constant_values=1.0)
+        x = _ref_random_crop(x, h, w, 2, rng)
+        return (_ref_normalize(x, T.FEMNIST_MEAN, T.FEMNIST_STD),
+                labels.astype(np.int32))
+
+    def imagenet(images, labels):
+        x = _ref_random_hflip(images, rng)
+        return (_ref_normalize(x, T.IMAGENET_MEAN, T.IMAGENET_STD),
+                labels.astype(np.int32))
+
+    train = {"CIFAR10": cifar(T.CIFAR10_MEAN, T.CIFAR10_STD),
+             "CIFAR100": cifar(T.CIFAR100_MEAN, T.CIFAR100_STD),
+             "EMNIST": femnist, "ImageNet": imagenet}[name]
+    return train, rng
+
+
+def _cohort_dataset(name, root, **kw):
+    """Small corpora in which clients hold unequal numbers of examples
+    once each natural unit is split over two clients."""
+    from commefficient_tpu.data import FedEMNIST, FedImageNet
+    if name == "CIFAR10":
+        return FedCIFAR10(root, synthetic_examples=(300, 20), **kw), 10
+    if name == "CIFAR100":
+        return FedCIFAR100(root, synthetic_examples=(1500, 20), **kw), 100
+    if name == "EMNIST":
+        return FedEMNIST(root, synthetic_examples=(6, 7), seed=1,
+                         **kw), 6
+    return FedImageNet(root, synthetic_examples=(80, 8), image_size=16,
+                       **kw), 16
+
+
+class _OneSlotIdle:
+    """A scheduler that fills one slot fewer than it is given."""
+
+    def select(self, alive, num_workers, rng):
+        return rng.choice(alive, num_workers - 1, replace=False)
+
+    def commit_round(self, slot_ids, n_valid):
+        pass
+
+
+@pytest.mark.parametrize("variant", ["plain", "ragged_idle_slot",
+                                     "strided_feed_slice", "iid"])
+@pytest.mark.parametrize("name", ["CIFAR10", "CIFAR100", "EMNIST",
+                                  "ImageNet"])
+def test_loader_cohort_rounds_equal_per_client_calls(tmp_path, name,
+                                                     variant):
+    from commefficient_tpu.data.transforms import TRANSFORMS
+    seed, W = 1234 + len(name) + len(variant), 4
+    kw = ({"do_iid": True, "num_clients": 10} if variant == "iid" else {})
+    ds, units = _cohort_dataset(name, str(tmp_path), **kw)
+    ref, _ = _cohort_dataset(name, str(tmp_path), **kw)
+    if variant != "iid":
+        ds._num_clients = ref._num_clients = 2 * units
+    ds.transform = TRANSFORMS[name](seed)[0]
+    ref.transform, ref_rng = _reference_train_transform(name, seed)
+    # whole clients make every row ragged; otherwise batches of two
+    batch = -1 if variant == "ragged_idle_slot" else 2
+    feed = slice(1, W, 2) if variant == "strided_feed_slice" else None
+    loader = FedLoader(ds, W, batch, seed=seed, feed_slice=feed)
+    sampler = FedSampler(ref.data_per_client, W, batch, seed=seed)
+    if variant == "ragged_idle_slot":
+        loader.sampler.scheduler = _OneSlotIdle()
+        sampler.scheduler = _OneSlotIdle()
+    # the drivers draw one example through get_client_batch first
+    for a, b in zip(ds.get_client_batch(0, np.array([0])),
+                    ref.get_client_batch(0, np.array([0]))):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+    B = sampler.round_batch_size
+    rounds, seen = 0, set()
+    for (ids, data, mask), r in zip(loader.epoch(), sampler.epoch()):
+        rows = range(W)[feed] if feed is not None else range(W)
+        n_valid = [int(r.mask[w].sum()) for w in rows]
+        seen.update(n_valid)
+        got = [ref.get_client_batch(int(r.client_ids[w]),
+                                    r.idx_within[w, :n]) if n else None
+               for w, n in zip(rows, n_valid)]
+        protos = next(g for g in got if g is not None)
+        want = tuple(np.zeros((len(rows), B) + p.shape[1:], p.dtype)
+                     for p in protos)
+        for i, g in enumerate(got):
+            for buf, part in zip(want, g or ()):
+                buf[i, :len(part)] = part
+        np.testing.assert_array_equal(ids, r.client_ids)
+        assert ids.dtype == r.client_ids.dtype
+        np.testing.assert_array_equal(mask, r.mask[list(rows)])
+        assert mask.dtype == r.mask.dtype
+        assert len(data) == len(want)
+        for a, b in zip(data, want):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert np.array_equal(a, b)
+        rounds += 1
+        if rounds == 3:
+            break
+    assert rounds == 3
+    if variant == "ragged_idle_slot":
+        assert 0 in seen and len(seen) > 2
+    for a, b in zip(ds.transform.rng.get_state(), ref_rng.get_state()):
+        assert np.array_equal(a, b)

@@ -583,13 +583,18 @@ def test_device_wait_in_collect_and_in_emit_round(tmp_path):
         (0, 1), (1, 2), (2, None)]
 
 
-def test_loader_same_batches_and_load_spans_closed_before_yield(tmp_path):
+@pytest.mark.parametrize("cohort", [1, 0])
+def test_loader_same_batches_and_load_spans_closed_before_yield(
+        tmp_path, cohort):
     from commefficient_tpu.data import FedCIFAR10, FedLoader
     from commefficient_tpu.data.transforms import cifar10_transforms
+    from commefficient_tpu.telemetry.journal import summarize
 
     def batches(trace_on):
         ds = FedCIFAR10(str(tmp_path), synthetic_examples=(200, 20))
-        ds.transform = cifar10_transforms()[1]   # the deterministic one
+        # the train transform has a cohort form; the test one (the
+        # deterministic one) has none, so its rounds go client by client
+        ds.transform = cifar10_transforms()[0 if cohort else 1]
         loader = FedLoader(ds, num_workers=4, local_batch_size=8, seed=7)
         out, open_at_yield = [], []
         if trace_on:
@@ -623,17 +628,22 @@ def test_loader_same_batches_and_load_spans_closed_before_yield(tmp_path):
         lo, hi = parent["t0"], parent["t0"] + parent["dur"]
         kids = [s for s in spans if s["name"].startswith("load_")
                 and lo - 2e-6 <= s["t0"] <= hi + 2e-6]
+        # the buffers first, then the fetch that writes into them
         assert [k["name"] for k in kids] == [
-            "load_sample", "load_fetch", "load_assemble"]
+            "load_sample", "load_assemble", "load_fetch"]
         assert sum(k["dur"] for k in kids) <= parent["dur"] + 5e-6
     fetch = [s for s in spans if s["name"] == "load_fetch"]
     assert all(s["clients"] == 4 and 0 < s["transform_s"] <= s["dur"]
                + 1e-6 for s in fetch)
+    assert [s["cohort"] for s in fetch] == [cohort] * len(on)
     nbytes = sum(d.nbytes for d in on[0][1])
     assert all(s["bytes"] == nbytes for s in spans
                if s["name"] == "load_assemble")
     assert ds.transform_s == pytest.approx(
         sum(s["transform_s"] for s in fetch), abs=1e-5)
+    summary = summarize([{"event": "trace", "spans": spans}])
+    assert summary["loader_cohort_share"] == float(cohort)
+    assert summary["trace_stages"]["load_fetch"]["n"] == len(on)
 
 
 def test_trace_flush_cadence_injected_clock(tmp_path):
