@@ -1142,7 +1142,7 @@ class FedModel:
                     placed = mh.globalize(self.mesh, P(),
                                           data.astype(np.float32))
                     new = new._replace(
-                        **{name: field.at[gidx].set(placed)})
+                        **{name: field.set_rows(gidx, placed)})
                 self.clients = new
         elif ckpt.clients is not None:
             if self.state_store is not None:
@@ -1164,12 +1164,13 @@ class FedModel:
                 # from sparse saves.
                 specs = fround.client_state_specs(ckpt.clients)
                 # globalize_owned: these blocks enter the scatter/span
-                # donation chain (see the server fields above)
-                self.clients = fround.ClientState(*[
-                    mh.globalize_owned(self.mesh, spec,
-                                       np.asarray(field))
-                    for field, spec in zip(ckpt.clients, specs)])
-                if any(np.asarray(f).ndim == 2 for f in ckpt.clients):
+                # donation chain (see the server fields above); leaf
+                # by leaf, so a RowBlock's tiles land sharded as tiles
+                self.clients = jax.tree.map(
+                    lambda leaf, spec: mh.globalize_owned(
+                        self.mesh, spec, np.asarray(leaf)),
+                    ckpt.clients, specs)
+                if any(f.ndim == 2 for f in ckpt.clients):
                     self._sparse_rows_ok = False
         self._finish_load(ckpt)
         return ckpt.scheduler_step

@@ -14,17 +14,24 @@ over the `clients` mesh axis:
   * NCCL reduce           -> `lax.psum` of the compressed quantity
 
 Per-client persistent state (errors/velocities/stale weights,
-reference fed_aggregator.py:105-129) lives as [padded_population, ...]
-device arrays sharded `P('clients', None)` across hosts. Since ISSUE 9
+reference fed_aggregator.py:105-129) lives as RowBlocks: logically
+[padded_population, D], stored [padded_population, T, 128] with
+T = 8 * ceil(D / 1024) so that one client's row is a whole number of
+the chip's (8, 128) tiles (zero padding behind D; below D = 1,024
+whole lanes only, T = ceil(D / 128)), sharded
+`P('clients', None, None)` across hosts. Since ISSUE 9
 the participant-row motion happens OUTSIDE the jitted round: a
 dedicated cohort-GATHER program pulls the sampled rows into a
-[num_workers, ...] CohortState before dispatch, and a SCATTER-BACK
+[num_workers, D] CohortState before dispatch, and a SCATTER-BACK
 program writes the updated rows after — so the three traced round
 programs see only O(cohort) operands, never a population-shaped
 buffer (graftaudit AU004 now hard-errors on one), and device traffic
 per round is O(active) regardless of the population size. The
 gather/scatter pair (SURVEY.md hard part #3) are the only two
-programs allowed to touch the [population, D] blocks.
+programs allowed to touch the population blocks; they move each row
+as one contiguous piece and convert the cohort between the tile form
+and [num_workers, D] in one pass, so the round programs trace what
+they always did.
 
 True-top-k momentum factor masking of client velocities — broken in
 the reference via an unset global (SURVEY.md §7.4 D6) — is just data
@@ -33,12 +40,15 @@ applies it to the participating rows in the same jitted program.
 """
 from __future__ import annotations
 
+import dataclasses
+import operator
 from functools import partial
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import shard_map
+import numpy as np
+from jax import lax, shard_map
 from jax.lax import pcast
 # not exported by jax 0.9.0 (the one pinned in pyproject.toml): the
 # all_gather whose result is typed replicated, see its one use below
@@ -55,6 +65,142 @@ from commefficient_tpu.telemetry import metrics as tmetrics
 from commefficient_tpu.telemetry.trace import TRACE
 
 
+# ---------------------------------------------------------------------------
+# the storage format of the per-client blocks. A float32 [rows, D]
+# array is tiled (8, 128) over (client, coordinate) on the chip, so one
+# client's row is one sublane of D/128 tiles that also hold seven other
+# clients, and moving a row reads and writes eight. Stored
+# [rows, T, 128] the tiled dimensions are the row's own: a row is T/8
+# whole tiles in one piece. The two functions below are the whole
+# format; everything that reads or writes block rows goes through them
+# (or through RowBlock, which does), and the checkpoint on disk and
+# the host tier's tables stay [rows, D].
+
+LANES = 128
+_ROW_QUANTUM = 8 * LANES
+
+
+def lane_rows(D: int) -> int:
+    """T: how many 128-lane rows one client's D coordinates take:
+    whole (8, 128) tiles from D = 1,024 up. Below it whole lanes only:
+    such a row is under one tile wherever it lies (the chip pads T to
+    eight itself), and eight sublanes of zeros would be up to 64 times
+    the state of a tiny model with a large population."""
+    lanes = -(-int(D) // LANES)
+    return lanes if D < _ROW_QUANTUM else -(-lanes // 8) * 8
+
+
+def rows_to_tiles(rows):
+    """[..., D] -> [..., T, 128], zero padding behind D (numpy in,
+    numpy out; a jax array or tracer stays one)."""
+    xp = jnp if isinstance(rows, jax.Array) else np
+    D = rows.shape[-1]
+    T = lane_rows(D)
+    pad = [(0, 0)] * (rows.ndim - 1) + [(0, T * LANES - D)]
+    return xp.pad(rows, pad).reshape(rows.shape[:-1] + (T, LANES))
+
+
+def tiles_to_rows(tiles, D: int):
+    """[..., T, 128] -> [..., D]: the inverse; the padding is cut,
+    never read."""
+    return tiles.reshape(tiles.shape[:-2] + (-1,))[..., :D]
+
+
+def take_rows(tiles, ids, sharded: bool):
+    """[rows, T, 128] tiles, [W] ids -> those rows, [W, T, 128].
+    On one device a loop of W row copies (`dynamic_slice` out,
+    `dynamic_update_slice` in: a row is one contiguous run of whole
+    tiles, written in place), not XLA's `gather` op, which the chip's
+    compiler lowers to hundreds of strided pieces with gigabytes of
+    temporaries. The ids are distinct and in range (the sampler's
+    contract). A `fori_loop` and not W unrolled slices concatenated:
+    no compiler fuses a loop into what reads the cohort, so composed
+    into one program (`round_full`) the round's arithmetic is fused,
+    and rounded, as the round program alone fuses it.
+
+    `sharded`: the tiles lie across a clients mesh, the rows change
+    devices, and that is left to GSPMD's partitioning of the indexing
+    (each shard gathers the rows it holds, masked, and the cohort is
+    all-reduced): the same loop under `shard_map` with its own
+    reduction deadlocks the CPU runtime's eight virtual devices in
+    long unsynchronised loops (PERF.md, section 7). The branch goes
+    once the loop is shown on a real mesh (ROADMAP S3)."""
+    if sharded:
+        return tiles[ids]
+
+    def copy_row(i, out):
+        row = lax.dynamic_slice_in_dim(tiles, ids[i], 1, axis=0)
+        return lax.dynamic_update_slice_in_dim(out, row, i, axis=0)
+    return lax.fori_loop(
+        0, ids.shape[0], copy_row,
+        jnp.zeros((ids.shape[0],) + tiles.shape[1:], tiles.dtype))
+
+
+@partial(jax.jit, static_argnames=("D", "sharded"))
+def _rows_at(tiles, ids, D: int, sharded: bool):
+    # one program for the host's reads of a few rows (one compile,
+    # where the three eager steps would be three)
+    return tiles_to_rows(take_rows(tiles, ids, sharded), D)
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["tiles"], meta_fields=["D"])
+@dataclasses.dataclass(frozen=True)
+class RowBlock:
+    """One tracked per-client block: logically float32 [rows, D] (what
+    `shape`, indexing and `np.asarray` say), stored as `tiles`
+    [rows, T, 128] (see above). A pytree with the one leaf, so a
+    ClientState of RowBlocks is donated, sharded and scanned like the
+    arrays it replaced. Only the cohort-gather and scatter-back
+    programs read `tiles` on the device; the methods here are the
+    host's occasional access (checkpoints, the state tier, tests)."""
+    tiles: jax.Array             # [rows, T, 128]
+    D: int
+
+    @classmethod
+    def from_rows(cls, rows) -> "RowBlock":
+        return cls(rows_to_tiles(rows), int(rows.shape[-1]))
+
+    @property
+    def shape(self):
+        return (self.tiles.shape[0], self.D)
+
+    ndim = 2
+
+    @property
+    def dtype(self):
+        return self.tiles.dtype
+
+    def __getitem__(self, idx):
+        """Rows by a client id, a slice or a 1-D integer array of ids
+        -> [..., D]."""
+        if isinstance(idx, slice):
+            return tiles_to_rows(self.tiles[idx], self.D)
+        if np.ndim(idx) == 0:
+            return tiles_to_rows(self.tiles[operator.index(idx)], self.D)
+        ids = jnp.asarray(idx)
+        if ids.ndim != 1 or not jnp.issubdtype(ids.dtype, jnp.integer):
+            raise TypeError(
+                "a RowBlock is read by a client id, a slice or a 1-D "
+                f"integer array of ids, not {ids.dtype}{list(ids.shape)}")
+        return _rows_at(self.tiles, ids, self.D,
+                        len(self.tiles.sharding.device_set) > 1)
+
+    def set_rows(self, ids, rows) -> "RowBlock":
+        """A new block with `rows` ([len(ids), D]) written at `ids`."""
+        return RowBlock(
+            self.tiles.at[ids].set(rows_to_tiles(jnp.asarray(rows))),
+            self.D)
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.ascontiguousarray(
+            tiles_to_rows(np.asarray(self.tiles), self.D))
+        return out if dtype is None else out.astype(dtype)
+
+    def is_deleted(self) -> bool:
+        return self.tiles.is_deleted()
+
+
 class ServerState(NamedTuple):
     """All PS-side mutable state (reference globals g_ps_weights /
     FedOptimizer.Vvelocity / .Verror, fed_aggregator.py:37-44,408-409)."""
@@ -65,10 +211,12 @@ class ServerState(NamedTuple):
 
 
 class ClientState(NamedTuple):
-    """Per-client persistent state, [padded_population, ...] rows
-    (reference shared-memory arrays at fed_aggregator.py:105-129),
-    sharded over the mesh's clients axis (CLIENT_STATE_RULES). Fields
-    are zero-size placeholders when the config doesn't need them.
+    """Per-client persistent state (reference shared-memory arrays at
+    fed_aggregator.py:105-129): each tracked field a RowBlock,
+    logically [padded_population, D] and stored in whole tiles
+    [padded_population, T, 128], sharded over the mesh's clients axis
+    (CLIENT_STATE_RULES). Fields are zero-size plain-array
+    placeholders when the config doesn't need them.
 
     The jitted round NEVER takes this treedef as an operand: only the
     cohort-gather and scatter-back state-motion programs touch it
@@ -79,9 +227,10 @@ class ClientState(NamedTuple):
     indexed by LRU slot, not client id, and the cold tail lives on
     the host (federated/statestore.py; client_state_rows picks the
     allocation size)."""
-    errors: jax.Array            # [padded_population, D] or [0]
-    velocities: jax.Array        # [padded_population, D] or [0]
-    weights: jax.Array           # [padded_population, D] or [0]
+    # each a RowBlock [padded_population, D], or a plain [0]
+    errors: "RowBlock | jax.Array"
+    velocities: "RowBlock | jax.Array"
+    weights: "RowBlock | jax.Array"
 
 
 class CohortState(NamedTuple):
@@ -94,7 +243,9 @@ class CohortState(NamedTuple):
     (merged: dropped clients keep their gathered values) by the jitted
     round, written back by the scatter-back program. O(cohort) in every
     dimension — this treedef is what makes the round programs
-    population-free."""
+    population-free. Plain [num_workers, D] arrays: the blocks' tile
+    form ends inside the gather program and begins again inside the
+    scatter program."""
     errors: jax.Array            # [num_workers, D] or [num_workers]
     velocities: jax.Array        # [num_workers, D] or [num_workers]
     weights: jax.Array           # [num_workers, D] or [num_workers]
@@ -103,10 +254,10 @@ class CohortState(NamedTuple):
 # partition rules for the persistent client-state blocks — the
 # match_partition_rules pattern (SNIPPETS.md [1], parallel/multihost)
 # applied to the one treedef that matters at population scale: every
-# live [padded_population, D] row block shards over the clients axis,
-# placeholders/scalars replicate (the helper's ndim guard).
+# live RowBlock's [padded_population, T, 128] tiles shard over the
+# clients axis, placeholders/scalars replicate (no rule matches them).
 CLIENT_STATE_RULES = (
-    (r"\.(errors|velocities|weights)$", P("clients", None)),
+    (r"\.(errors|velocities|weights)\.tiles$", P("clients", None, None)),
 )
 
 
@@ -235,9 +386,9 @@ def init_server_state(cfg: Config, ps_weights: jax.Array,
 def init_client_state(cfg: Config, num_clients: int,
                       ps_weights: Optional[jax.Array] = None,
                       mesh: Optional[Mesh] = None) -> ClientState:
-    """Allocate per-client state rows (sharded over the mesh's clients
-    axis when a mesh is given, since at 17K+ clients these arrays are
-    the memory hazard — SURVEY.md §7.0).
+    """Allocate per-client state rows as RowBlocks (sharded over the
+    mesh's clients axis when a mesh is given, since at 17K+ clients
+    these arrays are the memory hazard — SURVEY.md §7.0).
 
     The row count is padded up to a multiple of the mesh axis so any
     num_clients shards (e.g. CIFAR's 10 natural clients on an 8-device
@@ -247,6 +398,7 @@ def init_client_state(cfg: Config, num_clients: int,
     D = cfg.grad_size
     n = mesh.shape["clients"] if mesh is not None else 1
     rows = -(-num_clients // n) * n
+    shape = (rows, lane_rows(D), LANES)
 
     if mesh is not None:
         from commefficient_tpu.parallel import multihost as mh
@@ -260,27 +412,31 @@ def init_client_state(cfg: Config, num_clients: int,
         def empty():
             return mh.zeros(mesh, P(), (0,))
 
-        def alloc(shape):
+        def alloc():
             # global sharded allocation: shard-local zeros only — in a
             # multi-controller run no host ever materializes the full
-            # [num_clients, D] block
-            return mh.zeros(mesh, P("clients", None), shape)
+            # block
+            return RowBlock(
+                mh.zeros(mesh, P("clients", None, None), shape), D)
     else:
         def empty():
             return jnp.zeros((0,), jnp.float32)
 
-        def alloc(shape):
-            return jnp.zeros(shape, jnp.float32)
+        def alloc():
+            return RowBlock(jnp.zeros(shape, jnp.float32), D)
 
-    errors = alloc((rows, D)) if _has_errors(cfg) else empty()
-    velocities = (alloc((rows, D)) if _has_velocities(cfg)
-                  else empty())
+    errors = alloc() if _has_errors(cfg) else empty()
+    velocities = alloc() if _has_velocities(cfg) else empty()
     if cfg.do_topk_down:
         assert ps_weights is not None
         if mesh is not None:
-            weights = mh.tile_rows(mesh, ps_weights, rows)
+            # host-side conversion of the one base row; the tile is
+            # materialized shard-locally
+            base = rows_to_tiles(np.asarray(ps_weights))
+            weights = RowBlock(mh.tile_rows(mesh, base, rows), D)
         else:
-            weights = jnp.broadcast_to(ps_weights, (rows, D)).copy()
+            weights = RowBlock(jnp.broadcast_to(
+                rows_to_tiles(ps_weights), shape).copy(), D)
     else:
         weights = empty()
     return ClientState(errors, velocities, weights)
@@ -1064,9 +1220,17 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
     # programs OUTSIDE the jitted round (module docstring): the round
     # programs therefore never see a population-shaped operand —
     # graftaudit AU004's hard-error contract — and the only programs
-    # touching the sharded [padded_population, D] blocks move exactly
-    # O(cohort) rows each. Both compile once per config and are cache
-    # hits on every later dispatch (tests pin the counts).
+    # touching the sharded RowBlocks move exactly O(cohort) rows each,
+    # every row as one contiguous run of whole tiles. Both compile
+    # once per config and are cache hits on every later dispatch
+    # (tests pin the counts).
+
+    def _gathered(block, ids):
+        # the cohort converts to [W, D] in one piece (a tile transpose
+        # and the cut of the padding), never row by row: a [W, D]
+        # buffer written a row at a time is sublane-strided again
+        return tiles_to_rows(
+            take_rows(block.tiles, ids, sharded=n_shards > 1), block.D)
 
     def gather_cohort(clients: ClientState, ids) -> CohortState:
         """Pull the sampled cohort's rows out of the sharded population
@@ -1077,35 +1241,36 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
         W = ids.shape[0]
         with scope("gather_cohort"):
             return CohortState(
-                errors=(clients.errors[ids] if _has_errors(cfg)
-                        else jnp.zeros((W,))),
-                velocities=(clients.velocities[ids]
+                errors=(_gathered(clients.errors, ids)
+                        if _has_errors(cfg) else jnp.zeros((W,))),
+                velocities=(_gathered(clients.velocities, ids)
                             if _has_velocities(cfg)
                             else jnp.zeros((W,))),
-                weights=(clients.weights[ids] if cfg.do_topk_down
-                         else jnp.zeros((W,))))
+                weights=(_gathered(clients.weights, ids)
+                         if cfg.do_topk_down else jnp.zeros((W,))))
 
     def scatter_back(clients: ClientState, ids,
                      cohort: CohortState) -> ClientState:
         """Write the round's merged cohort rows back into the sharded
-        population blocks. The rows already encode the dropout
-        contract (round_step merged dropped clients' gathered values
-        back), so this is an unconditional per-slot write; untracked
-        placeholder fields pass through."""
+        population blocks, in place on the donated block. The rows
+        already encode the dropout contract (round_step merged dropped
+        clients' gathered values back), so this is an unconditional
+        per-slot write of whole-tile rows; untracked placeholder
+        fields pass through."""
         new_clients = clients
         with scope("scatter_back"):
             if _has_errors(cfg):
                 new_clients = new_clients._replace(
-                    errors=new_clients.errors.at[ids].set(
-                        cohort.errors))
+                    errors=new_clients.errors.set_rows(
+                        ids, cohort.errors))
             if _has_velocities(cfg):
                 new_clients = new_clients._replace(
-                    velocities=new_clients.velocities.at[ids].set(
-                        cohort.velocities))
+                    velocities=new_clients.velocities.set_rows(
+                        ids, cohort.velocities))
             if cfg.do_topk_down:
                 new_clients = new_clients._replace(
-                    weights=new_clients.weights.at[ids].set(
-                        cohort.weights))
+                    weights=new_clients.weights.set_rows(
+                        ids, cohort.weights))
         return new_clients
 
     # ---------------- full train round ----------------------------------
@@ -1295,11 +1460,20 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
         step (client state rides the scan carry, so the gather/scatter
         happen per scanned round exactly as before the split) and the
         bit-identity twin tests compare the three-program dispatch
-        against."""
-        cohort = gather_cohort(clients, batch.client_ids)
+        against. The cohort crosses an `optimization_barrier` each
+        way, so the chip's compiler keeps the three programs three
+        fusion regions and cannot fuse the conversion out of (or
+        into) the blocks' tile form with the round's arithmetic and
+        round that differently than the round program alone does.
+        (The CPU's compiler drops barriers before it fuses; there the
+        row loop of `take_rows` keeps the cohort out of the round's
+        fusions.)"""
+        cohort = lax.optimization_barrier(
+            gather_cohort(clients, batch.client_ids))
         server, new_cohort, metrics = round_step(
             server, cohort, batch, lr, key)
-        clients = scatter_back(clients, batch.client_ids, new_cohort)
+        clients = scatter_back(clients, batch.client_ids,
+                               lax.optimization_barrier(new_cohort))
         return server, clients, metrics
 
     # explicit output placement for the state-motion programs (the
@@ -1321,7 +1495,9 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
         from commefficient_tpu.parallel import multihost as mh
 
         def spec(tracked):
-            return P("clients", None) if tracked else P()
+            # one spec per field: a tracked RowBlock's only leaf is
+            # its [rows, T, 128] tiles
+            return P("clients", None, None) if tracked else P()
         return mh.shardings(mesh, ClientState(
             spec(_has_errors(cfg)), spec(_has_velocities(cfg)),
             spec(cfg.do_topk_down)))
@@ -1354,6 +1530,8 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
     span_donate = (SPAN_DEAD_ARGNUMS
                    if cfg.donate_round_state and not cfg.pipeline
                    else ())
+    n_tracked = (int(_has_errors(cfg)) + int(_has_velocities(cfg))
+                 + int(cfg.do_topk_down))
     _gather_jit = jax.jit(gather_cohort,
                           out_shardings=_cohort_sharding())
     _scatter_jit = jax.jit(scatter_back, donate_argnums=scatter_donate,
@@ -1430,12 +1608,18 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
             # dispatch/collect seam). The round/span tags inherit
             # from the caller's enclosing `dispatch` span; nothing
             # here touches the traced programs.
-            with TRACE.span("gather"):
+            # `rows`/`bytes`: what has to move, from shapes alone (W
+            # rows of D float32 per tracked block, each way) — over
+            # the device time of the two programs, the bandwidth the
+            # state motion achieves (journal.summarize sums them)
+            rows = batch.client_ids.shape[0] * n_tracked
+            moved = dict(rows=rows, bytes=rows * cfg.grad_size * 4)
+            with TRACE.span("gather", **moved):
                 cohort = _gather_jit(clients, batch.client_ids)
             with TRACE.span("round_dispatch"):
                 server, new_cohort, metrics = _train_round_jit(
                     server, cohort, batch, lr, key)
-            with TRACE.span("scatter"):
+            with TRACE.span("scatter", **moved):
                 clients = _scatter_jit(clients, batch.client_ids,
                                        new_cohort)
             return server, clients, metrics

@@ -2,15 +2,20 @@
 set over a host-spilled long tail.
 
 PR 9 made every per-round *cost* O(active cohort), but the
-`[padded_population, D]` client-state blocks still lived sharded in
-device HBM — ~78 TB at flagship D for 1e6 local_topk clients, so
-"million clients" was real for compute but not for residency. Behind
-``Config.state_tier=host`` this module caps the device-resident rows
+`[padded_population, D]` client-state blocks (each a RowBlock,
+federated/round: stored `[rows, T, 128]`, a row as whole tiles) still
+lived sharded in device HBM — ~78 TB at flagship D for 1e6
+local_topk clients, so "million clients" was real for compute but
+not for residency. Behind ``Config.state_tier=host`` this module
+caps the device-resident rows
 at an LRU working set of ``Config.state_working_set`` recently-active
 clients: the ClientState blocks shrink to ``[working_set, D]``
 (federated/round.client_state_rows) and rows are addressed by device
 SLOT, while the cold tail lives on the host (optionally disk-backed
-sparse memmaps under ``Config.state_spill_dir``).
+sparse memmaps under ``Config.state_spill_dir``) as plain
+``[rows, D]`` tables: the tile form is the device's alone, and every
+row crossing between the tiers does so as a ``[W, D]`` CohortState
+through the two state-motion programs, which convert it.
 
 The PR-9 cohort-gather/scatter-back state-motion pair is the single
 choke point extended — and stays the ONLY pair of state-motion
@@ -807,7 +812,7 @@ class TieredStateStore:
                 field = getattr(new, name)
                 placed = mh.globalize(self.mesh, P(), data)
                 new = new._replace(
-                    **{name: field.at[gidx].set(placed)})
+                    **{name: field.set_rows(gidx, placed)})
             clients = new
         return clients
 

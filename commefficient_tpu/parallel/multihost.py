@@ -383,12 +383,15 @@ def zeros(mesh: Mesh, spec: P, shape: Tuple[int, ...],
 
 
 def tile_rows(mesh: Mesh, vec, rows: int) -> jax.Array:
-    """``[rows, D]`` global array whose every row is ``vec``, sharded
-    ``P('clients', None)`` — the per-client stale-weights state of the
-    download-top-k path. Shard-local materialization only."""
+    """``[rows, *vec.shape]`` global array whose every row is ``vec``,
+    sharded over its leading axis — the per-client stale-weights state
+    of the download-top-k path (``vec`` is the base row in the
+    blocks' tile form, federated/round.rows_to_tiles). Shard-local
+    materialization only."""
     host = np.asarray(vec)
-    shape = (rows, host.shape[0])
-    sharding = NamedSharding(mesh, P(CLIENTS_AXIS, None))
+    shape = (rows,) + host.shape
+    sharding = NamedSharding(
+        mesh, P(CLIENTS_AXIS, *([None] * host.ndim)))
     if not is_multihost():
         # materialize the tile ON DEVICE from the (small, explicit)
         # device_put of the base vector: like zeros() above, the
@@ -402,7 +405,7 @@ def tile_rows(mesh: Mesh, vec, rows: int) -> jax.Array:
         # _unaliasable: these rows ride the donation chain — see
         # zeros() above
         return _unaliasable(np.broadcast_to(
-            host[idx[1]], _shard_shape(idx, shape)))
+            host[idx[1:]], _shard_shape(idx, shape)))
 
     return jax.make_array_from_callback(shape, sharding, cb)
 

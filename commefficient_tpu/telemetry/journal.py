@@ -998,7 +998,10 @@ def summarize(records: List[dict], corrupt_lines: int = 0) -> dict:
     (`overlap_efficiency` = device-busy / wall over the
     device_execute spans) and, where FedLoader's `load_fetch` spans
     are among them, `loader_cohort_share` (the share of its rounds
-    fetched and transformed as one cohort, from their `cohort` tags).
+    fetched and transformed as one cohort, from their `cohort` tags),
+    and where TrainRound's `gather`/`scatter` spans are,
+    `state_motion_rows_per_round` / `state_motion_bytes_per_round`
+    (what the client state motion has to move, from their tags).
     Independently, round events carrying the
     `mono` timestamp yield the inter-round `cadence` block
     (p50/p95 + histogram) — deltas are taken on the MONOTONIC clock,
@@ -1208,6 +1211,19 @@ def summarize(records: List[dict], corrupt_lines: int = 0) -> dict:
             # (the rest were fetched and transformed client by client)
             out["loader_cohort_share"] = round(
                 sum(fetches) / len(fetches), 4)
+        moved = [sp for sp in trace_spans
+                 if sp.get("name") in ("gather", "scatter")
+                 and "bytes" in sp]
+        gathers = sum(sp["name"] == "gather" for sp in moved)
+        if gathers:
+            # client state motion (federated/round TrainRound): the
+            # rows and bytes a round's cohort gather and scatter-back
+            # have to move, both ways summed — over `state_motion_ms`
+            # of a device trace, the bandwidth the two programs reach
+            out["state_motion_rows_per_round"] = round(
+                sum(sp["rows"] for sp in moved) / gathers, 2)
+            out["state_motion_bytes_per_round"] = round(
+                sum(sp["bytes"] for sp in moved) / gathers, 1)
         busy = wall = 0.0
         for seg in trace_segments:
             bw = device_busy_wall(seg)

@@ -44,7 +44,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from commefficient_tpu.federated.round import ClientState, ServerState
+from commefficient_tpu.federated.round import (
+    ClientState, RowBlock, ServerState,
+)
 from commefficient_tpu.parallel import multihost as mh
 from commefficient_tpu.telemetry.trace import TRACE
 
@@ -389,11 +391,21 @@ def save_checkpoint(path: str, server: ServerState,
 
 
 def _gather_rows(x, chunk_rows: int = 256):
-    """Gather a clients-sharded [rows, D] block to the COORDINATOR's
-    host in bounded chunks: every process participates in each chunk's
-    collective gather, but only the coordinator accumulates the full
-    array — non-coordinators' transient peak is one chunk. Returns the
-    full array on the coordinator, an empty placeholder elsewhere."""
+    """Gather a clients-sharded block to the COORDINATOR's host in
+    bounded chunks of client rows: every process participates in each
+    chunk's collective gather, but only the coordinator accumulates
+    the full array — non-coordinators' transient peak is one chunk.
+    Returns the full array on the coordinator, an empty placeholder
+    elsewhere. A RowBlock (the device's whole-tile storage,
+    federated/round) comes back in the checkpoint's own form, a plain
+    [rows, D] array: the file format is independent of the device
+    layout, so files written before and after the tile form load
+    alike."""
+    if isinstance(x, RowBlock):
+        tiles = _gather_rows(x.tiles, chunk_rows)
+        if tiles.ndim != 3:
+            return tiles          # a non-coordinator's placeholder
+        return np.asarray(RowBlock(tiles, x.D))
     if (not mh.is_multihost() or getattr(x, "ndim", 1) < 2
             or x.shape[0] <= chunk_rows):
         return mh.gather_host(x)
@@ -407,6 +419,16 @@ def _gather_rows(x, chunk_rows: int = 256):
             out[lo:hi] = block
         del block
     return out if out is not None else np.zeros((0,), np.float32)
+
+
+def _as_block(saved: np.ndarray):
+    """A saved client_* array back in the blocks' form: a [rows, D]
+    block becomes a RowBlock (its tiles still on the host: the
+    loading model places them, FedModel.load_state), a zero-size
+    placeholder stays a plain array."""
+    if saved.ndim == 2:
+        return RowBlock.from_rows(saved)
+    return jnp.asarray(saved)
 
 
 def load_checkpoint(path: str,
@@ -450,9 +472,9 @@ def load_checkpoint(path: str,
                        if k.startswith("crows_")}
     elif "client_errors" in z:
         clients = ClientState(
-            errors=jnp.asarray(z["client_errors"]),
-            velocities=jnp.asarray(z["client_velocities"]),
-            weights=jnp.asarray(z["client_weights"]),
+            errors=_as_block(z["client_errors"]),
+            velocities=_as_block(z["client_velocities"]),
+            weights=_as_block(z["client_weights"]),
         )
     acct = {k[len("acct_"):]: z[k] for k in z.files
             if k.startswith("acct_") and k != "acct_prev_change_words"}

@@ -102,7 +102,8 @@ def test_population_inventory_names_the_client_state(full_audit):
     # the named client-state map now lives on the two state-motion
     # programs: gather reads all three dense blocks, scatter carries
     # them in AND out
-    names = {"clients.errors", "clients.velocities", "clients.weights"}
+    names = {"clients.errors.tiles", "clients.velocities.tiles",
+             "clients.weights.tiles"}
     g = report["programs"]["client-state/gather"][
         "population_inventory"]
     assert {e["name"] for e in g["inputs"]} == names
@@ -359,7 +360,7 @@ def _mini(mesh, donate: bool, num_clients: int = 16):
     handle = make_train_fn(_loss_fn, unravel, cfg, mesh)
     server = init_server_state(cfg, vec, mesh=mesh)
     # mesh-placed, the production pattern: the scatter-back jit pins
-    # P('clients', None) out_shardings, and donation only aliases when
+    # P('clients', None, None) out_shardings, and donation only aliases when
     # the input already lives in that layout
     clients = init_client_state(cfg, num_clients, vec, mesh=mesh)
     rng = np.random.RandomState(7)
@@ -404,7 +405,9 @@ def test_donation_resume_bit_exact(mesh):
 
     from jax.sharding import PartitionSpec as P
 
-    from commefficient_tpu.federated.round import client_state_specs
+    from commefficient_tpu.federated.round import (
+        RowBlock, client_state_specs,
+    )
     from commefficient_tpu.parallel import multihost as mh
 
     h2, s2, c2, b2 = _mini(mesh, donate=True)
@@ -416,10 +419,12 @@ def test_donation_resume_bit_exact(mesh):
     # a default-placed restore would silently defeat the scatter-back
     # donation aliasing
     s3 = type(s2)(*[mh.globalize(mesh, P(), f) for f in saved_server])
-    c3 = type(c2)(*[mh.globalize(mesh, spec, f)
-                    for f, spec in zip(saved_clients,
-                                       client_state_specs(
-                                           type(c2)(*saved_clients)))])
+    # the host copies are [rows, D], as a checkpoint holds them; the
+    # device's form is the RowBlock's whole tiles
+    restored = type(c2)(*[RowBlock.from_rows(f) if f.ndim == 2 else f
+                          for f in saved_clients])
+    c3 = jax.tree.map(lambda leaf, spec: mh.globalize(mesh, spec, leaf),
+                      restored, client_state_specs(restored))
     s3, c3 = _run(h2, s3, c3, b2, 3, key)
     assert _state_bytes(s_straight) == _state_bytes(s3)
     assert _state_bytes(c_straight) == _state_bytes(c3)
@@ -434,15 +439,15 @@ def test_donated_dispatch_three_programs_and_no_transfers(
     implicit transfers in steady state."""
     from jax.sharding import PartitionSpec as P
 
+    from commefficient_tpu.federated.round import client_state_specs
     from commefficient_tpu.parallel import multihost as mh
 
     h, server, clients, batch = _mini(mesh, donate=True)
     server = jax.tree.map(
         lambda a: mh.globalize(mesh, P(), np.asarray(a)), server)
     clients = jax.tree.map(
-        lambda a: mh.globalize(
-            mesh, P("clients", None) if np.ndim(a) == 2 else P(),
-            np.asarray(a)), clients)
+        lambda a, spec: mh.globalize(mesh, spec, np.asarray(a)),
+        clients, client_state_specs(clients))
     ids = mh.globalize(mesh, P(), np.arange(8, dtype=np.int32))
     data = tuple(mh.shard_rows(mesh, np.asarray(d))
                  for d in batch.data)

@@ -15,11 +15,15 @@ GPT2-small head shapes; and the three sketch kernels behind
 `kernel_backend="pallas"` at the flagship 5 x 500,000 table with
 D=6,568,640 — which Mosaic has never accepted. Those are strict
 xfails carrying the compiler's message: the day a re-tiling gets one
-through, its test fails until the marker goes.
+through, its test fails until the marker goes. And the two client
+state-motion programs at the shapes of the benchmark's local top-k
+cell (2 x 100 clients x D=6,568,640, 16 a round): the rows must move
+as whole tiles, which only the chip's compiler can say.
 
 A compile that passes is not a chip run: chip_smoke.py is.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,3 +114,125 @@ def test_pallas_threshold_decode_compiles_at_flagship(one_chip,
     sk = _tpu_sketch(monkeypatch)
     _compile(lambda t: sketch_pallas.pallas_threshold_decode(sk, t, 50_000),
              ((sk.r, sk.c), jnp.float32), sharding=one_chip)
+
+
+# ---------------------------------------------------------------------------
+# client state motion at the local top-k cell's shapes
+
+
+@pytest.fixture(scope="module")
+def state_motion(topo, one_chip):
+    """The compiled cohort-gather and scatter-back of the real round
+    factory, for one described chip (one_chip: the compile cache
+    off), built as `scripts/state_motion_layout.py` builds them."""
+    import importlib.util
+    import sys
+
+    from commefficient_tpu.federated.round import lane_rows
+
+    spec = importlib.util.spec_from_file_location(
+        "state_motion_layout",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "scripts",
+            "state_motion_layout.py"))
+    layout = importlib.util.module_from_spec(spec)
+    sys.modules["state_motion_layout"] = layout
+    spec.loader.exec_module(layout)
+    D, pop, W = FLAGSHIP["d"], 100, 16
+    gather, scatter, _ = layout.compile_state_motion(
+        topo.devices[:1], D, pop, W)
+    return {"gather": gather, "scatter": scatter,
+            "block_bytes": 2 * pop * lane_rows(D) * 128 * 4,
+            "cohort_bytes": 2 * W * D * 4}
+
+
+@pytest.mark.parametrize("program", ["gather", "scatter"])
+def test_state_motion_block_is_tiled_over_the_rows_own_dims(
+        state_motion, program):
+    """`f32[100,51320,128]{2,1,0:T(8,128)}`: the (8, 128) tiles lie
+    over one client's coordinates, so a row is whole tiles in one
+    piece — not `f32[100,6568640]{1,0:T(8,128)}`, where a row is one
+    sublane of tiles shared with seven other clients."""
+    text = state_motion[program].as_text()
+    blocks = set(re.findall(
+        r"= (f32\[100,[\d,]+\]\{[^}]*\}) parameter\(", text))
+    assert blocks == {"f32[100,51320,128]{2,1,0:T(8,128)}"}, blocks
+
+
+def test_gather_moves_rows_without_the_gather_op(state_motion):
+    """No `gather` instruction (the compiler made 201 strided pieces
+    and 10 GB of traffic a table of it): a row loop a table whose
+    body is one in-place copy of a `[1, 51320, 128]` row, and traffic
+    within 1.6x of the passes each table needs (the rows, the tile
+    transpose, the cut)."""
+    compiled = state_motion["gather"]
+    text = compiled.as_text()
+    assert not re.findall(r" gather\(", text)
+    assert len(re.findall(r" while\(", text)) == 2
+    assert len(set(re.findall(
+        r"%(dynamic-slice_dynamic-update-slice_fusion\S*) = "
+        r"f32\[16,51320,128\]", text))) == 2
+    assert "f32[1,6568640]" not in text and "f32[1,6568960]" not in text
+    # the compiler counts a loop at one trip: fifteen more of a row
+    # read and written, for both tables
+    accessed = (compiled.cost_analysis()["bytes accessed"]
+                + 15 * 2 * state_motion["cohort_bytes"] / 16)
+    assert accessed <= 1.6 * 6 * state_motion["cohort_bytes"], accessed
+
+
+def test_scatter_writes_whole_tile_rows_in_place(state_motion):
+    """The donated blocks are the results (no copy of 5 GB), and the
+    row writes are `[1, 51320, 128]` pieces, never a `[1, D]` row
+    padded to eight sublanes."""
+    compiled = state_motion["scatter"]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_motion["block_bytes"]
+    assert mem.temp_size_in_bytes < 3 * state_motion["cohort_bytes"]
+    text = compiled.as_text()
+    assert "f32[1,51320,128]" in text
+    assert "f32[1,6568640]" not in text and "f32[1,6568960]" not in text
+
+
+# ---------------------------------------------------------------------------
+# the same two programs on a four-chip `clients` mesh
+
+
+@pytest.fixture(scope="module")
+def state_motion_mesh(topo, one_chip, state_motion):
+    """As `state_motion`, the blocks sharded over the four described
+    chips (`scripts/state_motion_layout.py --devices 4`)."""
+    import sys
+    layout = sys.modules["state_motion_layout"]
+    D, pop, W = FLAGSHIP["d"], 100, 16
+    gather, scatter, _ = layout.compile_state_motion(
+        topo.devices[:4], D, pop, W)
+    return {"gather": gather, "scatter": scatter,
+            "share_bytes": state_motion["block_bytes"] // 4}
+
+
+@pytest.mark.parametrize("program", ["gather", "scatter"])
+def test_mesh_state_motion_takes_a_quarter_of_the_blocks(
+        state_motion_mesh, program):
+    """A device is handed its 25 clients of each block (plus ids and,
+    for the scatter, its share of the cohort) and no collective
+    gathers a block: the cohort crosses chips, the blocks never do."""
+    compiled = state_motion_mesh[program]
+    share = state_motion_mesh["share_bytes"]
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert share <= args < 1.2 * share, (args, share)
+    text = compiled.as_text()
+    assert "f32[25,51320,128]{2,1,0:T(8,128)} parameter(" in text
+    assert "f32[100,51320,128]" not in text
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "round.take_rows(sharded=True) leaves a mesh's cohort gather to "
+    "GSPMD's partitioning of tiles[ids]: XLA's gather op per shard, 52 "
+    "gather instructions and 16.5 GB accessed a device where 1.3 GB of "
+    "rows move (a shard_map row loop deadlocked the CPU runtime's "
+    "virtual devices; ROADMAP S3, PERF.md section 7). The branch and "
+    "this marker go once the row loop is shown on a real mesh."))
+def test_mesh_gather_moves_rows_without_the_gather_op(state_motion_mesh):
+    compiled = state_motion_mesh["gather"]
+    assert not re.findall(r" gather\(", compiled.as_text())
+    assert compiled.cost_analysis()["bytes accessed"] < 4e9
