@@ -20,7 +20,7 @@ mechanical:
     Per-line ``# graftlint: disable=GLxxx`` suppressions and
     a baseline file grandfather justified hits.
   * `audit` + `costmodel` — the SECOND tier (``graftaudit``, ISSUE 7):
-    traces the three round programs per config/backend to ClosedJaxprs
+    traces the three round programs per config to ClosedJaxprs
     and walks the program itself — forbidden host-interaction
     primitives, f64, large exact sorts, population-scaling buffers
     (with the named client-state inventory), buffer-donation coverage,

@@ -3,8 +3,8 @@
 graftlint (engine/rules) sees SOURCE — it catches what syntax can
 prove and nothing more. This module is the second analysis tier: it
 traces the round programs the engine actually dispatches (the three
-RoundBatch treedefs of federated/round.PROGRAM_VARIANTS, on both
-kernel backends plus a client-state-bearing config) to ClosedJaxprs
+RoundBatch treedefs of federated/round.PROGRAM_VARIANTS, for a
+sketch config plus a client-state-bearing one) to ClosedJaxprs
 and walks the PROGRAM — post-closure, post-fusion, post-dispatch-
 gating — for the contracts prose and AST can't check:
 
@@ -22,7 +22,7 @@ gating — for the contracts prose and AST can't check:
   AU003  exact `sort`/`top_k` over large static operands — the GL008
          regression class (~125 ms/round on TPU, PERF.md §1), caught
          here AFTER all dispatch gating, so a config routing around
-         `approx_max_k`/the fused kernels cannot hide.
+         `approx_max_k` cannot hide.
   AU004  population scaling. Since ISSUE 9 the rule is STRICT for
          round programs: ANY population-shaped value — input, output,
          intermediate, or baked-in constant — is an error, because
@@ -51,7 +51,7 @@ gating — for the contracts prose and AST can't check:
 
 The auditor is config-driven from ``[tool.graftaudit]`` in
 pyproject.toml and ships as the ``graftaudit`` console script
-(scripts/audit.sh; tier1.sh runs it right after graftlint). Its cost
+(scripts/audit.sh). Its cost
 report is journaled as an ``audit_digest`` event
 (telemetry/journal.py) and is bit-identical across runs — tracing is
 deterministic, the report is canonical-JSON — which is what lets the
@@ -112,7 +112,7 @@ AUDIT_POPULATION = 23
 
 # the synthetic workload geometry — small enough to trace in
 # milliseconds, structured enough that every audited code path (sketch
-# encode/decode, pallas kernels, per-client state gather/scatter) is
+# encode/decode, per-client state gather/scatter) is
 # live. Committed baselines price THIS geometry; change it and the
 # baseline must be regenerated.
 AUDIT_GEOMETRY = dict(D=1024, W=8, B=4, k=64, rows=3, cols=256)
@@ -372,10 +372,9 @@ def donation_findings(config_name: str, handle) -> List[AuditFinding]:
 # traced jaxprs are the production programs at audit geometry
 
 
-def audit_configs(backends: Sequence[str] = ("xla", "pallas"),
-                  population: int = AUDIT_POPULATION):
-    """(name, Config) pairs the auditor traces. Two sketch configs pin
-    the compression hot path on each kernel backend; `client-state`
+def audit_configs(population: int = AUDIT_POPULATION):
+    """(name, Config) pairs the auditor traces. `sketch` pins the
+    compression hot path; `client-state`
     (local_topk + local error + momentum + topk_down) is the config
     whose per-client rows populate the AU004 inventory. `population`
     overrides the num_clients sentinel (the mesh tier,
@@ -385,13 +384,11 @@ def audit_configs(backends: Sequence[str] = ("xla", "pallas"),
     base = dict(weight_decay=0.0, num_workers=g["W"],
                 microbatch_size=-1, grad_size=g["D"],
                 num_clients=population, seed=0)
-    out = []
-    for b in backends:
-        out.append((f"sketch-{b}", Config(
-            mode="sketch", error_type="virtual", virtual_momentum=0.9,
-            local_momentum=0.0, k=g["k"], num_rows=g["rows"],
-            num_cols=g["cols"], num_blocks=1, kernel_backend=b,
-            **base).validate()))
+    out = [("sketch", Config(
+        mode="sketch", error_type="virtual", virtual_momentum=0.9,
+        local_momentum=0.0, k=g["k"], num_rows=g["rows"],
+        num_cols=g["cols"], num_blocks=1,
+        **base).validate())]
     out.append(("client-state", Config(
         mode="local_topk", error_type="local", local_momentum=0.9,
         do_topk_down=True, k=g["k"], down_k=32,
@@ -416,7 +413,7 @@ def audit_configs(backends: Sequence[str] = ("xla", "pallas"),
     out.append(("sketch-screened", Config(
         mode="sketch", error_type="virtual", virtual_momentum=0.9,
         local_momentum=0.0, k=g["k"], num_rows=g["rows"],
-        num_cols=g["cols"], num_blocks=1, kernel_backend="xla",
+        num_cols=g["cols"], num_blocks=1,
         update_screen="norm", **base).validate()))
     # Byzantine-robust aggregation (ISSUE 17): the screened sketch
     # config with a live adversary draw and the beta-trimmed mean —
@@ -427,7 +424,7 @@ def audit_configs(backends: Sequence[str] = ("xla", "pallas"),
     out.append(("sketch-robust", Config(
         mode="sketch", error_type="virtual", virtual_momentum=0.9,
         local_momentum=0.0, k=g["k"], num_rows=g["rows"],
-        num_cols=g["cols"], num_blocks=1, kernel_backend="xla",
+        num_cols=g["cols"], num_blocks=1,
         update_screen="norm", byzantine_rate=0.2, attack="sign_flip",
         aggregator="trimmed_mean", **base).validate()))
     # compressor plugins (ISSUE 19): the two new plugin families.
@@ -688,8 +685,7 @@ def exit_code(violations: Sequence, drift: Sequence,
 # the full audit
 
 
-def run_audit(backends: Sequence[str] = ("xla", "pallas"),
-              inventory_configs: Sequence[str] = ()
+def run_audit(inventory_configs: Sequence[str] = ()
               ) -> Tuple[dict, List[AuditFinding]]:
     """Trace every audit config x (round program variant + the two
     state-motion programs); return (report, findings). Findings carry
@@ -706,7 +702,7 @@ def run_audit(backends: Sequence[str] = ("xla", "pallas"),
 
     programs: Dict[str, dict] = {}
     findings: List[AuditFinding] = []
-    for cfg_name, cfg in audit_configs(backends):
+    for cfg_name, cfg in audit_configs():
         strict = cfg_name not in set(inventory_configs)
         handle, server, clients, variants, lr, key = build_workload(cfg)
         findings.extend(donation_findings(cfg_name, handle))
@@ -823,11 +819,6 @@ def main(argv: Optional[list] = None) -> int:
                     default=float(conf.get("cost_tolerance", 0.0)),
                     help="relative cost drift allowed before AU006 "
                          "(default 0.0: exact match)")
-    ap.add_argument("--backends", nargs="*",
-                    default=list(conf.get("backends",
-                                          ["xla", "pallas"])),
-                    help="kernel backends to trace the sketch "
-                         "programs on")
     ap.add_argument("--inventory-configs", nargs="*",
                     default=list(conf.get(
                         "population_inventory_configs", [])),
@@ -852,15 +843,8 @@ def main(argv: Optional[list] = None) -> int:
             print(f"{code}  {doc}")
         return 0
 
-    for b in args.backends:
-        if b not in ("xla", "pallas"):
-            # 3, not 2: exit 2 is reserved for baseline drift
-            print(f"graftaudit: unknown backend {b!r}",
-                  file=sys.stderr)
-            return 3
-
     report, findings = run_audit(
-        args.backends, inventory_configs=args.inventory_configs)
+        inventory_configs=args.inventory_configs)
 
     if args.write_baseline:
         counts: Dict[Tuple[str, str], int] = {}

@@ -247,7 +247,7 @@ for _name, _edge in ORDERING_EDGES.items():
 PRECISION_SEAMS = {
     "sketch-wire-bf16": {
         "src": "float32", "dst": "bfloat16",
-        "path": "commefficient_tpu/ops/kernels/quant.py",
+        "path": "commefficient_tpu/ops/quant.py",
         "function": "quantize_table",
         "why": "the bf16 sketch-table wire format (PR 6): the rounding "
                "is bounded per-cell and lands in the error-feedback "
@@ -255,7 +255,7 @@ PRECISION_SEAMS = {
     },
     "sketch-wire-int8": {
         "src": "float32", "dst": "int8",
-        "path": "commefficient_tpu/ops/kernels/quant.py",
+        "path": "commefficient_tpu/ops/quant.py",
         "function": "quantize_table",
         "why": "the int8 symmetric sketch-table wire format (PR 6): "
                "per-row scale rides beside the payload, quantization "

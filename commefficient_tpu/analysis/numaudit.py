@@ -865,8 +865,7 @@ def trace_span(handle, server, clients, batch, lr, key,
     return closed, in_names, _leaf_names("out", out_shape)
 
 
-def run_num_audit(backends: Sequence[str] = ("xla", "pallas")
-                  ) -> Tuple[dict, List[AuditFinding]]:
+def run_num_audit() -> Tuple[dict, List[AuditFinding]]:
     """Trace every audit config x (round variants + the two
     state-motion programs + the scanned span) and run the numerics
     walks; return (report, findings). Findings carry NU001-NU004;
@@ -891,7 +890,7 @@ def run_num_audit(backends: Sequence[str] = ("xla", "pallas")
         ulp[prog] = {"worst_case_ulp": reassociation_ulp_bound(
             closed, ULP_AXIS_SIZES)}
 
-    for cfg_name, cfg in audit_configs(backends):
+    for cfg_name, cfg in audit_configs():
         handle, server, clients, variants, lr, key = build_workload(
             cfg)
         for variant in program_variants_for(cfg):
@@ -997,11 +996,6 @@ def main(argv: Optional[list] = None) -> int:
                     help="report every finding and skip the ulp diff")
     ap.add_argument("--write-baseline", action="store_true",
                     help="regenerate the baseline from this audit")
-    ap.add_argument("--backends", nargs="*",
-                    default=list(conf.get("backends",
-                                          ["xla", "pallas"])),
-                    help="kernel backends to trace the sketch "
-                         "programs on")
     ap.add_argument("--journal", default="",
                     help="append the report to this JSONL run journal "
                          "as a `num_audit_digest` event")
@@ -1015,13 +1009,7 @@ def main(argv: Optional[list] = None) -> int:
             print(f"{code}  {doc}")
         return 0
 
-    for b in args.backends:
-        if b not in ("xla", "pallas"):
-            # 3, not 2: exit 2 is reserved for baseline drift
-            print(f"graftnum: unknown backend {b!r}", file=sys.stderr)
-            return 3
-
-    report, findings = run_num_audit(args.backends)
+    report, findings = run_num_audit()
 
     if args.write_baseline:
         counts: Dict[Tuple[str, str], int] = {}

@@ -598,8 +598,8 @@ def check_gl008(module: ModuleInfo) -> Iterator[Violation]:
             "code: exact top-k lowers to a full sorting network on TPU "
             "(the ~125 ms/round regression class in PERF.md); use "
             "`jax.lax.approx_max_k` (error feedback absorbs the ~5% "
-            "recall miss) or the fused selection kernel "
-            "(ops/kernels/sketch_pallas.pallas_threshold_decode)")
+            "recall miss) or the sampled-threshold mask "
+            "(ops/flat.sampled_threshold_mask)")
 
 
 # ---------------------------------------------------------------------------

@@ -40,9 +40,9 @@ not prose:
     writer-queue handoffs (`queue.Queue.put`/`get`): a counter-driven
     (never random — replayable) sub-millisecond stagger that widens
     the producer/drain race windows the bounded-queue writers must
-    tolerate. tier1.sh arms both over the pipeline/statetier/
-    controlplane suites via the `CCTPU_SYNC_SANITIZE=1` autouse
-    fixture (tests/conftest.py).
+    tolerate. `CCTPU_SYNC_SANITIZE=1` arms both over whatever suites
+    pytest then runs (pipeline/statetier/controlplane are the ones
+    worth it) via an autouse fixture (tests/conftest.py).
 
   * `NumericSanitizer` — graftnum's runtime twin (ISSUE 18).
     Installed, it wraps `telemetry.metrics.named` (the ONE host
@@ -53,9 +53,10 @@ not prose:
     predicate is semantically sufficient). `replay_drill(fn, *args)`
     dispatches a traced program twice on identical operands and
     asserts bitwise equality leaf by leaf — the executable form of
-    the NU004 crash->resume contract. tier1.sh re-runs the
-    valuefaults/byzantine suites with the guard armed via the
-    `CCTPU_NUM_SANITIZE=1` autouse fixture (tests/conftest.py).
+    the NU004 crash->resume contract. `CCTPU_NUM_SANITIZE=1` arms
+    the guard over whatever suites pytest then runs (valuefaults and
+    byzantine are the ones worth it) via an autouse fixture
+    (tests/conftest.py).
 
 The `sanitize` pytest fixture (tests/conftest.py) hands tests the
 program-count/transfer pair; `lock_sanitizer` hands them an

@@ -532,15 +532,14 @@ class MeshBaseline(AuditBaseline):
     DRIFT_RULE = "MAU006"
 
 
-def mesh_configs(backends: Sequence[str] = ("xla", "pallas")):
+def mesh_configs():
     """The audit-config surface, re-populated for the mesh tier: the
     sentinel must divide every registered clients axis so client-state
     rows carry it un-padded."""
-    return audit_configs(backends, population=MESH_POPULATION)
+    return audit_configs(population=MESH_POPULATION)
 
 
-def run_mesh_audit(backends: Sequence[str] = ("xla", "pallas"),
-                   mesh_names: Optional[Sequence[str]] = None,
+def run_mesh_audit(mesh_names: Optional[Sequence[str]] = None,
                    replicated_min_bytes: int = 1 << 20,
                    dcn_table_bytes: int = 1024,
                    ) -> Tuple[dict, List[AuditFinding]]:
@@ -552,7 +551,7 @@ def run_mesh_audit(backends: Sequence[str] = ("xla", "pallas"),
     meshes = build_meshes(mesh_names)
     programs: Dict[str, dict] = {}
     findings: List[AuditFinding] = []
-    for cfg_name, cfg in mesh_configs(backends):
+    for cfg_name, cfg in mesh_configs():
         # single-device reshard baseline, shared across meshes: the
         # same program traced on the 1-device mesh (AU011's "the
         # single-device program doesn't have" reference)
@@ -660,9 +659,6 @@ def main(argv: Optional[list] = None) -> int:
                     help="report every finding and skip the link diff")
     ap.add_argument("--write-baseline", action="store_true",
                     help="regenerate the baseline from this audit")
-    ap.add_argument("--backends", nargs="*",
-                    default=list(conf.get("backends",
-                                          ["xla", "pallas"])))
     ap.add_argument("--meshes", nargs="*",
                     default=list(conf.get("meshes", [])) or None,
                     help="subset of the mesh registry to audit")
@@ -694,13 +690,8 @@ def main(argv: Optional[list] = None) -> int:
                   f"dcn_spans={dict(link.axis_slices)}")
         return 0
 
-    for b in args.backends:
-        if b not in ("xla", "pallas"):
-            print(f"graftmesh: unknown backend {b!r}", file=sys.stderr)
-            return 3
-
     report, findings = run_mesh_audit(
-        args.backends, args.meshes,
+        args.meshes,
         replicated_min_bytes=args.replicated_min_bytes,
         dcn_table_bytes=args.dcn_table_bytes)
 

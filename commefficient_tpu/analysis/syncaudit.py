@@ -95,8 +95,7 @@ telemetry.journal.validate_journal like the other tiers' digests).
 The runtime twin — the LockOrderSanitizer that records REAL
 acquisition edges and asserts the graph acyclic at teardown, plus
 the interleaving-stress helper — lives in analysis/runtime.py and is
-armed over the pipeline/statetier/controlplane suites by
-scripts/tier1.sh.
+armed by `CCTPU_SYNC_SANITIZE=1` (tests/conftest.py).
 """
 from __future__ import annotations
 

@@ -102,7 +102,7 @@ class DpSketchCompressor(Compressor):
         # the sensitivity clip in residual() is nonlinear
         sketch = CSVec(d=cfg.grad_size, c=cfg.num_cols,
                        r=cfg.num_rows, num_blocks=cfg.num_blocks,
-                       seed=42, backend=cfg.kernel_backend)
+                       seed=42)
         return sketch.encode(grad)
 
     def residual(self, cfg, to_transmit, error, velocity, key=None):

@@ -42,7 +42,7 @@ class SketchCompressor(Compressor):
     def wire_bytes(self, cfg) -> int:
         # quantized wire transport (--sketch_table_dtype): bill at the
         # realized element size, plus int8's per-row f32 scales
-        from commefficient_tpu.ops.kernels.quant import wire_table_bytes
+        from commefficient_tpu.ops.quant import wire_table_bytes
         return wire_table_bytes(cfg.num_rows, cfg.num_cols,
                                 cfg.sketch_table_dtype)
 
@@ -54,7 +54,7 @@ class SketchCompressor(Compressor):
             return grad
         sketch = CSVec(d=cfg.grad_size, c=cfg.num_cols,
                        r=cfg.num_rows, num_blocks=cfg.num_blocks,
-                       seed=42, backend=cfg.kernel_backend)
+                       seed=42)
         table = sketch.encode(grad)
         if cfg.max_grad_norm is not None:
             table = clip_table_to_l2(

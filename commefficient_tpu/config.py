@@ -141,25 +141,15 @@ class Config:
     # update is k-sparse per round while a sparsely-participating
     # client accumulates MANY rounds of changes between downloads, so
     # the download budget that keeps staleness bounded is a multiple
-    # of k — the tradeoff benchmarks/convergence.py sweeps.
+    # of k.
     down_k: int = 0
-    # kernel backend for the compression hot path (ISSUE 6,
-    # commefficient_tpu/ops/kernels): "xla" — the default, bit-
-    # identical to the pre-kernel program (the dispatch gates are
-    # untaken, not re-proven) — or "pallas", which routes count-sketch
-    # encode / estimate-all / the large-d threshold decode through
-    # fused Pallas TPU kernels (interpret-mode on CPU, so tests
-    # execute the same kernel bodies). Static config: either choice
-    # traces the same THREE round programs, stays transfer-guard
-    # clean, and resumes bit-exactly (tests/test_kernels.py).
-    kernel_backend: str = "xla"
     # wire dtype of the transmitted [r, c] sketch table (sketch mode
     # only): "f32" (default — the transport code path is the identity,
     # bit-identical to a build without the flag), "bf16", or "int8"
     # (symmetric per-row scales). Quantization rounds the shard's
     # client-sum table before the psum; the server's virtual error
     # feedback absorbs the rounding noise the same way it absorbs
-    # sketch compression noise (ops/kernels/quant.py), telemetry's
+    # sketch compression noise (ops/quant.py), telemetry's
     # estimate_residual metric gauges whether accuracy pays for it,
     # and the accountant bills upload bytes at the WIRE element size
     # (Config.upload_bytes).
@@ -1103,10 +1093,6 @@ class Config:
                 "--state_working_set caps the device-resident rows of "
                 "the HOST tier and requires --state_tier host (the "
                 "device tier keeps every row in HBM, uncapped)")
-        if self.kernel_backend not in ("xla", "pallas"):
-            raise ValueError(
-                f"unknown kernel_backend {self.kernel_backend!r} "
-                "(choices: xla, pallas — commefficient_tpu/ops/kernels)")
         if self.sketch_table_dtype not in ("f32", "bf16", "int8"):
             raise ValueError(
                 f"unknown sketch_table_dtype {self.sketch_table_dtype!r} "
@@ -1207,13 +1193,6 @@ def _build_parser(default_lr: Optional[float] = None) -> argparse.ArgumentParser
     p.add_argument("--down_k", type=int, default=0,
                    help="download top-k budget (0 = share --k); see "
                         "Config.down_k")
-    p.add_argument("--kernel_backend", choices=("xla", "pallas"),
-                   default="xla",
-                   help="compression hot-path kernels: xla (default, "
-                        "bit-identical to the pre-kernel program) or "
-                        "pallas (fused TPU kernels for sketch encode/"
-                        "estimate/threshold decode; interpret-mode "
-                        "off-TPU — commefficient_tpu/ops/kernels)")
     p.add_argument("--sketch_table_dtype",
                    choices=("f32", "bf16", "int8"), default="f32",
                    help="wire dtype of the transmitted sketch table "

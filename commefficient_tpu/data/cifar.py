@@ -80,7 +80,7 @@ def _synthetic_cifar(num_classes: int, n_train: int, n_val: int, seed: int,
     protos, which the standard train transforms destroy: a +-4px
     random crop decorrelates a per-pixel pattern almost entirely and
     a horizontal flip negates it, so even direct SGD sat at chance
-    for epochs (measured — PERF.md round 5 / benchmarks/c3_probe.py).
+    for epochs (measured on the chip, round 5).
     Blocky symmetric protos survive crop (75%+ block overlap) and
     flip (exactly invariant), making the augmented synthetic task
     behave like real CIFAR instead of an adversarial one.

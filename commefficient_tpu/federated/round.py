@@ -964,7 +964,7 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
                                 fserver.args2sketch(cfg).encode)(txa)
                         if (cfg.mode == "sketch"
                                 and cfg.sketch_table_dtype != "f32"):
-                            from commefficient_tpu.ops.kernels import (
+                            from commefficient_tpu.ops.quant import (
                                 wire_roundtrip,
                             )
                             txa = wire_roundtrip(
@@ -1112,9 +1112,9 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
             # default traces the exact pre-quantization program. The
             # rounding noise lands in the server's virtual error
             # accumulator like any other compression noise
-            # (ops/kernels/quant.py); the accountant bills the wire
+            # (ops/quant.py); the accountant bills the wire
             # bytes (Config.upload_bytes).
-            from commefficient_tpu.ops.kernels import wire_roundtrip
+            from commefficient_tpu.ops.quant import wire_roundtrip
             with scope("encode"):
                 local_sum = wire_roundtrip(local_sum,
                                            cfg.sketch_table_dtype)

@@ -46,14 +46,9 @@ class ServerUpdate(NamedTuple):
 
 
 def args2sketch(cfg: Config) -> CSVec:
-    """Sketch geometry from config (reference fed_aggregator.py:464-467).
-    Carries Config.kernel_backend so the sketch's dense hot-path ops
-    (encode / estimate / threshold decode) run on the fused Pallas
-    kernels when selected — the hash tables themselves are identical
-    either way."""
+    """Sketch geometry from config (reference fed_aggregator.py:464-467)."""
     return CSVec(d=cfg.grad_size, c=cfg.num_cols, r=cfg.num_rows,
-                 num_blocks=cfg.num_blocks, seed=42,
-                 backend=cfg.kernel_backend)
+                 num_blocks=cfg.num_blocks, seed=42)
 
 
 def get_server_update(gradient: jax.Array, Vvelocity: jax.Array,

@@ -43,8 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from commefficient_tpu.ops.kernels.vma import vary_together
-
 DEFAULT_BLOCK = 128
 NEG_INF = -1e30
 # pad rows of the saved logsumexp carry this so exp(s - lse) == 0
@@ -139,6 +137,22 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
         lse_ref[0] = (m_scr[:, :1] + jnp.log(l_safe)[:, None]).astype(
             jnp.float32)                                # [bq, 1]
+
+
+def vary_together(*operands):
+    """(vma, operands) with every operand pcast to the union `vma` of
+    their varying-axes sets. Under `shard_map`'s `check_vma` a
+    `pallas_call` does not infer that set for its outputs (a bare
+    `ShapeDtypeStruct` is rejected), and its body refuses to mix
+    operands whose sets differ: both are settled before the call.
+    Outside `shard_map` the union is empty and nothing changes."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+    def lift(x):
+        missing = tuple(sorted(vma - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return vma, tuple(lift(x) for x in operands)
 
 
 def _flash_fwd_pallas(q, k, v, sm_scale, block_q, block_k,

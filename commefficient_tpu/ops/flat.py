@@ -91,9 +91,8 @@ def threshold_from_sq_sample(sq_sample: jax.Array, k: int,
                              total: int) -> jax.Array:
     """THE k-th-largest-square threshold estimate from a sample of
     squared magnitudes — one copy of the quantile math (ks clamp,
-    approx_max_k, tiny floor) shared by sampled_threshold_mask below
-    and the fused Pallas decode (ops/kernels/sketch_pallas), so the
-    two routes' selection contracts cannot drift apart.
+    approx_max_k, tiny floor), kept apart from the sampling in
+    sampled_threshold_mask below.
 
     sq_sample: [n] squared values sampled ~uniformly from a vector of
     `total` squared values; returns the scalar threshold: a vector

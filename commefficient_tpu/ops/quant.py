@@ -27,9 +27,7 @@ UNTOUCHED), so the default config's program is bit-identical to a
 build without this module.
 
 Pure elementwise jnp by design: XLA already fuses a cast or a
-scale/round/clip chain into the surrounding encode/psum — a Pallas
-kernel would add launch overhead for zero fusion win, so the kernel
-budget goes to the rotation/median ops (sketch_pallas) instead.
+scale/round/clip chain into the surrounding encode/psum.
 """
 from __future__ import annotations
 

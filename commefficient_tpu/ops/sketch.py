@@ -122,17 +122,6 @@ class CSVec:
     r: int
     num_blocks: int = 1   # accepted for parity; results are invariant
     seed: int = 42
-    # kernel backend for the dense hot-path ops (Config.kernel_backend,
-    # ISSUE 6): "xla" keeps every method on the code below — the
-    # default program is bit-identical to a build without the field —
-    # while "pallas" routes encode / estimate_all / the threshold
-    # decode through the fused kernels in ops/kernels/sketch_pallas
-    # (interpret-mode off TPU, so CPU tests run the kernel bodies).
-    # A geometry past the kernels' VMEM gate (pallas_fits) raises:
-    # the route is the one asked for or none. The hash/gather paths
-    # (estimate, encode_sparse) have no kernel: they are the
-    # scatter/gather formulation the kernels exist to avoid.
-    backend: str = "xla"
 
     def __post_init__(self):
         rng = np.random.RandomState(self.seed)
@@ -156,26 +145,6 @@ class CSVec:
     @property
     def _static_path(self) -> bool:
         return self.r * self.n_chunks <= STATIC_UNROLL_LIMIT
-
-    def _pallas(self, kind: str) -> bool:
-        """Whether `kind` ('encode' | 'estimate') runs on the fused
-        Pallas kernel for this sketch: the backend field decides. A
-        geometry the kernel's VMEM gate refuses raises — the XLA route
-        is never taken in silence for a method that was asked to run
-        on Pallas."""
-        if self.backend != "pallas":
-            return False
-        from commefficient_tpu.ops.kernels.sketch_pallas import (
-            PALLAS_VMEM_BUDGET, pallas_fits, pallas_vmem_bytes,
-        )
-        if not pallas_fits(self, kind):
-            raise ValueError(
-                f"kernel_backend='pallas': the {kind} kernel does not "
-                f"fit at d={self.d}, r={self.r}, c={self.c}: "
-                f"{pallas_vmem_bytes(self, kind)} bytes of VMEM against "
-                f"a budget of {PALLAS_VMEM_BUDGET}; use "
-                f"kernel_backend='xla' or a narrower table")
-        return True
 
     @property
     def table_shape(self) -> Tuple[int, int]:
@@ -222,12 +191,7 @@ class CSVec:
 
         Static-offset unroll (shifts known at trace time -> `jnp.roll`
         lowers to fusible static slices; see module perf notes); scan
-        fallback above STATIC_UNROLL_LIMIT; the fused Pallas kernel
-        (one VMEM pass per row, hardware dynamic rotate, compile time
-        flat in r * B) replaces BOTH when backend == 'pallas'."""
-        if self._pallas("encode"):
-            from commefficient_tpu.ops.kernels import pallas_encode
-            return pallas_encode(self, vec)
+        fallback above STATIC_UNROLL_LIMIT."""
         chunks = self._padded_chunks(vec)                  # [B, c]
         eps = jnp.asarray(self._eps)                       # [r, c]
 
@@ -322,14 +286,9 @@ class CSVec:
         """[B, c] median-of-rows estimates for every coordinate
         (flattened [: d] is the full estimate vector): r inverse
         rotations + sign correction per chunk, no gathers. Static
-        unroll when small enough (module perf notes); one fused
-        rotate+median kernel pass when backend == 'pallas' (the
-        Pallas route additionally zeroes the padding tail — a
-        superset of this method's contract that every caller
-        re-zeroes anyway)."""
-        if self._pallas("estimate"):
-            from commefficient_tpu.ops.kernels import pallas_estimate_all
-            return pallas_estimate_all(self, table)
+        unroll when small enough (module perf notes), a scan over
+        chunks above STATIC_UNROLL_LIMIT. The padding tail (coords
+        >= d) is NOT zeroed here: callers do (_flat_estimates)."""
         eps = jnp.asarray(self._eps)
 
         if self._static_path:
@@ -388,19 +347,9 @@ class CSVec:
         sampled-threshold route — one approx_max_k over a ~1M sample
         plus one elementwise mask, instead of an index top-k whose TPU
         partial-reduce sort grows with k*d — otherwise identical to
-        decode_topk. With backend == 'pallas' the threshold route is
-        the FUSED estimate+select kernel pair: the full [D] estimate
-        vector is never materialized in HBM (estimates recompute in
-        VMEM for the sample and the mask pass; kernels module
-        docstring covers the sample-phase difference the selection
-        tolerance already absorbs)."""
+        decode_topk."""
         if not self._threshold_decode:
             return self.decode_topk(table, k)
-        if self._pallas("estimate"):
-            from commefficient_tpu.ops.kernels import (
-                pallas_threshold_decode,
-            )
-            return pallas_threshold_decode(self, table, min(k, self.d))
 
         from commefficient_tpu.ops.flat import sampled_threshold_mask
         # the padding tail of _flat_estimates is already zeroed, which

@@ -700,8 +700,8 @@ def attach_config_transport(model, train_loader, cfg):
         (cfg.plan_controllers controllers). Chaos scripting rides env
         vars so the production CLI stays clean:
         CCTPU_EMU_COORD_CRASH=<round> kills the coordinator
-        mid-broadcast of that round (the tier1.sh smoke's scripted
-        crash), CCTPU_EMU_COORDINATOR=<pid> picks the (takeover)
+        mid-broadcast of that round (a scripted crash),
+        CCTPU_EMU_COORDINATOR=<pid> picks the (takeover)
         coordinator id.
 
     Returns the attached transport/mirror, or None when

@@ -11,8 +11,8 @@ Three parts, one session object tying them together:
     permanently on, and `ServerState` bits are provably unchanged;
   * `journal` — an append-only JSONL event log in the run dir
     recording round/span metrics, wall-clock spans, checkpoint saves,
-    XLA compile events, retry attempts, and injected faults; bench
-    harnesses append their digests in the same schema;
+    XLA compile events, retry attempts, and injected faults; the
+    analysis tiers append their digests in the same schema;
   * `clients` — per-client EMA throughput + participation, persisted
     in the checkpoint resume-bit-exact: the measurement substrate for
     the ROADMAP's deadline-estimation and straggler-aware-sampling
@@ -145,10 +145,8 @@ def attach_run_telemetry(model, cfg, log_dir: str, coord: bool,
         trace=bool(getattr(cfg, "trace", False)),
         dataset=cfg.dataset_name, num_workers=cfg.num_workers,
         num_clients=model.num_clients, grad_size=model.cfg.grad_size,
-        # compression-kernel provenance (ISSUE 6): a journal reader
-        # attributing up_bytes or round timings needs to know which
-        # backend ran and what dtype rode the wire
-        kernel_backend=cfg.kernel_backend,
+        # a journal reader attributing up_bytes needs to know what
+        # dtype rode the wire
         sketch_table_dtype=cfg.sketch_table_dtype,
         # residency provenance (ISSUE 11): a reader of state_tier
         # events needs the tier and working-set cap in the run record
@@ -220,8 +218,7 @@ class TelemetrySession:
     def _safe_write(self, write: Callable[[], object]) -> None:
         """Observability must never kill training: a journal append
         that fails (disk full, unwritable path mid-run) warns once and
-        the run continues — the same contract bench.journal_digest
-        keeps for measurements. Notably the retry hook journals from
+        the run continues. Notably the retry hook journals from
         INSIDE utils/retry.with_retries; an exception there would turn
         a recoverable transient into a fatal span failure."""
         try:

@@ -11,11 +11,9 @@ graftlint GL011 holds that line in code, `mono` holds it in the
 record format). `mono` values share a base only within one process
 lifetime: consumers must reset delta tracking at each `run_start`. One schema serves every
 producer: training runs (round/span metrics, checkpoint saves, XLA
-compile events, retry attempts, injected faults), bench harnesses
-(bench.py / benchmarks/profile_round.py append their digests as
-`bench_digest` / `profile_digest` events), and future tooling, so a
-perf investigation reads ONE record format instead of correlating
-stdout tables with BENCH_*.json by hand.
+compile events, retry attempts, injected faults) and the analysis
+tiers (scripts/audit.sh, num.sh, sync.sh append their reports as
+`*_audit_digest` events), so an investigation reads ONE record format.
 
 Durability: appends route through utils/atomic_io.atomic_append_line
 (flush + fsync per record); a preemption can tear at most the final
@@ -111,7 +109,6 @@ consumers must tolerate kinds they don't know):
                           (federated/statestore) and the row was
                           re-initialized from its init base —
                           `client`, `field`
-  bench_digest / profile_digest  bench harness result records
   audit_digest            graftaudit's static cost report
                           (analysis/audit): sha256 `digest`,
                           per-program `programs` {flops, hbm_bytes},
@@ -425,7 +422,7 @@ class RunJournal:
 
 def append_event(path: str, kind: str, /, **fields) -> dict:
     """One-shot append for producers without a long-lived journal
-    (bench harness digests)."""
+    (the analysis tiers' digests)."""
     return RunJournal(path).event(kind, **fields)
 
 
@@ -508,7 +505,7 @@ def validate_journal(path: str,
       * `state_tier` events (tiered client state, ISSUE 11) carry
         non-negative integer hits/misses/spills/restores and
         non-negative spill_bytes/restore_bytes/resident/working_set —
-        the residency record the BENCH_r11 working-set table reads;
+        the residency record of a working-set run;
       * `trace` events (graftscope, telemetry/trace.py) carry a list
         `spans` of objects each with a string `name`, string
         `thread`, numeric non-negative `t0` (monotonic seconds) and
@@ -529,13 +526,13 @@ def validate_journal(path: str,
       * `sync_audit_digest` events (graftsync concurrency reports,
         analysis/syncaudit) carry a 64-hex string `digest`, a `rules`
         object mapping each SY rule to a non-negative integer count,
-        and a non-negative integer `findings` — the record tier1's
-        sync step journals, so its shape must not rot;
+        and a non-negative integer `findings` — the record scripts/sync.sh
+        journals, so its shape must not rot;
       * `num_audit_digest` events (graftnum numerics reports,
         analysis/numaudit) carry the same 64-hex `digest` / `rules`
         counts / optional `findings` shape plus a `ulp` object
         mapping each audited program to a non-negative integer
-        worst-case reassociation bound — the record tier1's NUM step
+        worst-case reassociation bound — the record scripts/num.sh
         journals, so its shape must not rot;
       * `screened` events (ISSUE 16 value-fault admission) carry an
         integer `round`, a non-negative integer `n_screened`, and a
@@ -681,7 +678,7 @@ def validate_journal(path: str,
                 _comm_field(rec, n, field)
         if rec.get("event") == "screened":
             # value-fault admission (ISSUE 16): the record the drill
-            # matrix and the tier1 poisoned smoke read, so its shape
+            # matrix and a poisoned smoke read, so its shape
             # must not rot
             if not isinstance(rec.get("round"), int):
                 problems.append(
@@ -699,7 +696,7 @@ def validate_journal(path: str,
                     f"string `kind` (got {k2!r})")
         if rec.get("event") == "aggregator":
             # robust aggregation (ISSUE 17): the record the drill
-            # matrix and the tier1 adversarial smoke read
+            # matrix and an adversarial smoke read
             if not isinstance(rec.get("round"), int):
                 problems.append(
                     f"record {n}: aggregator event without an integer "
@@ -767,7 +764,7 @@ def validate_journal(path: str,
                     f"(got {rec.get('clamped')!r})")
         if rec.get("event") == "privacy":
             # differential privacy (ISSUE 19): the budget record the
-            # tier1 dp smoke's monotone-epsilon gate reads, so its
+            # monotone-epsilon gate of a dp smoke reads, so its
             # shape — and the monotonicity itself — must not rot
             if not isinstance(rec.get("round"), int):
                 problems.append(

@@ -392,9 +392,7 @@ def device_busy_wall(spans: List[dict]
 def overlap_efficiency(spans: List[dict]) -> Optional[float]:
     """Pipeline overlap efficiency: device-busy time / wall time over
     one trace segment. 1.0 means the device never waited on host
-    staging or persistence; the sync baseline measured ~0.79x cadence
-    at BENCH_r10 — this turns that one-off claim into a
-    continuously-measured number. For multi-segment journals
+    staging or persistence. For multi-segment journals
     (resume/takeover), summarize() sums device_busy_wall per segment
     instead of calling this across segments."""
     bw = device_busy_wall(spans)

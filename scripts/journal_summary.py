@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Validate + summarize a telemetry run journal (JSONL).
 
-The cheap CI check of the journal invariants (ISSUE 4 satellite):
-scripts/tier1.sh runs a tiny driver smoke with the journal on and then
-this tool over the result — a malformed line, a wrong schema version,
+The cheap check of the journal invariants (ISSUE 4 satellite): run a
+tiny driver smoke with the journal on and then this tool over the
+result — a malformed line, a wrong schema version,
 or a duplicate/out-of-order round event fails the build, so the record
 format every perf investigation depends on cannot silently rot.
 
@@ -12,8 +12,8 @@ totals (`down_bytes`/`up_bytes` on round events) must be non-negative
 numbers whose `run_end` cumulative covers the per-round sums, and
 `schedule` events (the round scheduler's decisions) must carry an
 integer round + sampler name with non-negative deadline/estimate
-payloads. tier1.sh runs a SECOND smoke under `--sampler throughput
---deadline_quantile 0.9` so those records are exercised in CI; the
+payloads (a smoke under `--sampler throughput --deadline_quantile
+0.9` writes those records); the
 summary line includes down_mib/up_mib and the deadline-round count.
 
 ISSUE 13 (graftscope): journals from `--trace` runs additionally
@@ -37,7 +37,7 @@ adjustment — are schema-checked (integer `round`, `controller`
 registered in analysis.domains.CONTROL_FIELDS, numeric
 `signal`/`old`/`new`, boolean `clamped`), and the summary grows a
 `controllers` block with per-controller adjustment/clamp counts and
-the final value, so the tier1 self-tuning smoke can gate on "every
+the final value, so a self-tuning smoke can gate on "every
 controller actually moved" from one summary read.
 
 Usage:

@@ -5,7 +5,7 @@
 # `--no-baseline`, `--write-baseline`, `--report`, `--journal`).
 #
 # Unlike graftlint/graftsync this pass traces: it walks every
-# registered round program's ClosedJaxpr (both kernel backends, the
+# registered round program's ClosedJaxpr (the round variants, the
 # state-motion programs, and the scanned span) with a dtype/finiteness
 # dataflow lattice — NaN-unsafe mask arithmetic, the PRECISION_SEAMS
 # downcast registry, zero-guarded denominators, replay-determinism —

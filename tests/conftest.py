@@ -87,8 +87,8 @@ def num_sanitizer():
 
 @pytest.fixture(autouse=True)
 def _num_sanitize(request):
-    """CCTPU_NUM_SANITIZE=1 (scripts/tier1.sh arms this over the
-    valuefaults/byzantine suites) runs EVERY test with graftnum's
+    """CCTPU_NUM_SANITIZE=1 (set it by hand over `pytest -m
+    "valuefaults or byzantine"`) runs EVERY test with graftnum's
     runtime twin installed: exported round metrics pass a
     post-dispatch finite guard, so poison that screening or robust
     aggregation should have absorbed but that leaked into telemetry
@@ -120,8 +120,8 @@ def _num_sanitize(request):
 
 @pytest.fixture(autouse=True)
 def _sync_sanitize():
-    """CCTPU_SYNC_SANITIZE=1 (scripts/tier1.sh arms this over the
-    pipeline/statetier/controlplane suites) runs EVERY test under the
+    """CCTPU_SYNC_SANITIZE=1 (set it by hand over `pytest -m
+    "pipeline or statetier or controlplane"`) runs EVERY test under the
     LockOrderSanitizer plus deterministic queue-handoff delay
     injection (analysis/runtime.interleaving_stress), and asserts the
     observed lock graph acyclic at teardown. Off by default: the

@@ -5,7 +5,7 @@ the second analysis tier:
   * the TREE audits clean against the SHIPPED baseline — the
     committed `audit.baseline.json` must match what the auditor finds
     and prices right now (the CI gate, run here so `pytest` alone
-    catches a drifted baseline before tier1.sh does);
+    catches a drifted baseline before scripts/audit.sh does);
   * seeded POSITIVE CONTROLS — each violation class (forbidden
     primitive, f64, large exact top-k/sort, population-shaped
     intermediate, undonated dead input, cost drift) must fire with
@@ -68,21 +68,13 @@ def test_shipped_baseline_has_no_unjustified_violations():
             f"unjustified baseline entry: {program} {rule} x{count}")
 
 
-def test_audit_covers_programs_and_backends(full_audit):
+def test_audit_covers_programs(full_audit):
     report, _ = full_audit
     for cfg_name, _cfg in A.audit_configs():
         # per-config program family (ISSUE 16): sketch-screened traces
         # the two screened variants, every other config the defaults
         for variant in program_variants_for(_cfg):
             assert f"{cfg_name}/{variant}" in report["programs"]
-    # the pallas configs really traced pallas kernels (the dispatch
-    # gate engaged — otherwise the backend column in PERF.md lies)
-    cfg = dict(A.audit_configs())["sketch-pallas"]
-    handle, server, clients, variants, lr, key = A.build_workload(cfg)
-    closed, _, _ = A.trace_variant(handle, server, clients,
-                                   variants["mask_free"], lr, key)
-    prims = {e.primitive.name for e in A.iter_eqns(closed)}
-    assert "pallas_call" in prims
 
 
 def test_population_inventory_names_the_client_state(full_audit):
@@ -93,7 +85,7 @@ def test_population_inventory_names_the_client_state(full_audit):
     # ISSUE 9: the ROUND programs are population-free — empty
     # inventory on the jitted-round side for every audit config (the
     # refactor's mechanical definition of done)
-    for cfg_name in ("client-state", "sketch-xla", "sketch-pallas"):
+    for cfg_name in ("client-state", "sketch"):
         for variant in ("mask_free", "dropout", "dropout_stragglers"):
             inv = report["programs"][f"{cfg_name}/{variant}"][
                 "population_inventory"]
@@ -114,7 +106,7 @@ def test_population_inventory_names_the_client_state(full_audit):
     for e in g["inputs"] + s["inputs"] + s["outputs"]:
         assert e["shape"][0] == A.AUDIT_POPULATION
     # the stateless sketch configs' state-motion programs move nothing
-    sk = report["programs"]["sketch-xla/gather"][
+    sk = report["programs"]["sketch/gather"][
         "population_inventory"]
     assert sk["inputs"] == [] and sk["outputs"] == []
 
@@ -122,8 +114,8 @@ def test_population_inventory_names_the_client_state(full_audit):
 def test_cost_report_bit_identical_across_runs():
     """Acceptance: the journaled cost report reproduces bit-identically
     — two fully independent audits must agree on the digest."""
-    r1, _ = A.run_audit(backends=["xla"])
-    r2, _ = A.run_audit(backends=["xla"])
+    r1, _ = A.run_audit()
+    r2, _ = A.run_audit()
     assert r1["digest"] == r2["digest"]
     assert r1["costs"] == r2["costs"]
 
@@ -228,10 +220,10 @@ def test_au004_population_intermediate_fires():
 
 
 def test_au005_undonated_dead_inputs_fire():
-    cfg = dict(A.audit_configs())["sketch-xla"]
+    cfg = dict(A.audit_configs())["sketch"]
     handle, *_ = A.build_workload(
         cfg.replace(donate_round_state=False))
-    findings = A.donation_findings("sketch-xla", handle)
+    findings = A.donation_findings("sketch", handle)
     assert {v.rule for v in findings} == {"AU005"}
     # per-round cohort + scatter-back clients + scanned server +
     # scanned clients
@@ -241,7 +233,7 @@ def test_au005_undonated_dead_inputs_fire():
                              + len(SPAN_DEAD_ARGNUMS))
     # with donation wired (the default) the same config is clean
     handle_on, *_ = A.build_workload(cfg)
-    assert A.donation_findings("sketch-xla", handle_on) == []
+    assert A.donation_findings("sketch", handle_on) == []
 
 
 def test_au006_cost_drift_new_and_stale_fire(full_audit):

@@ -47,13 +47,12 @@ def rules_of(findings):
 
 @pytest.fixture(scope="module")
 def audit_report():
-    """ONE full tree audit (both backends — the baseline's program
-    set), shared by the tree-clean / digest / journal gates below.
+    """ONE full tree audit (the baseline's program set), shared by the tree-clean / digest / journal gates below.
     ~seconds on CPU: every program the engine registers is traced."""
     cwd = os.getcwd()
     os.chdir(REPO)
     try:
-        report, findings = run_num_audit(("xla", "pallas"))
+        report, findings = run_num_audit()
     finally:
         os.chdir(cwd)
     return report, findings
@@ -232,7 +231,7 @@ from commefficient_tpu.analysis.audit import (
 )
 from commefficient_tpu.analysis.numaudit import lattice_findings
 
-cfg = dict(audit_configs(("xla",)))["sketch-screened"]
+cfg = dict(audit_configs())["sketch-screened"]
 handle, server, clients, variants, lr, key = build_workload(cfg)
 closed, _, _ = trace_variant(
     handle, server, clients, variants["screened"], lr, key)
@@ -322,8 +321,8 @@ def test_digest_bit_identical_across_independent_runs(audit_report):
     cwd = os.getcwd()
     os.chdir(REPO)
     try:
-        r1, _ = run_num_audit(("xla",))
-        r2, _ = run_num_audit(("xla",))
+        r1, _ = run_num_audit()
+        r2, _ = run_num_audit()
     finally:
         os.chdir(cwd)
     assert r1["digest"] == r2["digest"]
@@ -369,24 +368,6 @@ def test_journaled_num_digest_validates(audit_report, tmp_path):
     _, problems = validate_journal(path)
     assert any("64-char" in p for p in problems)
     assert any("ulp" in p for p in problems)
-
-
-def test_bench_digest_carries_static_ulp_bounds(tmp_path, monkeypatch):
-    """ISSUE 18 satellite: bench records get the per-program
-    worst-case ulp bound from the shipped baseline — the static twin
-    next to the measured metric."""
-    import bench
-    jpath = str(tmp_path / "bench.jsonl")
-    monkeypatch.setenv("BENCH_JOURNAL", jpath)
-    monkeypatch.chdir(REPO)
-    bench.journal_digest({"metric": "m", "value": 1.5,
-                          "platform": "cpu"}, "bench_digest")
-    from commefficient_tpu.telemetry.journal import validate_journal
-    records, problems = validate_journal(jpath)
-    assert not problems, problems
-    bounds = records[0]["digest"]["worst_case_ulp"]
-    assert bounds["per_program"] and bounds["max"] > 0
-    assert bounds["max"] == max(bounds["per_program"].values())
 
 
 # ---------------------------------------------------------------------------
@@ -467,7 +448,7 @@ def test_replay_drill_on_a_real_round_program():
         audit_configs, build_workload,
     )
     from commefficient_tpu.analysis.runtime import NumericSanitizer
-    cfg = dict(audit_configs(("xla",)))["sketch-xla"]
+    cfg = dict(audit_configs())["sketch"]
     handle, server, clients, variants, lr, key = build_workload(cfg)
     batch = variants["mask_free"]
     cohort = handle.gather_fn(clients, batch.client_ids)

@@ -699,7 +699,7 @@ def test_lock_sanitizer_uninstall_restores_factories():
 
 
 def test_real_writers_green_under_sanitizer_and_stress(tmp_path):
-    """The armed configuration tier1 runs: the async journal writer
+    """The armed configuration (CCTPU_SYNC_SANITIZE=1): the async journal writer
     and the checkpoint writer driven from two producer threads under
     the LockOrderSanitizer + deterministic queue-handoff stress.
     Green means: no lock-order cycle, every record durable, FIFO

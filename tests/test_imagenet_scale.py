@@ -2,7 +2,7 @@
 #7): demonstrate that `max_local_batch` bounds the staging arrays at
 ResNet50/224px shapes and that the round engine traces the full
 FixupResNet50 training step at those shapes — the configuration of the
-committed launch recipe (benchmarks/imagenet.sh, mirroring the
+committed launch recipe (scripts/imagenet.sh, mirroring the
 reference's tuned CommEfficient/imagenet.sh:2-21).
 
 The real-data run needs an ImageNet on disk and a TPU pod; what is

@@ -68,7 +68,7 @@ def test_tree_audits_clean_against_shipped_baseline(full_mesh_audit):
     assert baseline.apply_costs(report["links"], tolerance=0.0) == []
 
 
-def test_report_covers_programs_meshes_backends(full_mesh_audit):
+def test_report_covers_programs_meshes(full_mesh_audit):
     report, _ = full_mesh_audit
     assert set(report["meshes"]) == {"clients8", "clients4_model2",
                                      "multislice2"}
@@ -93,12 +93,12 @@ def test_multislice_report_splits_traffic(full_mesh_audit):
     ICI on the flat mesh and a DCN component on the slice-major one —
     with exactly one table-sized DCN reduction per round."""
     report, _ = full_mesh_audit
-    flat = report["links"]["sketch-xla/mask_free@clients8"]
-    ms = report["links"]["sketch-xla/mask_free@multislice2"]
+    flat = report["links"]["sketch/mask_free@clients8"]
+    ms = report["links"]["sketch/mask_free@multislice2"]
     assert flat["dcn_bytes"] == 0 and flat["dcn_collectives"] == 0
     assert ms["dcn_bytes"] > 0 and ms["dcn_collectives"] > 0
     # the span prices SPAN_LEN rounds of the same collectives
-    span = report["links"]["sketch-xla/span@multislice2"]
+    span = report["links"]["sketch/span@multislice2"]
     assert span["dcn_bytes"] == M.SPAN_LEN * ms["dcn_bytes"]
 
 
@@ -276,18 +276,18 @@ def test_exit_code_contract():
 def test_cli_exit_codes(tmp_path):
     """End-to-end: clean against the shipped baseline -> 0; a
     perturbed baseline -> 2 (drift, not violation)."""
-    rc = M.main(["--meshes", "clients8", "--backends", "xla",
+    rc = M.main(["--meshes", "clients8",
                  "--write-baseline", "--baseline",
                  str(tmp_path / "b.json")])
     assert rc == 0
-    rc = M.main(["--meshes", "clients8", "--backends", "xla",
+    rc = M.main(["--meshes", "clients8",
                  "--baseline", str(tmp_path / "b.json")])
     assert rc == 0
     doc = json.loads((tmp_path / "b.json").read_text())
     key = next(iter(doc["links"]))
     doc["links"][key]["ici_bytes"] += 1
     (tmp_path / "b.json").write_text(json.dumps(doc))
-    rc = M.main(["--meshes", "clients8", "--backends", "xla",
+    rc = M.main(["--meshes", "clients8",
                  "--baseline", str(tmp_path / "b.json")])
     assert rc == 2
 

@@ -28,9 +28,9 @@ def _no_transfers(sanitize):
         yield
 
 
-GPT2_D = 123_756_289      # GPT2-small double-heads (bench_gpt2.py)
-LTK_D = 5_252_388         # PreAct ResNet18 / CIFAR100 (bench_local_topk.py)
-FLAGSHIP_D = 6_568_640    # ResNet9 / CIFAR10 (bench.py)
+GPT2_D = 123_756_289      # GPT2-small double-heads
+LTK_D = 5_252_388         # PreAct ResNet18 / CIFAR100
+FLAGSHIP_D = 6_568_640    # ResNet9 / CIFAR10
 
 
 def gpt2_cfg():

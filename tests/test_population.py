@@ -475,9 +475,8 @@ def test_run_audit_inventory_opt_out():
     from commefficient_tpu.analysis import audit as A
 
     report, findings = A.run_audit(
-        backends=["xla"],
-        inventory_configs=["sketch-xla", "client-state"])
+        inventory_configs=["sketch", "client-state"])
     assert findings == []
-    strict_report, strict_findings = A.run_audit(backends=["xla"])
+    strict_report, strict_findings = A.run_audit()
     assert strict_findings == []
     assert report["costs"] == strict_report["costs"]

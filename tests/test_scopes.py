@@ -2,8 +2,8 @@
 round, gather, scatter and pack programs carry every scope name their
 mode has; scopes are metadata, so the lowered program text without
 locations, the three-program dispatch, its composed twin and three
-rounds' ServerState are what they are without them; the five
-pallas_calls are named."""
+rounds' ServerState are what they are without them; the
+pallas_call is named."""
 import contextlib
 import re
 
@@ -157,17 +157,12 @@ def test_scopes_are_metadata_three_rounds_bit_identical(mode, no_scopes):
 
 
 def test_pallas_calls_are_named():
-    """A kernel is found in a trace by name: the flash forward and
-    the four sketch kernels pass `name=` to `pallas_call`."""
+    """A kernel is found in a trace by name: the flash forward, the
+    repo's one kernel, passes `name=` to `pallas_call`."""
     import inspect
 
     from commefficient_tpu.ops import attention
-    from commefficient_tpu.ops.kernels import sketch_pallas
 
-    assert 'name="flash_fwd"' in inspect.getsource(attention)
-    src = inspect.getsource(sketch_pallas)
-    for name in ("sketch_encode", "sketch_estimate_all",
-                 "sketch_threshold_sample", "sketch_threshold_mask"):
-        assert f'name="{name}"' in src
-    assert len(re.findall(r"pl\.pallas_call\(", src)) == len(
-        re.findall(r"\bname=\"sketch_", src)) == 4
+    src = inspect.getsource(attention)
+    assert 'name="flash_fwd"' in src
+    assert len(re.findall(r"pl\.pallas_call\(", src)) == 1

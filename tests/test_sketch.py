@@ -142,6 +142,161 @@ def test_threshold_decode_sparser_than_k(monkeypatch):
     assert len(np.nonzero(out)[0]) <= 5 * s.r
 
 
+# ---------------------------------------------------------------------------
+# the routes of one geometry agree: static unroll against the scan
+# above STATIC_UNROLL_LIMIT, materialised against blockwise decode,
+# and each against the hash that defines the sketch
+
+GEOMETRIES = [
+    dict(d=1000, c=200, r=5, num_blocks=3),   # padded tail, odd r
+    dict(d=512, c=128, r=4, num_blocks=1),    # exact fit, even r
+    dict(d=300, c=400, r=3, num_blocks=2),    # single chunk, c > d
+]
+geometries = pytest.mark.parametrize(
+    "geom", GEOMETRIES, ids=["padded", "exact", "one_chunk"])
+
+
+def _vec(d, seed):
+    return np.random.RandomState(seed).randn(d).astype(np.float32)
+
+
+def _both_routes(monkeypatch, s, fn):
+    """(fn() with sketch `s` on the static route, fn() on the scan)."""
+    import commefficient_tpu.ops.sketch as sketch_mod
+    assert s._static_path
+    static = fn()
+    monkeypatch.setattr(sketch_mod, "STATIC_UNROLL_LIMIT", 0)
+    assert not s._static_path
+    return static, fn()
+
+
+@geometries
+def test_encode_static_matches_scan(geom, monkeypatch):
+    s = CSVec(**geom)
+    v = jnp.asarray(_vec(s.d, 1))
+    static, scan = _both_routes(
+        monkeypatch, s, lambda: np.asarray(s.encode(v)))
+    # the same terms summed chunk by chunk in both: a few ulp apart
+    np.testing.assert_allclose(static, scan, rtol=1e-6, atol=1e-6)
+
+
+@geometries
+def test_encode_matches_hash_definition(geom):
+    # table[j, bucket_j(i)] += sign_j(i) * v[i], coordinate by
+    # coordinate, in float64: what encode's rotations must amount to
+    s = CSVec(**geom)
+    v = _vec(s.d, 2)
+    buckets, signs = (np.asarray(a) for a in
+                      s.hash_indices(jnp.arange(s.d, dtype=jnp.int32)))
+    want = np.zeros(s.table_shape, np.float64)
+    for j in range(s.r):
+        np.add.at(want[j], buckets[j], signs[j].astype(np.float64) * v)
+    np.testing.assert_allclose(np.asarray(s.encode(jnp.asarray(v))), want,
+                               rtol=1e-5, atol=1e-5)
+
+
+@geometries
+def test_estimate_all_static_matches_scan(geom, monkeypatch):
+    s = CSVec(**geom)
+    t = s.encode(jnp.asarray(_vec(s.d, 3)))
+    static, scan = _both_routes(
+        monkeypatch, s, lambda: np.asarray(s.estimate_all(t)))
+    assert static.shape == (s.n_chunks, s.c)
+    # un-rotation, a sign and a median: no sum, so bit for bit
+    np.testing.assert_array_equal(static, scan)
+
+
+@geometries
+def test_estimate_all_matches_per_coordinate_estimate(geom):
+    s = CSVec(**geom)
+    t = s.encode(jnp.asarray(_vec(s.d, 4)))
+    np.testing.assert_array_equal(
+        np.asarray(s.estimate_all(t)).reshape(-1)[: s.d],
+        np.asarray(s.estimate(t, jnp.arange(s.d))))
+
+
+def test_zero_offsets_rotate_by_a_whole_row(monkeypatch):
+    # offset 0 is the boundary of both rotations: the scan's _rotate
+    # slices the doubled row at c - 0 == c, its last legal start, and
+    # _unrotate at 0. Every offset forced to 0, so the boundary is hit
+    # on purpose and not left to the seed's draws; the table is then
+    # the signed sum of the chunks, which numpy can say directly.
+    s = CSVec(d=600, c=128, r=3, num_blocks=1)
+    object.__setattr__(s, "_offsets", np.zeros_like(s._offsets))
+    v = _vec(s.d, 11)
+    chunks = np.pad(v, (0, s.n_chunks * s.c - s.d)).reshape(-1, s.c)
+    want = np.einsum("rb,rc,bc->rc", s._delta, s._eps, chunks)
+
+    def both():
+        t = s.encode(jnp.asarray(v))
+        return np.asarray(t), np.asarray(s.estimate_all(t))
+
+    (t_static, e_static), (t_scan, e_scan) = _both_routes(
+        monkeypatch, s, both)
+    np.testing.assert_allclose(t_static, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(t_scan, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(e_static, e_scan, rtol=1e-6, atol=1e-6)
+
+
+def test_decode_topk_sparse_materialized_matches_blockwise(monkeypatch):
+    # planted heavy hitters, several in one chunk: the blockwise route
+    # (per-chunk candidates, then one top-k over the survivors) must
+    # return what the single select over all estimates returns
+    s = CSVec(d=5000, c=1000, r=5, num_blocks=4)
+    rng = np.random.RandomState(3)
+    v = np.zeros(s.d, np.float32)
+    hot = np.concatenate([rng.choice(1000, 8, replace=False),
+                          1000 + rng.choice(4000, 12, replace=False)])
+    v[hot] = rng.choice([-1.0, 1.0], 20) * (5.0 + np.arange(20))
+    t = s.encode(jnp.asarray(v))
+
+    def decode():
+        idx, vals = (np.asarray(a) for a in s.decode_topk_sparse(t, k=20))
+        order = np.argsort(idx)
+        return idx[order], vals[order]
+
+    (i_mat, v_mat), (i_blk, v_blk) = _both_routes(monkeypatch, s, decode)
+    np.testing.assert_array_equal(i_mat, np.sort(hot))
+    np.testing.assert_array_equal(i_mat, i_blk)
+    np.testing.assert_array_equal(v_mat, v_blk)
+
+
+def test_threshold_decode_over_scan_estimates(monkeypatch):
+    # decode_topk_dense's own gate, lowered, on a sketch whose
+    # estimates come from the scan: a 10-sparse vector decodes exactly
+    # (the threshold floors at f32-tiny and keeps just the nonzeros)
+    import commefficient_tpu.ops.sketch as sketch_mod
+    monkeypatch.setattr(sketch_mod, "THRESHOLD_DECODE_MIN_D", 1000)
+    monkeypatch.setattr(sketch_mod, "STATIC_UNROLL_LIMIT", 0)
+    s = CSVec(d=20000, c=5000, r=5, num_blocks=4)
+    assert s._threshold_decode and not s._static_path
+    rng = np.random.RandomState(9)
+    v = np.zeros(s.d, np.float32)
+    hot = rng.choice(s.d, 10, replace=False)
+    v[hot] = rng.choice([-1.0, 1.0], 10) * (5.0 + rng.rand(10))
+    out = np.asarray(s.decode_topk_dense(s.encode(jnp.asarray(v)), k=10))
+    np.testing.assert_allclose(out, v, atol=1e-4)
+
+
+def test_threshold_decode_chunk_narrower_than_stride(monkeypatch):
+    # the sample strides over the flat estimates, not over chunks: at
+    # a stride of two chunks every sample comes from another chunk and
+    # half the chunks give none, and the heavy hitters still come back
+    import commefficient_tpu.ops.flat as flat_mod
+    import commefficient_tpu.ops.sketch as sketch_mod
+    monkeypatch.setattr(sketch_mod, "THRESHOLD_DECODE_MIN_D", 1000)
+    monkeypatch.setattr(flat_mod, "_TOPK_SAMPLE", 8)
+    s = CSVec(d=4096, c=256, r=5, num_blocks=1)
+    assert s._threshold_decode and s.d // 8 == 2 * s.c
+    v = np.zeros(s.d, np.float32)
+    hot = [5, 900, 3500]
+    v[hot] = [7.0, -6.0, 5.0]
+    # jitted: one compile in place of one per static shift
+    out = np.asarray(jax.jit(
+        lambda x: s.decode_topk_dense(s.encode(x), k=3))(jnp.asarray(v)))
+    np.testing.assert_allclose(out, v, atol=1e-4)
+
+
 def test_l2estimate():
     s = CSVec(d=10000, c=5000, r=5, num_blocks=4)
     rng = np.random.RandomState(4)

@@ -48,7 +48,7 @@ from commefficient_tpu.utils.checkpoint import (
 )
 from commefficient_tpu.utils.faults import FaultSchedule, InjectedFault
 
-# the suite tier1.sh re-runs under the LockOrderSanitizer +
+# a suite worth re-running under the LockOrderSanitizer +
 # interleaving stress (CCTPU_SYNC_SANITIZE=1) — the spill writer is
 # the lock-richest path in the tree
 pytestmark = pytest.mark.statetier

@@ -333,24 +333,6 @@ def test_journal_summary_cli(tmp_path):
     assert js.main([str(tmp_path / "missing.jsonl")]) == 2
 
 
-def test_bench_digest_shares_schema(tmp_path, monkeypatch):
-    """bench.py's journal_digest writes the same versioned record
-    format training runs produce."""
-    import bench
-    jpath = str(tmp_path / "bench.jsonl")
-    monkeypatch.setenv("BENCH_JOURNAL", jpath)
-    bench.journal_digest({"metric": "m", "value": 1.5,
-                          "platform": "cpu"}, "bench_digest")
-    records, problems = validate_journal(jpath)
-    assert not problems, problems
-    assert records[0]["event"] == "bench_digest"
-    assert records[0]["v"] == 1
-    assert records[0]["digest"]["value"] == 1.5
-    monkeypatch.setenv("BENCH_JOURNAL", "0")
-    bench.journal_digest({"metric": "m"}, "bench_digest")
-    assert len(validate_journal(jpath)[0]) == 1  # disabled -> no append
-
-
 def test_round_comm_bytes_journaled(tmp_path):
     """ISSUE 5 satellite: the accountant's per-round byte totals ride
     the round events (per-round path) and run_end carries the
