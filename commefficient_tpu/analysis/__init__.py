@@ -9,14 +9,16 @@ mechanical:
 
   * `engine` + `rules` — an AST lint pass (``python -m
     commefficient_tpu.analysis <paths>``) with JAX-specific rules
-    GL001-GL013: host nondeterminism reachable from traced code, hidden
+    GL001-GL015: host nondeterminism reachable from traced code, hidden
     host syncs / trace breaks, PRNG key reuse, Python control flow over
     traced values, fault-swallowing broad ``except`` handlers,
     non-atomic file writes, unconstrained shard_map/pjit layouts,
     large exact top-k, PRNG domain tags outside the `domains`
     registry, mesh-axis names outside its MESH_AXES registry,
-    wall-clock durations, anonymous threads, and float equality on
-    traced values (the exact-zero sparsity test stays legal).
+    wall-clock durations, anonymous threads, float equality on
+    traced values (the exact-zero sparsity test stays legal),
+    controller wire fields outside its CONTROL_FIELDS registry, and
+    strided subscripts in the traced packages (a gather on jax 0.9.0).
     Per-line ``# graftlint: disable=GLxxx`` suppressions and
     a baseline file grandfather justified hits.
   * `audit` + `costmodel` — the SECOND tier (``graftaudit``, ISSUE 7):

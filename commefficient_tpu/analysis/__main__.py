@@ -22,7 +22,7 @@ def main(argv: Optional[list] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="graftlint",
         description="trace-safety static analysis for the round engine "
-                    "(rules GL001-GL012; see --list-rules)")
+                    "(rules GL001-GL015; see --list-rules)")
     ap.add_argument("paths", nargs="*",
                     default=conf.get("paths", ["commefficient_tpu"]),
                     help="files/directories to lint")

@@ -1,4 +1,4 @@
-"""graftlint rules GL001-GL010.
+"""graftlint rules GL001-GL015.
 
 Each rule is a function ``check(module: ModuleInfo) -> Iterator[
 Violation]`` over one parsed file. The rules are deliberately
@@ -603,6 +603,52 @@ def check_gl008(module: ModuleInfo) -> Iterator[Violation]:
 
 
 # ---------------------------------------------------------------------------
+# GL015 — strided subscripts in the traced packages
+
+# On jax 0.9.0 a `jnp` subscript with a step (`x[::n]`, `x[a:b:n]`)
+# does not trace to a strided slice: it traces to iota -> mul ->
+# `gather`, one index lookup per element kept, and under a `vmap`
+# the chip's compiler runs it that way (ops/flat's top-k sample,
+# `sq[::6]` at [16, 6568640]: 1,094,774 lookups, 17.6 ms of a 64.5 ms
+# round on a v5e; PERF.md section 6, PR 34). `jax.lax.slice(x, start,
+# limit, strides)` is one `slice` instruction over the same elements.
+# Path-scoped like GL010, to the packages whose arrays are traced
+# (ops/, federated/, compress/), and over the whole file: their
+# kernels are module-level functions no transform names in the same
+# file, so lexical tracedness would miss exactly the site that cost.
+# A literal step of 1 (no op) or -1 (`rev`) is left alone; a host-side
+# numpy stride in these packages takes a `# graftlint: disable=GL015`.
+_GL015_SCOPES = ("/ops/", "/federated/", "/compress/")
+
+
+def check_gl015(module: ModuleInfo) -> Iterator[Violation]:
+    path = "/" + module.path.replace(os.sep, "/")
+    if not any(scope in path for scope in _GL015_SCOPES):
+        return
+    for node in ast.walk(module.tree):
+        if not isinstance(node, ast.Subscript):
+            continue
+        dims = (node.slice.elts if isinstance(node.slice, ast.Tuple)
+                else [node.slice])
+        for dim in dims:
+            if not isinstance(dim, ast.Slice) or dim.step is None:
+                continue
+            try:
+                if ast.literal_eval(dim.step) in (1, -1):
+                    continue
+            except ValueError:
+                pass    # a computed step: not a literal
+            yield Violation(
+                module.path, node.lineno, node.col_offset, "GL015",
+                f"subscript with a step `{module.segment(node)}`: on "
+                "jax 0.9.0 a strided `jnp` index traces to iota -> "
+                "`gather` (one lookup per element kept; 17.6 ms of a "
+                "64.5 ms round at ops/flat's top-k sample), not to a "
+                "strided slice; use `jax.lax.slice(x, start, limit, "
+                "strides)`")
+
+
+# ---------------------------------------------------------------------------
 # GL009 — PRNG-domain constants outside the central registry
 
 # The engine's deterministic-replay story separates the dropout /
@@ -1053,6 +1099,7 @@ ALL_RULES = {
     "GL012": check_gl012,
     "GL013": check_gl013,
     "GL014": check_gl014,
+    "GL015": check_gl015,
 }
 
 RULE_DOCS = {
@@ -1090,4 +1137,7 @@ RULE_DOCS = {
     "GL014": "controller plan wire field outside the analysis/domains "
              "CONTROL_FIELDS registry (unregistered WIRE_FIELD class "
              "attribute, or a registry collision)",
+    "GL015": "subscript with a step (x[::n]) in ops/, federated/ or "
+             "compress/ — jax 0.9.0 traces it to a gather, not a "
+             "strided slice; use jax.lax.slice",
 }

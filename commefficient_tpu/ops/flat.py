@@ -125,13 +125,21 @@ def sampled_threshold_mask(v: jax.Array, k: int) -> jax.Array:
     the realized support, which is why local_topk accounting records
     the realized nonzero count next to the analytic k
     (federated/accounting.CommAccountant.realized_nonzeros) — a tie
-    blowout shows up there instead of silently under-billing."""
+    blowout shows up there instead of silently under-billing.
+
+    The sample is a `jax.lax.slice`, squared after it is taken: on
+    jax 0.9.0 `sq[::stride]` traces to iota -> gather, which under the
+    round's per-client vmap the chip's compiler runs as one index
+    lookup per sampled coordinate (17.6 ms of the local top-k cell's
+    round at [16, 6568640]; PERF.md section 6, PR 34). `lax.slice`
+    compiles to one strided slice instruction over the same
+    coordinates, so the values are the same to the bit."""
     d = v.shape[0]
     k = min(k, d)
-    sq = v * v
     stride = max(1, d // _TOPK_SAMPLE)
-    thr = threshold_from_sq_sample(sq[::stride], k, d)
-    return jnp.where(sq >= thr, v, 0.0)
+    sample = jax.lax.slice(v, (0,), (d,), (stride,))
+    thr = threshold_from_sq_sample(sample * sample, k, d)
+    return jnp.where(v * v >= thr, v, 0.0)
 
 
 def clip_to_l2(vec: jax.Array, clip: float) -> jax.Array:

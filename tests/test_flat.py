@@ -62,6 +62,49 @@ def test_masked_topk_threshold_sampled(monkeypatch):
     np.testing.assert_allclose(out[nz], v[nz])
 
 
+def _parent_sampled_threshold_mask(v, k, sample):
+    """The definition `sampled_threshold_mask` had before its sample
+    became a `lax.slice`, in numpy: every `stride`-th square, the
+    `ks`-th largest of them floored at f32-tiny, `sq >= thr`."""
+    d = v.shape[0]
+    k = min(k, d)
+    sq = v * v
+    picked = sq[::max(1, d // sample)]
+    n = picked.shape[0]
+    ks = max(1, min(int(round(k * n / d)), n))
+    thr = max(np.sort(picked)[n - ks], np.finfo(np.float32).tiny)
+    return np.where(sq >= thr, v, np.float32(0.0))
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["1d", "vmapped"])
+@pytest.mark.parametrize("d, sample", [
+    (4096, 1024),      # d a multiple of the stride (4)
+    (4099, 1024),      # not one: the last stride is short
+    (6001, 1000),      # stride 6, as at the benchmark's D: 1,001 samples
+    (3000, 4096),      # stride 1: the sample is the vector
+], ids=["multiple", "ragged", "stride6", "stride1"])
+def test_sampled_threshold_mask_is_the_strided_sample_bit_for_bit(
+        monkeypatch, batched, d, sample):
+    # the sample is read by lax.slice (a jnp `sq[::stride]` traces to
+    # a gather on jax 0.9.0): same coordinates, same squares, same
+    # selection, to the bit (CPU approx_max_k is exact)
+    monkeypatch.setattr(flat, "_TOPK_SAMPLE", sample)
+    rng = np.random.RandomState(d)
+    v = (rng.standard_cauchy((3, d)) * 0.01).astype(np.float32)
+    k = d // 20
+
+    def one(r):
+        return flat.sampled_threshold_mask(r, k)
+
+    rows = jnp.asarray(v)
+    got = jax.vmap(one)(rows) if batched else jnp.stack(
+        [one(r) for r in rows])
+    want = np.stack([_parent_sampled_threshold_mask(r, k, sample)
+                     for r in v])
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert 0 < np.count_nonzero(want) < want.size
+
+
 def test_masked_topk_threshold_sparser_than_k(monkeypatch):
     # fewer than k nonzeros: the tiny floor keeps selection to exactly
     # the nonzeros instead of everything

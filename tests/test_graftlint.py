@@ -384,9 +384,32 @@ GL014_NEG = """
         WIRE_FIELD = ""
 """
 
-# rule -> (positive, negative[, lint path]); GL010 is path-scoped to
-# the packages that construct shardings, so its fixtures lint under a
-# parallel/ path (everything else uses the default snippet.py)
+GL015_POS = """
+    import jax.numpy as jnp
+
+    def sample(v, stride):
+        sq = v * v
+        return sq[::stride]
+
+    def columns(m, a, b):
+        return m[:, a:b:2]
+"""
+GL015_NEG = """
+    import jax
+
+    def sample(v, stride):
+        # the strided slice instruction itself
+        return jax.lax.slice(v, (0,), v.shape, (stride,))
+
+    def windows(m, off, n):
+        # no step, a step of 1 (no op) and a reversal (`rev`)
+        return m[:, off:off + n], m[::1], m[::-1]
+"""
+
+# rule -> (positive, negative[, lint path]); GL010 and GL015 are
+# path-scoped (to the packages that construct shardings; to the traced
+# packages), so their fixtures lint under a parallel/ and an ops/ path
+# (everything else uses the default snippet.py)
 FIXTURES = {
     "GL001": (GL001_POS, GL001_NEG),
     "GL002": (GL002_POS, GL002_NEG),
@@ -403,6 +426,8 @@ FIXTURES = {
     "GL012": (GL012_POS, GL012_NEG),
     "GL013": (GL013_POS, GL013_NEG),
     "GL014": (GL014_POS, GL014_NEG),
+    "GL015": (GL015_POS, GL015_NEG,
+              "commefficient_tpu/ops/snippet.py"),
 }
 
 
@@ -494,6 +519,23 @@ def test_gl010_scoped_to_sharding_packages():
     assert "GL010" not in _fixture_codes(GL010_POS)
     assert "GL010" in _fixture_codes(
         GL010_POS, "commefficient_tpu/federated/snippet.py")
+
+
+def test_gl015_flags_every_strided_subscript_in_the_traced_packages():
+    """Both strided subscripts of the fixture, each with its reason;
+    the same source outside ops/, federated/ and compress/ (a host
+    loader striding a numpy array) is not GL015's business."""
+    for pkg in ("ops", "federated", "compress"):
+        vs = [v for v in lint_source(
+            f"commefficient_tpu/{pkg}/snippet.py",
+            textwrap.dedent(GL015_POS)) if v.rule == "GL015"]
+        assert [v.line for v in vs] == [6, 9], vs
+        assert "sq[::stride]" in vs[0].message
+        assert all("gather" in v.message and "jax.lax.slice" in v.message
+                   for v in vs)
+    assert "GL015" not in _fixture_codes(
+        GL015_POS, "commefficient_tpu/data/snippet.py")
+    assert "GL015" not in _fixture_codes(GL015_POS)
 
 
 def test_gl010_shard_map_mesh_argument_not_scanned():

@@ -13,7 +13,9 @@ Cases: the flash-attention forward, the one Pallas kernel of the repo
 (models/gpt2.py takes it for every L >= 256 on a TPU), at GPT2-small
 head shapes. And the two client state-motion programs at the shapes of the benchmark's local top-k
 cell (2 x 100 clients x D=6,568,640, 16 a round): the rows must move
-as whole tiles, which only the chip's compiler can say.
+as whole tiles, which only the chip's compiler can say. And the
+per-client `masked_topk` at that cell's `[16, D]`: its threshold's
+sample must be a strided slice, not a gather.
 
 A compile that passes is not a chip run: chip_smoke.py is.
 """
@@ -25,7 +27,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from commefficient_tpu.ops import attention
+from commefficient_tpu.ops import attention, flat
 
 FLAGSHIP = dict(d=6_568_640, c=500_000, r=5, num_blocks=20)
 
@@ -69,6 +71,28 @@ def test_flash_forward_compiles(one_chip, L, dtype):
     _compile(lambda q, k, v: attention._flash_fwd_pallas(
         q, k, v, 0.125, block, block), shape, shape, shape,
         sharding=one_chip)
+
+
+# ---------------------------------------------------------------------------
+# the local top-k cell's per-client selection
+
+
+def test_topk_threshold_sample_is_a_strided_slice(one_chip):
+    """`jax.vmap`-ed `masked_topk` at the cell's `f32[16, 6568640]`,
+    k = 50,000: the threshold's ~1M sample is one `slice` with a step
+    (1.12 GB accessed). As `sq[::6]` it traced to a `gather` of
+    1,094,774 16-wide columns (`fusion:f32[1094774,16]`, 11.32 GB
+    accessed, 17.6 ms of the cell's 64.5 ms round on the chip)."""
+    d, k = FLAGSHIP["d"], 50_000
+    assert d > flat.TOPK_THRESHOLD_MIN_D
+    v = jax.ShapeDtypeStruct((16, d), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda x: flat.masked_topk(x, k)).lower(v).compile()
+    text = compiled.as_text()
+    assert not re.findall(r" gather\(", text)
+    assert "f32[1094774,16]" not in text
+    stride = d // flat._TOPK_SAMPLE
+    assert f"[0:{d}:{stride}]" in text
+    assert compiled.cost_analysis()["bytes accessed"] < 3e9
 
 
 # ---------------------------------------------------------------------------
