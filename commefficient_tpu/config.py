@@ -60,6 +60,10 @@ def num_classes_of_dataset(dataset_name: str) -> int:
     return FED_DATASETS[dataset_name]
 
 
+# Config.server_in_place's gate: 2**27 parameters, 512 MiB a copy
+IN_PLACE_MIN_D = 1 << 27
+
+
 @dataclass(frozen=True)
 class Config:
     # meta (reference: utils.py:106-111)
@@ -567,6 +571,12 @@ class Config:
     # set after model construction (reference mutates args.grad_size at
     # fed_aggregator.py:88; we return a new frozen Config instead)
     grad_size: int = 0
+    # set by a driver whose model has expert layers (like grad_size, no
+    # flag): their number. The loss's last metric is then each
+    # client's expert load [layers, held + 1], and the round appends
+    # four counters a layer to its telemetry vector
+    # (telemetry/metrics.expert_load_vector).
+    expert_load_layers: int = 0
 
     # --- derived helpers -------------------------------------------------
     def replace(self, **kw) -> "Config":
@@ -642,6 +652,29 @@ class Config:
                 and self.error_type != "local"
                 and not self.do_topk_down
                 and self.microbatch_size <= 0)
+
+    @property
+    def server_in_place(self) -> bool:
+        """Above IN_PLACE_MIN_D parameters the per-round program
+        updates the server state in place: it takes the ServerState
+        donated, packs the round's change bits itself (as the scanned
+        span program always has) and hands them back beside the
+        metrics, so no second copy of the weights is kept for the
+        accounting; and a mode whose server never touches Verror
+        (`server_error_unused`) keeps a one-element placeholder in its
+        place. At 4 bytes a parameter each of those copies is over
+        half a gigabyte there; at D = 6.6e8 weights, momentum, error,
+        their three outputs and the kept weights would be 18 GB of a
+        16 GB chip. D-based, not a flag: below the gate (every model
+        up to GPT2-small) the programs are what they were."""
+        return self.grad_size >= IN_PLACE_MIN_D
+
+    @property
+    def server_error_unused(self) -> bool:
+        """The modes whose server update hands Verror through
+        untouched (federated/server.py: _uncompressed, _fedavg,
+        _local_topk)."""
+        return self.mode in ("uncompressed", "fedavg", "local_topk")
 
     @property
     def robust_aggregation(self) -> bool:

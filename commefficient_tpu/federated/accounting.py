@@ -89,6 +89,38 @@ def pack_change_bits(update: jax.Array) -> jax.Array:
         return lo | (hi << jnp.uint32(16))
 
 
+# coordinates a row of `pack_change_bits_tiled`'s words covers
+TILE_BITS = 32 * 128
+
+
+def tiled_words(d: int) -> int:
+    return -(-d // TILE_BITS) * 128
+
+
+def pack_change_bits_tiled(update: jax.Array) -> jax.Array:
+    """`pack_change_bits` for vectors of hundreds of millions of
+    coordinates (Config.server_in_place), inside the round program:
+    the same bits in another order. The vector is read as
+    [rows, 32, 128] and bit j of word (row, lane) is coordinate
+    row * 4096 + j * 128 + lane, so a word is assembled across
+    sublanes, a full 128-lane register at a time, and no
+    [D/32, 2, 16] operand exists (its minor dimension of 16 pads to
+    128 lanes: 21 GB at D = 6.6e8). The host only ORs and counts
+    these words (CommAccountant), which no order of bits changes;
+    the tail past `d` is zero. Returns [tiled_words(d)] u32."""
+    d = update.shape[0]
+    rows = -(-d // TILE_BITS)
+    with scope("pack_change_bits"):
+        bits = jnp.not_equal(update, 0.0)
+        bits = jnp.pad(bits, (0, rows * TILE_BITS - d))
+        halves = bits.reshape(rows, 2, 16, 128).astype(jnp.float32)
+        w16 = jnp.asarray(2.0, jnp.float32) ** jnp.arange(16)
+        packed = (halves * w16[None, None, :, None]).sum(axis=2)
+        lo = packed[:, 0].astype(jnp.uint32)
+        hi = packed[:, 1].astype(jnp.uint32)
+        return (lo | (hi << jnp.uint32(16))).reshape(-1)
+
+
 def _popcount(words: np.ndarray) -> int:
     if _native is not None:
         return int(_native.popcount_words(
@@ -96,33 +128,51 @@ def _popcount(words: np.ndarray) -> int:
     return int(_POPCOUNT_TABLE[words.view(np.uint8)].sum())
 
 
-def _prefix_or_popcounts(changes, depths, n_words: int) -> dict:
+def _prefix_or_popcounts(changes, depths, n_words: int,
+                         covered=()) -> dict:
     """{s: popcount(OR of the last s change bitsets)} for each needed
     staleness s in `depths`. The OR prefix must walk every depth up to
     max(depths) either way; the C fast path fuses OR+popcount in one
     64-bit pass per depth, while the numpy fallback popcounts ONLY at
     the requested depths (each popcount materializes a byte-table
-    temporary, so popcounting every depth would dominate)."""
+    temporary, so popcounting every depth would dominate).
+
+    `covered` (aligned with `changes`, or empty): True where a bitset
+    is a subset of the one appended after it. The walk runs from the
+    newest bitset back, so by the time it reaches a covered one the
+    running OR already holds its successor and with it every bit of
+    it: it is skipped, and the count at its depth is the count of the
+    depth before. A dense update (uncompressed with momentum: once a
+    coordinate has moved it moves every round) makes every bitset
+    cover the one before it, and the walk is one bitset long however
+    stale the client; at D = 6.6e8 a bitset is 82 MB."""
     depths = sorted(set(int(d) for d in depths))
     if not depths:
         return {}
     max_depth = depths[-1]
-    if _native is not None and max_depth > 0:
+    if max_depth == 0:
+        return {0: 0}
+    n = len(changes)
+    # depth d -> how many of the last d bitsets the walk has to OR
+    keep = [i for i in range(n - max_depth, n)
+            if not (covered and covered[i])]
+    walked = {d: sum(1 for i in keep if i >= n - d) for d in depths}
+    rows = [changes[i] for i in keep]
+    counts = {0: 0}
+    if _native is not None:
         # zero-copy: each deque entry's buffer is consumed directly
-        rows = [np.ascontiguousarray(np.asarray(c), np.uint32).data
-                for c in changes]
-        counts = _native.prefix_or_popcounts(rows, n_words, max_depth)
-        return {d: counts[d] for d in depths}
-    out = {}
-    if depths[0] == 0:
-        out[0] = 0
-    acc = np.zeros(n_words, np.uint32)
-    need = set(depths)
-    for d in range(1, max_depth + 1):
-        acc |= changes[-d]
-        if d in need:
-            out[d] = int(_POPCOUNT_TABLE[acc.view(np.uint8)].sum())
-    return out
+        bufs = [np.ascontiguousarray(np.asarray(c), np.uint32).data
+                for c in rows]
+        got = _native.prefix_or_popcounts(bufs, n_words, len(rows))
+        counts.update({k: got[k] for k in set(walked.values())})
+    else:
+        acc = np.zeros(n_words, np.uint32)
+        need = set(walked.values())
+        for k in range(1, len(rows) + 1):
+            acc |= rows[-k]
+            if k in need:
+                counts[k] = int(_POPCOUNT_TABLE[acc.view(np.uint8)].sum())
+    return {d: counts[walked[d]] for d in depths}
 
 
 class CommAccountant:
@@ -130,7 +180,8 @@ class CommAccountant:
                  frozen_count: int = 0):
         self.cfg = cfg
         self.num_clients = num_clients
-        self.n_words = -(-cfg.grad_size // 32)
+        self.n_words = (tiled_words(cfg.grad_size) if cfg.server_in_place
+                        else -(-cfg.grad_size // 32))
         # finetune-frozen coordinates transmit nothing in the dense-
         # upload modes (the reference's requires_grad=False params are
         # not in the flat vector at all); sketch tables and the top-k
@@ -181,6 +232,10 @@ class CommAccountant:
                              * (1.0 - cfg.client_dropout))
             maxlen = int(DEQUE_MAXLEN_MULT / participation)
             self.changes: deque = deque([], maxlen=maxlen)
+            # per bitset: is it a subset of the one appended after it
+            # (_prefix_or_popcounts skips those); the newest is False
+            self._covered: deque = deque([], maxlen=maxlen)
+            self._empty: Optional[np.ndarray] = None
             # SPARSE staleness (ISSUE 9): a dense [num_clients] int64
             # vector made accountant state O(population). Staleness of
             # client c is `rounds_seen - last reset`, where the reset
@@ -190,6 +245,27 @@ class CommAccountant:
             # semantics) — O(clients-ever-seen) state and checkpoint.
             self.rounds_seen = 0
             self._last_reset: dict = {}
+
+    def _append(self, words: np.ndarray) -> None:
+        """A round's change bitset joins the window; the one before it
+        is marked where the new one covers it, and then gives its
+        memory back: the walk never reads a covered bitset and bitsets
+        leave the window oldest first, so its successor outlives it
+        and an empty set in its place (a subset of anything) changes
+        no count, now or after a checkpoint. Only a dense bitset (over
+        half the coordinates) is compared: two rounds' sparse top-k
+        supports are never nested, and the check would cost them a
+        pass for nothing."""
+        if self.changes:
+            dense = 2 * _popcount(words) > 32 * self.n_words
+            if dense and not np.any(self.changes[-1] & ~words):
+                self._covered[-1] = True
+                if self._empty is None:
+                    self._empty = np.zeros(self.n_words, np.uint32)
+                    self._empty.setflags(write=False)
+                self.changes[-1] = self._empty
+        self.changes.append(words)
+        self._covered.append(False)
 
     def _check_ids(self, participating: np.ndarray) -> None:
         """The dense stale vector this storage replaced bounds-checked
@@ -256,13 +332,14 @@ class CommAccountant:
             download[alive] = 4.0 * _popcount(self.updated_since_init)
         else:
             if prev_changed_words is not None:
-                self.changes.append(np.asarray(prev_changed_words))
+                self._append(np.asarray(prev_changed_words))
             if len(self.changes) and len(completed):
                 stale = np.clip(self.staleness(completed), 0,
                                 len(self.changes))
                 # staleness values share one OR-reduction prefix walk
                 counts = _prefix_or_popcounts(
-                    self.changes, np.unique(stale), self.n_words)
+                    self.changes, np.unique(stale), self.n_words,
+                    self._covered)
                 download[alive] = [4.0 * counts[int(s)] for s in stale]
             for c in completed:
                 self._last_reset[int(c)] = self.rounds_seen
@@ -300,7 +377,7 @@ class CommAccountant:
                 self.updated_since_init |= np.asarray(prev_changed_words)
         else:
             if prev_changed_words is not None:
-                self.changes.append(np.asarray(prev_changed_words))
+                self._append(np.asarray(prev_changed_words))
             for c in participating:
                 self._last_reset[int(c)] = self.rounds_seen
             self.rounds_seen += 1
@@ -358,5 +435,6 @@ class CommAccountant:
                 # which would undercharge returning clients' downloads
                 self.changes = deque([], maxlen=len(rows))
             self.changes.clear()
+            self._covered = deque([], maxlen=self.changes.maxlen)
             for row in rows:
-                self.changes.append(row)
+                self._append(row)

@@ -122,8 +122,13 @@ class FedModel:
             if module is None or init_batch is None:
                 raise ValueError("need either params or module+init_batch")
             params = module.init(jax.random.PRNGKey(cfg.seed), *init_batch)
-        self.params_template = params
+        # shapes only: the tree itself is one more copy of the model
+        # on the device for as long as this object lives
+        self.params_template = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
+                                           jnp.result_type(x)), params)
         vec, self.unravel = flatten_params(params)
+        del params
         cfg = cfg.replace(grad_size=int(vec.shape[0])).validate()
         self.cfg = cfg
 
@@ -1348,8 +1353,12 @@ class FedModel:
         is read AFTER dispatch for the one-round-lagged accounting
         bitset, and a donated ps_weights would be a deleted buffer by
         then (round.ROUND_DEAD_ARGNUMS / SCATTER_DEAD_ARGNUMS are the
-        authoritative declarations)."""
-        prev_weights = self.server.ps_weights
+        authoritative declarations). Except above
+        config.IN_PLACE_MIN_D parameters (Config.server_in_place):
+        there the round program takes the ServerState donated too and
+        hands the packed bitset back itself."""
+        prev_weights = (None if self.cfg.server_in_place
+                        else self.server.ps_weights)
         this_round = staged.round_idx
         if staged.tier_plan is not None:
             # tier motion first (ISSUE 11): spill-gather the plan's
@@ -1385,8 +1394,13 @@ class FedModel:
         # bits here instead would block on the round that was just
         # dispatched — a device sync per round.
         with TRACE.span("collect", round=this_round):
-            bits = self._pack_bits(self.server.ps_weights
-                                   - prev_weights)
+            if metrics.change_bits is not None:
+                # Config.server_in_place: the round program donated
+                # the old weights and packed the bits itself
+                bits = metrics.change_bits
+            else:
+                bits = self._pack_bits(self.server.ps_weights
+                                       - prev_weights)
             bits.copy_to_host_async()
             # screened family (ISSUE 16): accounting charges the
             # EFFECTIVE mask — host survivors x device admission — so

@@ -96,7 +96,21 @@ def make_flat_loss_fn(loss_fn: LossFn, unravel: Callable,
             batch = _cast_tree(batch, compute_dtype)
         loss, metrics = loss_fn(params, batch, mask)
         return loss.astype(jnp.float32), _cast_tree(metrics, jnp.float32)
+    flat_loss.cohort = is_cohort_loss(loss_fn)
     return flat_loss
+
+
+def is_cohort_loss(loss_fn) -> bool:
+    """A loss that takes a whole shard of clients at once: batch
+    leaves [W, B, ...] and mask [W, B] in, (losses [W], metrics with a
+    leading [W]) out, each client's loss the mean over its own valid
+    examples. A model whose layers batch over every position they are
+    given and cannot be written per client and then vmapped (a sorted,
+    grouped expert layer: models/smallthinker.py) marks its loss
+    `cohort = True`; the engine then calls it once where it would
+    vmap a per-client loss. Only the fused backward and the eval path
+    take such a loss."""
+    return bool(getattr(loss_fn, "cohort", False))
 
 
 def _microbatch_shape(batch_size: int, microbatch_size: int) -> Tuple[int, int]:
@@ -272,7 +286,11 @@ def fused_shard_grads(flat_loss_fn, weights, batch, mask,
         def one(d, m):
             loss, metrics = flat_loss_fn(vec, d, m)
             return loss, metrics, m.sum()
-        losses, metrics, counts = jax.vmap(one)(batch, mask)
+        if is_cohort_loss(flat_loss_fn):
+            losses, metrics = flat_loss_fn(vec, batch, mask)
+            counts = mask.sum(axis=1)
+        else:
+            losses, metrics, counts = jax.vmap(one)(batch, mask)
         if survivors is not None:
             counts = counts * survivors
         total = (losses * counts).sum()

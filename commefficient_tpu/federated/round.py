@@ -358,6 +358,9 @@ class RoundMetrics(NamedTuple):
     admitted: Optional[jax.Array] = None  # [num_workers] f32 or None
     contributors: Optional[jax.Array] = None  # [num_workers] f32 or None
     agg_stats: Optional[jax.Array] = None     # [4] f32 or None
+    # Config.server_in_place only: the round's packed change bits
+    # (accounting.pack_change_bits of the applied update), [D/32] u32
+    change_bits: Optional[jax.Array] = None
 
 
 def init_server_state(cfg: Config, ps_weights: jax.Array,
@@ -366,11 +369,15 @@ def init_server_state(cfg: Config, ps_weights: jax.Array,
     GLOBAL replicated array — required in multi-controller runs, a
     no-op placement in single-process ones (parallel/multihost.py)."""
     shape = cfg.state_shape
+    # a server that never reads its error keeps no D-sized zeros for
+    # it where D is large (Config.server_in_place)
+    err_shape = ((1,) if cfg.server_in_place and cfg.server_error_unused
+                 else shape)
     if mesh is None:
         return ServerState(
             ps_weights=ps_weights.astype(jnp.float32),
             Vvelocity=jnp.zeros(shape, jnp.float32),
-            Verror=jnp.zeros(shape, jnp.float32),
+            Verror=jnp.zeros(err_shape, jnp.float32),
             round_idx=jnp.zeros((), jnp.int32),
         )
     from commefficient_tpu.parallel import multihost as mh
@@ -378,7 +385,7 @@ def init_server_state(cfg: Config, ps_weights: jax.Array,
         ps_weights=mh.globalize(
             mesh, P(), jnp.asarray(ps_weights, jnp.float32)),
         Vvelocity=mh.zeros(mesh, P(), shape),
-        Verror=mh.zeros(mesh, P(), shape),
+        Verror=mh.zeros(mesh, P(), err_shape),
         round_idx=mh.globalize(mesh, P(), jnp.zeros((), jnp.int32)),
     )
 
@@ -524,6 +531,9 @@ STATE_MOTION_PROGRAMS = ("gather", "scatter")
 # so donating it would hand accounting a deleted buffer. graftaudit's
 # donation audit uses exactly these declarations.
 ROUND_DEAD_ARGNUMS = (1,)      # round program: the CohortState operand
+# ... and the ServerState too where the program packs the change bits
+# itself (Config.server_in_place): nothing reads the old weights after
+ROUND_DEAD_ARGNUMS_IN_PLACE = (0, 1)
 SCATTER_DEAD_ARGNUMS = (0,)    # scatter-back: the full ClientState
 # scanned-span dispatch (TrainRound.train_rounds): both state operands
 # are dead — run_rounds computes the change bitset INSIDE the span and
@@ -652,6 +662,13 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
             "fused_client_backward requires defer_sketch_encode in "
             "sketch mode (dense shard sum must be encoded in the "
             "shared tail)")
+    if fclient.is_cohort_loss(loss_fn) and not cfg.fused_client_backward:
+        raise ValueError(
+            "this model's loss takes a whole cohort at once "
+            "(client.is_cohort_loss) and trains only through the fused "
+            "backward: mode sketch, uncompressed or true_topk with no "
+            "per-client state, clipping or microbatching "
+            "(Config.fused_client_backward)")
     flat_grad = fclient.make_flat_grad_fn(
         loss_fn, unravel,
         compute_dtype=jnp.bfloat16 if cfg.do_bf16 else None)
@@ -1446,12 +1463,22 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
                     verror=upd.Verror, vvelocity=upd.Vvelocity,
                     survivors=(jnp.float32(num_workers) if eff is None
                                else eff.sum()))
+                if cfg.expert_load_layers:
+                    tele = jnp.concatenate(
+                        [tele, tmetrics.expert_load_vector(metrics[-1])])
         else:
             tele = tmetrics.empty_vector()
 
+        bits = None
+        if cfg.server_in_place:
+            from commefficient_tpu.federated.accounting import (
+                pack_change_bits_tiled,
+            )
+            bits = pack_change_bits_tiled(new_ps - server.ps_weights)
+
         return new_server, new_cohort, RoundMetrics(
             losses, metrics, counts, tele, admitted, contributors,
-            agg_stats)
+            agg_stats, bits)
 
     def round_full(server: ServerState, clients: ClientState,
                    batch: RoundBatch, lr, key):
@@ -1509,8 +1536,9 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
     # un-donated scatter-back transiently doubles it. The dead sets are
     # the registry constants above; donated operands are INVALID after
     # the call (see TrainRound docstring for the caller contract).
-    round_donate = (ROUND_DEAD_ARGNUMS if cfg.donate_round_state
-                    else ())
+    round_donate = ((ROUND_DEAD_ARGNUMS_IN_PLACE if cfg.server_in_place
+                     else ROUND_DEAD_ARGNUMS)
+                    if cfg.donate_round_state else ())
     # pipelined TIERED staging (ISSUE 11 + ISSUE 10): span t+1's
     # restore-scatters run against span t's result block while the
     # deferred span-boundary checkpoint still reads it, so the
@@ -1658,6 +1686,10 @@ def make_eval_fn(loss_fn: fclient.LossFn, unravel: Callable,
         compute_dtype=jnp.bfloat16 if cfg.do_bf16 else None)
 
     def shard_eval(ps_weights, data, mask):
+        if flat_loss.cohort:
+            loss, metrics = flat_loss(ps_weights, data, mask)
+            return loss, metrics, mask.sum(axis=1)
+
         def one_shard(b, m):
             _, loss, metrics, count = fclient.forward_grad(
                 flat_loss, ps_weights, b, m, cfg, compute_grad=False)

@@ -139,6 +139,20 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             jnp.float32)                                # [bq, 1]
 
 
+def vary_like(tree, *refs):
+    """`tree` with every leaf pcast to vary over the axes any of
+    `refs` varies over: what a loop's fresh initial carry needs under
+    `shard_map`'s `check_vma`, where the carry's type may not change
+    between iterations. Outside `shard_map` nothing changes."""
+    vma = frozenset().union(*(jax.typeof(r).vma for r in refs))
+
+    def lift(x):
+        missing = tuple(sorted(vma - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(lift, tree)
+
+
 def vary_together(*operands):
     """(vma, operands) with every operand pcast to the union `vma` of
     their varying-axes sets. Under `shard_map`'s `check_vma` a
@@ -147,12 +161,7 @@ def vary_together(*operands):
     operands whose sets differ: both are settled before the call.
     Outside `shard_map` the union is empty and nothing changes."""
     vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
-
-    def lift(x):
-        missing = tuple(sorted(vma - jax.typeof(x).vma))
-        return jax.lax.pcast(x, missing, to="varying") if missing else x
-
-    return vma, tuple(lift(x) for x in operands)
+    return vma, vary_like(operands, *operands)
 
 
 def _flash_fwd_pallas(q, k, v, sm_scale, block_q, block_k,
@@ -214,9 +223,12 @@ def _flash_fwd_xla(q, k, v, sm_scale, block_k) -> Tuple[jax.Array, jax.Array]:
         k_pos = j * block_k + jnp.arange(block_k)
         return online_softmax_fold(carry, qs, kj, vj, q_pos, k_pos), None
 
-    m0 = jnp.full((B, H, L), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H, L), jnp.float32)
-    acc0 = jnp.zeros((B, H, L, Dh), jnp.float32)
+    # inside shard_map (check_vma) a scan's carry keeps one type: the
+    # fresh constants vary wherever the operands do before they enter
+    m0, l0, acc0 = vary_like(
+        (jnp.full((B, H, L), NEG_INF, jnp.float32),
+         jnp.zeros((B, H, L), jnp.float32),
+         jnp.zeros((B, H, L, Dh), jnp.float32)), q, k, v)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, acc0),
         (kb.transpose(2, 0, 1, 3, 4), vb.transpose(2, 0, 1, 3, 4),
@@ -263,7 +275,8 @@ def _flash_bwd_xla(q, k, v, o, lse, do, sm_scale, block_k):
             preferred_element_type=jnp.float32)
         return dq, (dk_j, dv_j)
 
-    dq0 = jnp.zeros((B, H, L, Dh), jnp.float32)
+    dq0 = vary_like(jnp.zeros((B, H, L, Dh), jnp.float32),
+                    q, k, v, o, lse, do)
     dq, (dk_b, dv_b) = jax.lax.scan(
         body, dq0,
         (kb.transpose(2, 0, 1, 3, 4), vb.transpose(2, 0, 1, 3, 4),
@@ -336,3 +349,219 @@ def reference_attention(q, k, v, sm_scale: Optional[float] = None):
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
+
+
+# ---------------- grouped-query, windowed, blockwise ---------------------
+#
+# The long-sequence path of models that mix full and sliding-window
+# causal attention and share each key-value head among several query
+# heads (models/smallthinker.py). Plain XLA, no kernel: an outer loop
+# over query blocks, an inner loop over just the key blocks a query
+# block can see, the online-softmax state carried between them. A
+# block the mask rules out whole is never visited, forward or
+# backward; K and V keep their Hkv heads (the query heads of a group
+# ride an extra axis of q, nothing is repeated in memory); scores
+# exist one [G * block, block] tile per key-value head at a time.
+# The matmul operands are the mathematical quantities themselves
+# (scores are scaled after the product, p is exp(s - lse)), so at the
+# chip's default precision they round as a plain softmax(QK^T) V does.
+
+BLOCKWISE_BLOCK = 512
+
+
+def _allowed(q_pos, k_pos, window: Optional[int]):
+    ok = q_pos[:, None] >= k_pos[None, :]
+    if window is not None:
+        ok = ok & (k_pos[None, :] > q_pos[:, None] - window)
+    return ok
+
+
+def _first_block(a, window: Optional[int], block: int):
+    """The first key block that query block `a` can see."""
+    if window is None:
+        return jnp.zeros_like(a)
+    return jnp.maximum(a - (-(-window // block)), 0)
+
+
+def _blocked(x, block: int):
+    """[..., L, Dh] -> [L // block, ..., block, Dh]."""
+    *lead, L, Dh = x.shape
+    x = x.reshape(*lead, L // block, block, Dh)
+    return jnp.moveaxis(x, len(lead), 0)
+
+
+def _unblocked(xb):
+    """Inverse of `_blocked`."""
+    n, *lead, block, Dh = xb.shape
+    return jnp.moveaxis(xb, 0, len(lead)).reshape(*lead, n * block, Dh)
+
+
+def _bw_scores(qa, kj, a, j, scale, window, block):
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qa, kj,
+                   preferred_element_type=jnp.float32) * scale
+    ok = _allowed(a * block + jnp.arange(block),
+                  j * block + jnp.arange(block), window)
+    return s, ok
+
+
+def _bw_fwd(q, k, v, window, block):
+    """q [B, Hkv, G, L, Dh], k and v [B, Hkv, L, Dh], L a multiple of
+    `block` -> (o like q, lse [B, Hkv, G, L] f32)."""
+    B, Hkv, G, L, Dh = q.shape
+    scale = 1.0 / math.sqrt(Dh)
+    kb, vb = _blocked(k, block), _blocked(v, block)
+
+    def q_block(xs):
+        a, qa = xs
+
+        def fold(j, state):
+            m, l, acc = state
+            s, ok = _bw_scores(qa, kb[j], a, j, scale, window, block)
+            s = jnp.where(ok, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            # a row whose every key in this block is masked keeps
+            # m = NEG_INF: its exp(0) must not count
+            p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+            rescale = jnp.exp(m - m_new)
+            l = l * rescale + p.sum(axis=-1)
+            acc = acc * rescale[..., None] + jnp.einsum(
+                "bhgqk,bhkd->bhgqd", p.astype(v.dtype), vb[j],
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        state = vary_like(
+            (jnp.full((B, Hkv, G, block), NEG_INF, jnp.float32),
+             jnp.zeros((B, Hkv, G, block), jnp.float32),
+             jnp.zeros((B, Hkv, G, block, Dh), jnp.float32)), q, k, v)
+        m, l, acc = jax.lax.fori_loop(
+            _first_block(a, window, block), a + 1, fold, state)
+        return (acc / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    n = L // block
+    ob, lseb = jax.lax.map(q_block, (jnp.arange(n), _blocked(q, block)))
+    return _unblocked(ob), jnp.moveaxis(lseb, 0, 3).reshape(B, Hkv, G, L)
+
+
+def _bw_bwd(q, k, v, o, lse, do, window, block):
+    """One pass over the visible (query block, key block) pairs: dq of
+    the query block rides the inner loop, dk and dv of all blocks the
+    outer one (33 MB at 8,192 positions), updated in place."""
+    B, Hkv, G, L, Dh = q.shape
+    scale = 1.0 / math.sqrt(Dh)
+    n = L // block
+    kb, vb = _blocked(k, block), _blocked(v, block)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    lseb = jnp.moveaxis(lse.reshape(B, Hkv, G, n, block), 3, 0)
+    deltab = jnp.moveaxis(delta.reshape(B, Hkv, G, n, block), 3, 0)
+
+    def q_block(carry, xs):
+        a, qa, doa, lsea, deltaa = xs
+
+        def fold(j, state):
+            dqa, dkb, dvb = state
+            s, ok = _bw_scores(qa, kb[j], a, j, scale, window, block)
+            p = jnp.where(ok, jnp.exp(s - lsea[..., None]), 0.0)
+            dv_j = jnp.einsum("bhgqk,bhgqd->bhkd", p.astype(do.dtype),
+                              doa, preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bhgqd,bhkd->bhgqk", doa, vb[j],
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - deltaa[..., None]) * scale).astype(q.dtype)
+            dqa = dqa + jnp.einsum("bhgqk,bhkd->bhgqd", ds, kb[j],
+                                   preferred_element_type=jnp.float32)
+            dk_j = jnp.einsum("bhgqk,bhgqd->bhkd", ds, qa,
+                              preferred_element_type=jnp.float32)
+            return (dqa, dkb.at[j].add(dk_j), dvb.at[j].add(dv_j))
+
+        dqa = vary_like(jnp.zeros((B, Hkv, G, block, Dh), jnp.float32),
+                        q, k, v, do)
+        dqa, dkb, dvb = jax.lax.fori_loop(
+            _first_block(a, window, block), a + 1, fold, (dqa, *carry))
+        return (dkb, dvb), dqa.astype(q.dtype)
+
+    zeros = vary_like(jnp.zeros((n, B, Hkv, block, Dh), jnp.float32),
+                      q, k, v, do)
+    (dkb, dvb), dqb = jax.lax.scan(
+        q_block, (zeros, zeros),
+        (jnp.arange(n), _blocked(q, block), _blocked(do, block),
+         lseb, deltab))
+    return (_unblocked(dqb), _unblocked(dkb).astype(k.dtype),
+            _unblocked(dvb).astype(v.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def blockwise_attention(q, k, v, window: Optional[int] = None,
+                        block: int = BLOCKWISE_BLOCK):
+    """Causal attention of q [B, Hq, L, Dh] over k, v [B, Hkv, L, Dh],
+    Hq a multiple of Hkv (query head i reads key-value head
+    i // (Hq // Hkv)); with `window`, position i sees only positions
+    j > i - window. Any L: it is padded to a block multiple inside
+    (pad keys lie after every real query, pad queries are cut off).
+    Returns [B, Hq, L, Dh]."""
+    o, _ = _bwa_fwd_impl(q, k, v, window, block)
+    return o
+
+
+def _bwa_pad(x, Lp):
+    pad = Lp - x.shape[-2]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
+
+
+def _bwa_group(q, Hkv):
+    B, Hq, L, Dh = q.shape
+    return q.reshape(B, Hkv, Hq // Hkv, L, Dh)
+
+
+def _bwa_geometry(L: int, block: int):
+    """(block, padded length): a short sequence is one block."""
+    block = min(block, _pad_len(L, 8))
+    return block, _pad_len(L, block)
+
+
+def _bwa_fwd_impl(q, k, v, window, block):
+    B, Hq, L, Dh = q.shape
+    block, Lp = _bwa_geometry(L, block)
+    o, lse = _bw_fwd(_bwa_group(_bwa_pad(q, Lp), k.shape[1]),
+                     _bwa_pad(k, Lp), _bwa_pad(v, Lp), window, block)
+    return o.reshape(B, Hq, Lp, Dh)[:, :, :L], lse[..., :L]
+
+
+def _bwa_fwd(q, k, v, window, block):
+    o, lse = _bwa_fwd_impl(q, k, v, window, block)
+    return o, (q, k, v, o, lse)
+
+
+def _bwa_bwd(window, block, res, do):
+    q, k, v, o, lse = res
+    B, Hq, L, Dh = q.shape
+    Hkv = k.shape[1]
+    block, Lp = _bwa_geometry(L, block)
+    pad = Lp - L
+    # pad queries: zero do, and an lse that makes their p vanish
+    lsep = (jnp.pad(lse, ((0, 0),) * 3 + ((0, pad),),
+                    constant_values=LSE_PAD) if pad else lse)
+    dq, dk, dv = _bw_bwd(
+        _bwa_group(_bwa_pad(q, Lp), Hkv), _bwa_pad(k, Lp),
+        _bwa_pad(v, Lp), _bwa_group(_bwa_pad(o, Lp), Hkv), lsep,
+        _bwa_group(_bwa_pad(do, Lp), Hkv), window, block)
+    return (dq.reshape(B, Hq, Lp, Dh)[:, :, :L], dk[:, :, :L],
+            dv[:, :, :L])
+
+
+blockwise_attention.defvjp(_bwa_fwd, _bwa_bwd)
+
+
+def reference_windowed_attention(q, k, v, window: Optional[int] = None):
+    """Dense masked grouped-query attention (O(L^2) memory), for
+    equivalence tests of `blockwise_attention`."""
+    B, Hq, L, Dh = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(B, Hkv, Hq // Hkv, L, Dh).astype(jnp.float32)
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k.astype(jnp.float32)) \
+        / math.sqrt(Dh)
+    pos = jnp.arange(L)
+    s = jnp.where(_allowed(pos, pos, window), s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32)) \
+        .reshape(B, Hq, L, Dh).astype(q.dtype)

@@ -39,6 +39,18 @@ ops, so the split is exact to a fusion, not to an instruction.
     gather_cohort, scatter_back, pack_change_bits
                       their bodies, findable inside round_full and
                       the scanned program too
+
+Inside `fwdbwd` a model may name its own layers (they are INNER names:
+the rule above still gives their ops to `fwdbwd`, a reader that asks
+for one of them by name finds it anywhere on the path):
+
+    attention_full, attention_window
+                      RoPE and the blockwise attention core of a
+                      full-attention and of a sliding-window layer
+    moe_route         the router's matmul, top-k, the sort of the
+                      picks by expert and the gather into that order
+    expert_ffn        the grouped expert products, the return to
+                      position order and the weighted combine
 """
 from __future__ import annotations
 
@@ -47,7 +59,8 @@ import jax
 SCOPE_PREFIX = "fed_"
 SCOPES = ("fwdbwd", "residual", "encode", "aggregate", "select",
           "server_state", "telemetry", "gather_cohort", "scatter_back",
-          "pack_change_bits")
+          "pack_change_bits",
+          "attention_full", "attention_window", "moe_route", "expert_ffn")
 
 
 def scope(name: str):

@@ -38,6 +38,20 @@ Metric semantics (indices are `METRIC_NAMES` order):
                     falling behind the gradient — the knob PowerSGD-
                     style error feedback otherwise hides. 0 when the
                     mode has no error accumulator.
+
+A model with expert layers (Config.expert_load_layers) appends four
+counters a layer, `moe<l>_<name>` for name in LOAD_COUNTERS, from the
+per-client loads its loss reports (models/smallthinker.py):
+
+  routed            picks of the round's positions that fell on an
+                    expert this chip holds
+  max_load, min_load
+                    the picks on the most and on the least loaded held
+                    expert (0 for an expert no position reached: its
+                    gradient is zero this round)
+  absent_share      the share of all picks that fell on experts this
+                    chip does not hold (1 - held / all under a uniform
+                    router)
 """
 from __future__ import annotations
 
@@ -54,6 +68,7 @@ METRIC_NAMES = (
     "estimate_residual",
 )
 NUM_METRICS = len(METRIC_NAMES)
+LOAD_COUNTERS = ("routed", "max_load", "min_load", "absent_share")
 METRIC_INDEX = {name: i for i, name in enumerate(METRIC_NAMES)}
 
 _EPS = 1e-12
@@ -100,9 +115,30 @@ def round_vector(losses, counts, delta, verror, vvelocity,
     ])
 
 
+def expert_load_vector(load) -> jnp.ndarray:
+    """[layers * len(LOAD_COUNTERS)] f32 from the cohort's expert
+    loads: load [W, layers, held + 1], per client and layer the picks
+    on each held expert and, last, the picks in all."""
+    load = load.astype(jnp.float32).sum(0)          # over the cohort
+    held, picks = load[:, :-1], load[:, -1]
+    routed = held.sum(-1)
+    return jnp.stack(
+        [routed, held.max(-1), held.min(-1),
+         1.0 - routed / jnp.maximum(picks, 1.0)], axis=-1).reshape(-1)
+
+
+def metric_names(size: int) -> tuple:
+    """Names of a telemetry vector of `size` entries: METRIC_NAMES,
+    then LOAD_COUNTERS for each expert layer the vector carries."""
+    layers = (size - NUM_METRICS) // len(LOAD_COUNTERS)
+    return METRIC_NAMES + tuple(
+        f"moe{l}_{c}" for l in range(layers) for c in LOAD_COUNTERS)
+
+
 def named(vec) -> dict:
     """Host-side convenience: {metric name: float} from one materialized
-    [NUM_METRICS] vector (or a no-op {} for a zero-size placeholder)."""
+    telemetry vector (or a no-op {} for a zero-size placeholder)."""
     if vec is None or getattr(vec, "size", 0) == 0:
         return {}
-    return {name: float(vec[i]) for i, name in enumerate(METRIC_NAMES)}
+    return {name: float(vec[i])
+            for i, name in enumerate(metric_names(len(vec)))}

@@ -29,7 +29,7 @@ import numpy as np
 from commefficient_tpu.config import Config, parse_args
 from commefficient_tpu.data.loader import FedLoader, FedValLoader
 from commefficient_tpu.data.persona import (
-    FedPERSONA, IGNORE_INDEX, make_tokenizer,
+    FedPERSONA, HashTokenizer, IGNORE_INDEX, make_tokenizer,
 )
 from commefficient_tpu.federated.api import FedModel, FedOptimizer
 from commefficient_tpu.models.gpt2 import (
@@ -37,6 +37,7 @@ from commefficient_tpu.models.gpt2 import (
     resize_position_embeddings, resize_token_embeddings, save_pretrained,
     try_load_pretrained,
 )
+from commefficient_tpu.models import smallthinker
 from commefficient_tpu.parallel import multihost as mh
 from commefficient_tpu.parallel.mesh import make_multihost_client_mesh
 from commefficient_tpu.parallel.tp import tp_loss
@@ -201,21 +202,24 @@ def train_gpt2(model: FedModel, opt: FedOptimizer, lr_scheduler,
         # nothing; float()ing the fresh round would block the host
         # every round (PERF.md). NaN abort latency grows by one round.
         def emit(p) -> bool:
-            bidx, lr_v, l_, lm_, mc_ = p
+            bidx, lr_v, l_, *parts = p
             # gather_host: metrics are cross-process sharded in
             # multi-controller runs (np.asarray otherwise)
-            l_, lm_, mc_ = (mh.gather_host(l_), mh.gather_host(lm_),
-                            mh.gather_host(mc_))
-            losses.append(float(np.mean(l_)))
-            logger.append({
+            losses.append(float(np.mean(mh.gather_host(l_))))
+            row = {
                 "batch_idx": bidx,
                 "lr": round(lr_v, 5),
                 "train_time": timer(),
                 "train_loss": losses[-1],
-                "lm_loss": float(np.mean(lm_)),
-                "mc_loss": float(np.mean(mc_)),
-                "total_time": timer.total_time,
-            })
+            }
+            if len(parts) == 2:
+                # the double-heads loss reports its two terms; a
+                # language-model-only loss (--model smallthinker) has
+                # none beside the loss itself
+                row["lm_loss"] = float(np.mean(mh.gather_host(parts[0])))
+                row["mc_loss"] = float(np.mean(mh.gather_host(parts[1])))
+            row["total_time"] = timer.total_time
+            logger.append(row)
             return not (np.isnan(losses[-1])
                         or losses[-1] > cfg.nan_threshold)
 
@@ -264,8 +268,8 @@ def train_gpt2(model: FedModel, opt: FedOptimizer, lr_scheduler,
                 # adaptive span provider; static --scan_span otherwise
                 model.control_bank if cfg.span_palette
                 else (cfg.scan_span if cfg.scan_span > 0 else spe),
-                lambda tag, l_, lm_, mc_: emit(
-                    (tag[0], tag[1], l_, lm_, mc_)),
+                lambda tag, l_, *parts: emit(
+                    (tag[0], tag[1], l_, *parts)),
                 on_comm,
                 # span-boundary saves bound a mid-span preemption's
                 # loss to ckpt_every_spans spans, not one epoch
@@ -291,7 +295,7 @@ def train_gpt2(model: FedModel, opt: FedOptimizer, lr_scheduler,
                 ctx = (guard() if guard is not None and warmed[0]
                        else contextlib.nullcontext())
                 with ctx:
-                    loss, lm, mc, down, up = model(
+                    loss, *parts, down, up = model(
                         (client_ids, data, mask))
                 warmed[0] = True
                 opt.step()
@@ -306,7 +310,7 @@ def train_gpt2(model: FedModel, opt: FedOptimizer, lr_scheduler,
                     aborted = True
                     break
                 pending = (batch_idx, float(opt.param_groups[0]["lr"]),
-                           loss, lm, mc)
+                           loss, *parts)
             if pending is not None and not emit(pending):
                 aborted = True
         if aborted:
@@ -461,6 +465,57 @@ def build_model_and_params(cfg: Config, tokenizer, seq_len: int,
     return module, params
 
 
+# ---------------- --model smallthinker -----------------------------------
+
+def smallthinker_config(cfg: Config):
+    """None unless `--model smallthinker`; else the model's sizes:
+    the tiny preset under --test, otherwise the public `config.json`
+    found in --model_checkpoint (a directory, as for GPT2), where the
+    keys `held_experts` [first, count] and `router_width` may say
+    which experts of how many this process holds (one chip's share of
+    an expert-parallel deployment). Weights are drawn from --seed:
+    no checkpoint of this family is read."""
+    if not cfg.model.lower().startswith("smallthinker"):
+        return None
+    if cfg.do_test:
+        return smallthinker.TINY.replace(remat=cfg.do_remat)
+    import json
+    path = os.path.join(cfg.model_checkpoint, "config.json")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"--model smallthinker: no config.json in "
+            f"--model_checkpoint {cfg.model_checkpoint!r}")
+    with open(path) as f:
+        published = json.load(f)
+    experts = int(published.get("router_width",
+                                published["moe_num_primary_experts"]))
+    return smallthinker.SmallThinkerConfig.from_published(
+        published, num_experts=experts,
+        held_experts=tuple(published.get("held_experts", (0, experts))))
+
+
+def build_smallthinker(cfg: Config, mcfg, tokenizer, params=None):
+    """(cfg with the model's counters switched on, train loss, val
+    loss, params): the language-model loss over every real token of
+    a sequence, for FedModel. `params` may be handed in (the
+    benchmark makes its own from the seed)."""
+    mcfg = mcfg.replace(vocab_size=len(tokenizer))
+    pad = tokenizer.special_ids()["<pad>"]
+    loss_train = smallthinker.make_lm_loss(mcfg, pad)
+
+    def loss_val(p, batch, mask):
+        # no candidate is chosen, so no accuracy: zero in its place
+        nll, _ = loss_train(p, batch, mask)
+        return nll, (jnp.zeros_like(nll),)
+
+    loss_val.cohort = True
+    if params is None:
+        params = smallthinker.init_params(
+            mcfg, jax.random.PRNGKey(cfg.seed))
+    return (cfg.replace(expert_load_layers=mcfg.num_layers), loss_train,
+            loss_val, params)
+
+
 def main(argv=None) -> bool:
     enable_persistent_compilation_cache()
     cfg = parse_args(default_lr=4e-2, argv=argv)
@@ -476,8 +531,14 @@ def main(argv=None) -> bool:
     timer = Timer()
     np.random.seed(cfg.seed)
 
-    tokenizer = make_tokenizer(cfg.model_checkpoint,
-                               fallback_vocab=500 if cfg.do_test else 5000)
+    st_cfg = smallthinker_config(cfg)
+    if st_cfg is not None:
+        # ids are drawn from the vocabulary the model holds
+        tokenizer = HashTokenizer(st_cfg.vocab_size)
+    else:
+        tokenizer = make_tokenizer(
+            cfg.model_checkpoint,
+            fallback_vocab=500 if cfg.do_test else 5000)
     train_loader, val_loader = get_data_loaders(cfg, tokenizer)
     # each split pads to its own corpus max; position embeddings must
     # cover both (out-of-range ids would silently clamp, not raise)
@@ -496,12 +557,17 @@ def main(argv=None) -> bool:
                    for f in ("pytorch_model.bin", "pytorch_model.npz"))):
         source = cfg.finetune_path
 
-    module, params = build_model_and_params(
-        cfg, tokenizer, seq_len, source=source,
-        require_load=(source == cfg.finetune_path and cfg.do_finetune))
-
-    loss_train = make_compute_loss_train(module, cfg)
-    loss_val = make_compute_loss_val(module)
+    if st_cfg is not None:
+        module = None
+        cfg, loss_train, loss_val, params = build_smallthinker(
+            cfg, st_cfg, tokenizer)
+    else:
+        module, params = build_model_and_params(
+            cfg, tokenizer, seq_len, source=source,
+            require_load=(source == cfg.finetune_path
+                          and cfg.do_finetune))
+        loss_train = make_compute_loss_train(module, cfg)
+        loss_val = make_compute_loss_val(module)
     mesh = None
     if cfg.model_parallel > 1:
         # (clients, model) mesh: manual DP over clients, GSPMD tensor
@@ -649,7 +715,7 @@ def main(argv=None) -> bool:
                            client_rows=model.client_rows_payload())
             # HF-style final artifact: tokenizer + config + weights
             # (reference gpt2_train.py:275-283, fed_aggregator.py:208-211)
-            if coord:
+            if coord and module is not None:
                 save_pretrained(log_dir, model.state_dict(), module.cfg,
                                 tokenizer)
             # the final eval legitimately first-compiles after the

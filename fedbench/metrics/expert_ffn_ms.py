@@ -1,0 +1,7 @@
+"""Device ms a round under the model's layer `expert_ffn`
+(`commefficient_tpu/scopes.py`), forward and backward."""
+from fedbench.metrics._layers import layer_ms
+
+
+def read(ctx):
+    return layer_ms(ctx, "expert_ffn")

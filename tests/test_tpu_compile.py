@@ -215,3 +215,98 @@ def test_mesh_gather_moves_rows_without_the_gather_op(state_motion_mesh):
     compiled = state_motion_mesh["gather"]
     assert not re.findall(r" gather\(", compiled.as_text())
     assert compiled.cost_analysis()["bytes accessed"] < 4e9
+
+
+# ---------------- the language model's round program, whole ---------------
+
+@pytest.fixture(scope="module")
+def smallthinker_round(topo, one_chip):
+    """The round program of the benchmark's `smallthinker_unc_w2_l8192`
+    cell (D=370,547,200: two sequences of 8,192 positions, four
+    layers, 8 of 64 experts, uncompressed with momentum), compiled
+    for one described chip as FedModel would dispatch it."""
+    import json
+    import numpy as np
+    from jax.flatten_util import ravel_pytree
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from commefficient_tpu.config import Config
+    from commefficient_tpu.federated import round as fround
+    from commefficient_tpu.models import smallthinker as st
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "fedbench", "configs",
+                           "smallthinker_21b_ep8.json")) as f:
+        published = json.load(f)
+    mcfg = st.SmallThinkerConfig.from_published(
+        published, num_experts=published["router_width"],
+        held_experts=tuple(published["held_experts"]))
+    W, L, D = 2, 8192, st.num_params(mcfg)
+    mesh = Mesh(np.array(topo.devices[:1]), ("clients",))
+    rep, cl = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
+    holder = {}
+
+    def flat():
+        vec, holder["unravel"] = ravel_pytree(jax.tree.map(
+            lambda s: jnp.zeros(s, jnp.float32), st.param_shapes(mcfg),
+            is_leaf=lambda x: isinstance(x, tuple)))
+        return vec
+
+    jax.eval_shape(flat)
+    cfg = Config(mode="uncompressed", error_type="none",
+                 virtual_momentum=0.9, local_momentum=0.0, num_workers=W,
+                 local_batch_size=1, weight_decay=0.0, num_clients=64,
+                 expert_load_layers=mcfg.num_layers) \
+        .replace(grad_size=D).validate()
+    handle = fround.make_train_fn(st.make_lm_loss(mcfg, 4),
+                                  holder["unravel"], cfg, mesh)
+
+    def shaped(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    server = jax.tree.map(
+        lambda x: shaped(x.shape, x.dtype, rep), jax.eval_shape(
+            lambda: fround.init_server_state(
+                cfg, jnp.zeros((D,), jnp.float32))))
+    cohort = fround.CohortState(
+        *(shaped((W,), jnp.float32, cl) for _ in range(3)))
+    ids = shaped((W, 1, 1, L), jnp.int32, cl)
+    batch = fround.RoundBatch(
+        client_ids=shaped((W,), jnp.int32, rep),
+        data=(ids, shaped((W, 1, 1), jnp.int32, cl), ids,
+              shaped((W, 1), jnp.int32, cl), ids),
+        mask=shaped((W, 1), jnp.float32, cl))
+    compiled = jax.jit(
+        handle.round_step, donate_argnums=handle.round_donate_argnums) \
+        .lower(server, cohort, batch, shaped((), jnp.float32, rep),
+               shaped((2,), jnp.uint32, rep)).compile()
+    return cfg, compiled
+
+
+def test_smallthinker_round_fits_one_chip(smallthinker_round):
+    """Weights and momentum updated in place, no D-sized error, the
+    change bits packed inside: the program's arguments, outputs and
+    temporaries fit a v5e's 15.75 GiB with room for the reference's
+    and the harness's buffers beside them."""
+    cfg, compiled = smallthinker_round
+    assert cfg.server_in_place
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    # weights and momentum alias their outputs
+    assert m.alias_size_in_bytes >= 2 * 4 * cfg.grad_size
+    assert m.argument_size_in_bytes < 2.1 * 4 * cfg.grad_size
+    assert total < 10 * 2 ** 30, total
+
+
+def test_smallthinker_round_runs_the_grouped_expert_kernel(
+        smallthinker_round):
+    """The expert layer reaches the chip as the compiler's own grouped
+    matmul (`ragged_dot` -> a Mosaic call with the groups' tiles as
+    metadata), not as sixteen dense products, and the change bits are
+    assembled across sublanes: no operand with a minor dimension of 16
+    exists at [D/32, 2, 16]."""
+    cfg, compiled = smallthinker_round
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert not re.search(r"\[\d+,2,16\]", text)
