@@ -33,7 +33,13 @@ its exchange. No token is dropped at any imbalance: the picks are
 sorted by expert, the held ones are a prefix of the sorted rows, and
 `jax.lax.ragged_dot` runs each expert over its own stretch, however
 long (on the TPU a grouped-matmul kernel that visits only the row
-tiles in use).
+tiles in use). Where only some experts are held, only that prefix is
+carried: each chunk of positions counts its held picks and runs in
+the smallest of the static capacities that holds them (twice and four
+times a uniform router's share, then every pick: `compact_capacities`),
+gathering those rows from the positions and adding the weighted
+results back by position. Where every expert is held there is nothing
+to choose, and every pick's row is carried (`full_width`).
 
 Everything here is a function of a plain parameter tree (no Flax
 module): the tree is the one the benchmark's plain reference builds
@@ -46,7 +52,9 @@ Long sequences: attention is `ops.attention.blockwise_attention`
 (scores exist a block at a time); each layer is rematerialised in the
 backward pass; the expert layer runs in chunks of `moe_chunk`
 positions, each rematerialised, so the sorted copies of the picks
-(six rows a position, held or not) never exist for a whole batch; the
+(the held picks' rows up to the chunk's capacity; six rows a position
+only where the held picks outnumber four times a uniform router's, or
+every expert is held) never exist for a whole batch; the
 head and the loss run in blocks of `loss_block` positions, so logits
 over the vocabulary never exist for a whole batch either.
 """
@@ -222,13 +230,10 @@ def route(cfg: SmallThinkerConfig, r):
     return idx, jax.nn.softmax(top, axis=-1)
 
 
-def expert_chunk(cfg: SmallThinkerConfig, p: dict, h2, r):
-    """The held experts' part of the expert layer for one chunk of
-    positions: h2 [T, H] (normed), r [T, E] -> (y [T, H],
-    load [held + 1]: picks that fell on each held expert, then the
-    chunk's picks in all)."""
-    T, H = h2.shape
-    k = cfg.experts_per_token
+def sorted_picks(cfg: SmallThinkerConfig, r):
+    """Router logits r [T, E] -> (prob [T, k], order [T*k]: the picks
+    sorted by held expert, absent experts' picks last as one group,
+    sizes [held]: picks on each held expert)."""
     first, held = cfg.held_experts
     with scope("moe_route"):
         idx, prob = route(cfg, r)
@@ -237,33 +242,195 @@ def expert_chunk(cfg: SmallThinkerConfig, p: dict, h2, r):
         group = jnp.where((local >= 0) & (local < held), local,
                           held).reshape(-1)
         order = jnp.argsort(group, stable=True)
-        inverse = jnp.argsort(order)
         sizes = (group[:, None] == jnp.arange(held)[None, :]) \
             .sum(0).astype(jnp.int32)
+    return prob, order, sizes
+
+
+def _cut_to(live):
+    """Rows past the held groups belong to no expert here; the grouped
+    product leaves its result there unwritten (whatever the buffer
+    held), forward and transposed alike, so every operand and result
+    is cut to the rows in use."""
+    return lambda x: jnp.where(live, x, jnp.zeros((), x.dtype))
+
+
+def _grouped_ffn(p: dict, rows, sizes, cut):
+    g = cut(jax.lax.ragged_dot(rows, p["gate"], sizes))
+    u = cut(jax.lax.ragged_dot(rows, p["up"], sizes))
+    return cut(jax.lax.ragged_dot(jax.nn.relu(g) * u, p["down"], sizes))
+
+
+def full_width(cfg: SmallThinkerConfig, p: dict, h2, prob, order, sizes):
+    """Every pick's row carried through the grouped products, in
+    expert order: the chunk where every expert is held. -> y [T, H]."""
+    T, H = h2.shape
+    k = cfg.experts_per_token
+    with scope("moe_route"):
+        inverse = jnp.argsort(order)
         rows = _permute(jnp.repeat(h2, k, axis=0), order, inverse)
-        # rows past the held groups belong to no expert here; the
-        # grouped product leaves its result there unwritten (whatever
-        # the buffer held), forward and transposed alike, so every
-        # operand and result is cut to the rows in use
-        live = (jnp.arange(T * k) < sizes.sum())[:, None]
-
-        def cut(x):
-            return jnp.where(live, x, jnp.zeros((), x.dtype))
-
+        cut = _cut_to((jnp.arange(T * k) < sizes.sum())[:, None])
         rows = cut(rows)
     with scope("expert_ffn"):
-        g = cut(jax.lax.ragged_dot(rows, p["gate"], sizes))
-        u = cut(jax.lax.ragged_dot(rows, p["up"], sizes))
-        out = cut(jax.lax.ragged_dot(jax.nn.relu(g) * u, p["down"],
-                                     sizes))
+        out = _grouped_ffn(p, rows, sizes, cut)
         picks = _permute(out, inverse, order).reshape(T, k, H)
-        y = (picks * prob[..., None].astype(picks.dtype)).sum(1)
-    load = jnp.concatenate([sizes, jnp.full((1,), T * k, jnp.int32)])
-    return y.astype(h2.dtype), load.astype(jnp.float32)
+        return (picks * prob[..., None].astype(picks.dtype)).sum(1)
+
+
+# The two ways between positions and the C carried rows. `tok` [C] is
+# the position of each carried row; `slot` [T, k] is the carried row
+# of each pick, C for a pick that is not carried. Each is the other's
+# transpose, so neither direction falls to a scatter.
+
+@jax.custom_vjp
+def _take(x, tok, slot):
+    """x [T, H] -> x[tok] [C, H]."""
+    return x[tok]
+
+
+@jax.custom_vjp
+def _spread(x, tok, slot):
+    """x [C, H] -> [T, H]: each position's carried rows, added up."""
+    table = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+    y = table[slot[:, 0]]
+    for j in range(1, slot.shape[1]):
+        y = y + table[slot[:, j]]
+    return y
+
+
+_take.defvjp(lambda x, tok, slot: (x[tok], (tok, slot)),
+             lambda res, g: (_spread(g, *res), None, None))
+_spread.defvjp(lambda x, tok, slot: (_spread(x, tok, slot), (tok, slot)),
+               lambda res, g: (_take(g, *res), None, None))
+
+
+def compacted(cfg: SmallThinkerConfig, C: int, p: dict, h2, prob, order,
+              sizes):
+    """`full_width` for a chunk whose held picks number at most C:
+    only the first C sorted rows exist. -> y [T, H]."""
+    T, k = h2.shape[0], cfg.experts_per_token
+    with scope("moe_route"):
+        carried = order[:C]
+        tok = carried // k
+        slot = jnp.minimum(jnp.argsort(order), C).reshape(T, k)
+        cut = _cut_to((jnp.arange(C) < sizes.sum())[:, None])
+        rows = cut(_take(h2, tok, slot))
+    with scope("expert_ffn"):
+        out = _grouped_ffn(p, rows, sizes, cut)
+        w = prob.reshape(-1)[carried].astype(out.dtype)
+        return _spread(out * w[:, None], tok, slot)
+
+
+ROW_TILE = 8      # rows of a float32 (8, 128) tile
+
+
+def compact_capacities(cfg: SmallThinkerConfig, T: int) -> Tuple[int, ...]:
+    """The static row counts a chunk of T positions may be compacted
+    to: twice and four times the held picks of a uniform router, in
+    whole row tiles, where that is under the T * k picks in all. None
+    where every expert is held."""
+    picks = T * cfg.experts_per_token
+    uniform = picks * cfg.held_experts[1] / cfg.num_experts
+    caps = {math.ceil(f * uniform / ROW_TILE) * ROW_TILE for f in (2, 4)}
+    return tuple(sorted(c for c in caps if c < picks))
+
+
+def _branches(cfg: SmallThinkerConfig, T: int):
+    """One per capacity, then one that carries every pick: exact at
+    any imbalance."""
+    return [functools.partial(compacted, cfg, C)
+            for C in compact_capacities(cfg, T)
+            + (T * cfg.experts_per_token,)]
+
+
+# Differentiated as written, a `switch` makes every branch hand back
+# zeros in place of the other branches' residuals: arrays of every
+# pick's row out of the smaller branches. So the backward pass makes
+# the choice again, each branch from its inputs. Not as a `switch`:
+# with a `conditional` in the backward pass the chip's compiler copies
+# the old weights of the round (1.48 GB in the benchmark's cell) on
+# entry, where the copy lives through the program's peak. As loops of
+# one trip or none it does not.
+
+def _once(pred, fn, otherwise, *args):
+    """fn(*args) where `pred`, else `otherwise`, as a loop of one trip
+    or none. The arguments pass a barrier together with the counter,
+    or the compiler would lift the whole body out of the loop and run
+    it whether chosen or not."""
+    def trip(i, _):
+        _, held = jax.lax.optimization_barrier((i, args))
+        return fn(*held)
+
+    return jax.lax.fori_loop(0, pred.astype(jnp.int32), trip, otherwise)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _fitting_branch(cfg, which, p, h2, prob, order, sizes):
+    return jax.lax.switch(which, _branches(cfg, h2.shape[0]), p, h2, prob,
+                          order, sizes)
+
+
+def _fitting_fwd(cfg, which, p, h2, prob, order, sizes):
+    args = (which, p, h2, prob, order, sizes)
+    return _fitting_branch(cfg, *args), args
+
+
+def _fitting_bwd(cfg, args, dy):
+    which, p, h2, prob, order, sizes = args
+
+    def grads(branch, p, h2, prob, order, sizes, dy):
+        return jax.vjp(lambda *a: branch(*a, order, sizes),
+                       p, h2, prob)[1](dy)
+
+    out = jax.tree.map(jnp.zeros_like, (p, h2, prob))
+    for i, branch in enumerate(_branches(cfg, h2.shape[0])):
+        out = _once(which == i, functools.partial(grads, branch), out,
+                    p, h2, prob, order, sizes, dy)
+    return (None, *out, None, None)
+
+
+_fitting_branch.defvjp(_fitting_fwd, _fitting_bwd)
+
+
+def expert_chunk(cfg: SmallThinkerConfig, p: dict, h2, r):
+    """The held experts' part of the expert layer for one chunk of
+    positions: h2 [T, H] (normed), r [T, E] -> (y [T, H],
+    load [held + 3]: picks that fell on each held expert, then the
+    chunk's picks in all, those of them that ran compacted (all or
+    none), and the held picks' share of all).
+
+    Where only some experts are held, the chunk runs in the smallest
+    of `compact_capacities` that holds its held picks, and with every
+    pick's row where none does."""
+    T = h2.shape[0]
+    picks = T * cfg.experts_per_token
+    prob, order, sizes = sorted_picks(cfg, r)
+    caps = compact_capacities(cfg, T)
+    live = sizes.sum()
+    if caps:
+        which = (live > jnp.asarray(caps, jnp.int32)).sum()
+        y = _fitting_branch(
+            cfg, which, {name: p[name] for name in ("gate", "up", "down")},
+            h2, prob, order, sizes)
+        compact = jnp.where(which < len(caps), picks, 0)
+    else:
+        y = full_width(cfg, p, h2, prob, order, sizes)
+        compact = 0
+    load = jnp.concatenate([
+        sizes.astype(jnp.float32),
+        jnp.stack([picks, compact, live / picks]).astype(jnp.float32)])
+    return y.astype(h2.dtype), load
+
+
+def merge_loads(load, axis: int):
+    """Loads of several chunks as one: counts add up, the last entry
+    (the held picks' share) keeps its largest."""
+    return jnp.concatenate([load[..., :-1].sum(axis),
+                            load[..., -1:].max(axis)], axis=-1)
 
 
 def expert_layer(cfg: SmallThinkerConfig, p: dict, h2, r):
-    """h2 [N, L, H], r [N, L, E] -> (y [N, L, H], load [N, held + 1]),
+    """h2 [N, L, H], r [N, L, E] -> (y [N, L, H], load [N, held + 3]),
     a chunk of positions at a time."""
     N, L, H = h2.shape
     c = _block_of(L, cfg.moe_chunk)
@@ -274,7 +441,7 @@ def expert_layer(cfg: SmallThinkerConfig, p: dict, h2, r):
         lambda xs: chunk(*xs),
         (h2.reshape(N * L // c, c, H), r.reshape(N * L // c, c, -1)))
     return (y.reshape(N, L, H),
-            load.reshape(N, L // c, -1).sum(1))
+            merge_loads(load.reshape(N, L // c, -1), 1))
 
 
 def layer(cfg: SmallThinkerConfig, rope_on: bool, window_on: bool,
@@ -291,7 +458,7 @@ def layer(cfg: SmallThinkerConfig, rope_on: bool, window_on: bool,
 
 def hidden(cfg: SmallThinkerConfig, params: dict, input_ids):
     """input_ids [N, L] -> (final normed hidden [N, L, H],
-    load [N, layers, held + 1])."""
+    load [N, layers, held + 3])."""
     x = params["embed"][input_ids]
     loads = []
     for i in range(cfg.num_layers):
@@ -340,7 +507,7 @@ def make_lm_loss(cfg: SmallThinkerConfig, pad_id: int):
     """Next-token loss over every real token of a PersonaChat
     sequence (`data/persona.py`'s batch: input_ids [.., B, C, L]
     first), for a whole cohort at once: batch leaves [W, B, ...],
-    mask [W, B] -> (losses [W], (load [W, layers, held + 1],)); the
+    mask [W, B] -> (losses [W], (load [W, layers, held + 3],)); the
     round's telemetry turns the loads into its per-layer counters
     (telemetry/metrics.expert_load_vector). A client's loss is the
     mean over the real next tokens of its valid examples."""
@@ -359,8 +526,8 @@ def make_lm_loss(cfg: SmallThinkerConfig, pad_id: int):
         nll = next_token_nll(cfg, params["head"], h, labels, valid)
         per_client = nll.reshape(W, B * C).sum(1) / jnp.maximum(
             valid.reshape(W, -1).sum(1), 1.0)
-        return per_client, (load.reshape(W, B * C, *load.shape[1:])
-                            .sum(1),)
+        return per_client, (
+            merge_loads(load.reshape(W, B * C, *load.shape[1:]), 1),)
 
     compute_loss.cohort = True
     return compute_loss

@@ -47,8 +47,8 @@ for one of them by name finds it anywhere on the path):
     attention_full, attention_window
                       RoPE and the blockwise attention core of a
                       full-attention and of a sliding-window layer
-    moe_route         the router's matmul, top-k, the sort of the
-                      picks by expert and the gather into that order
+    moe_route         the router's matmul, top-k, the sort of the picks
+                      by expert, the carried rows' gather and masks
     expert_ffn        the grouped expert products, the return to
                       position order and the weighted combine
 """

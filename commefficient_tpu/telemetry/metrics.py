@@ -39,7 +39,7 @@ Metric semantics (indices are `METRIC_NAMES` order):
                     style error feedback otherwise hides. 0 when the
                     mode has no error accumulator.
 
-A model with expert layers (Config.expert_load_layers) appends four
+A model with expert layers (Config.expert_load_layers) appends six
 counters a layer, `moe<l>_<name>` for name in LOAD_COUNTERS, from the
 per-client loads its loss reports (models/smallthinker.py):
 
@@ -52,6 +52,11 @@ per-client loads its loss reports (models/smallthinker.py):
   absent_share      the share of all picks that fell on experts this
                     chip does not hold (1 - held / all under a uniform
                     router)
+  compact_share     the share of the round's chunks of positions whose
+                    expert layer ran in one of its capacities, under a
+                    row a pick (0 where every expert is held)
+  live_peak         the held picks' share of all picks, in the chunk
+                    that had most of them
 """
 from __future__ import annotations
 
@@ -68,7 +73,8 @@ METRIC_NAMES = (
     "estimate_residual",
 )
 NUM_METRICS = len(METRIC_NAMES)
-LOAD_COUNTERS = ("routed", "max_load", "min_load", "absent_share")
+LOAD_COUNTERS = ("routed", "max_load", "min_load", "absent_share",
+                 "compact_share", "live_peak")
 METRIC_INDEX = {name: i for i, name in enumerate(METRIC_NAMES)}
 
 _EPS = 1e-12
@@ -117,14 +123,19 @@ def round_vector(losses, counts, delta, verror, vvelocity,
 
 def expert_load_vector(load) -> jnp.ndarray:
     """[layers * len(LOAD_COUNTERS)] f32 from the cohort's expert
-    loads: load [W, layers, held + 1], per client and layer the picks
-    on each held expert and, last, the picks in all."""
-    load = load.astype(jnp.float32).sum(0)          # over the cohort
-    held, picks = load[:, :-1], load[:, -1]
+    loads: load [W, layers, held + 3], per client and layer the picks
+    on each held expert, the picks in all, those of them in chunks of
+    positions that ran compacted and, last, the held picks' share of
+    the chunk that had most (models/smallthinker.expert_chunk)."""
+    load = load.astype(jnp.float32)
+    live_peak = load[..., -1].max(0)                # over the cohort
+    load = load[..., :-1].sum(0)
+    held, picks, compact = load[:, :-2], load[:, -2], load[:, -1]
     routed = held.sum(-1)
+    picks = jnp.maximum(picks, 1.0)
     return jnp.stack(
-        [routed, held.max(-1), held.min(-1),
-         1.0 - routed / jnp.maximum(picks, 1.0)], axis=-1).reshape(-1)
+        [routed, held.max(-1), held.min(-1), 1.0 - routed / picks,
+         compact / picks, live_peak], axis=-1).reshape(-1)
 
 
 def metric_names(size: int) -> tuple:

@@ -17,6 +17,10 @@ as whole tiles, which only the chip's compiler can say. And the
 per-client `masked_topk` at that cell's `[16, D]`: its threshold's
 sample must be a strided slice, not a gather.
 
+And the language model's round program whole, with one chunk of its
+expert layer alone: compacted to the held experts' rows it must move
+a fraction of the full-width path's bytes.
+
 A compile that passes is not a chip run: chip_smoke.py is.
 """
 import os
@@ -296,7 +300,14 @@ def test_smallthinker_round_fits_one_chip(smallthinker_round):
     # weights and momentum alias their outputs
     assert m.alias_size_in_bytes >= 2 * 4 * cfg.grad_size
     assert m.argument_size_in_bytes < 2.1 * 4 * cfg.grad_size
+    # 9.2 GiB. With a `conditional` in the backward pass it was 10.7:
+    # the compiler then copies the old weights (1.48 GB; the telemetry
+    # reads them after the new ones exist) on entry and not behind
+    # their last use, and the copy lives through the program's peak.
+    # So the expert layer's backward makes its choice with loops
+    # (models/smallthinker._once)
     assert total < 10 * 2 ** 30, total
+    assert "copy(%server_ps_weights" not in compiled.as_text()
 
 
 def test_smallthinker_round_runs_the_grouped_expert_kernel(
@@ -310,3 +321,70 @@ def test_smallthinker_round_runs_the_grouped_expert_kernel(
     text = compiled.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert not re.search(r"\[\d+,2,16\]", text)
+    # 8 of 64 experts held: each chunk picks its capacity as it runs,
+    # forward as a `conditional` in each of the four layers
+    assert len(re.findall(r" conditional\(", text)) == 4
+
+
+def test_compacted_expert_chunk_moves_a_fraction_of_the_bytes(one_chip):
+    """One chunk of the cell's expert layer (4,096 positions, top-6,
+    8 of 64 experts held), forward and backward as the model
+    rematerialises it: compacted to its first capacity the compiler
+    counts under 9 GB accessed where the full-width path reads 19,
+    and the grouped products are still the chip's kernel."""
+    import functools
+
+    from commefficient_tpu.models import smallthinker as st
+
+    cfg = st.SmallThinkerConfig(num_layers=4, held_experts=(0, 8),
+                                vocab_size=18992)
+    T, H, F = cfg.moe_chunk, cfg.hidden_size, cfg.expert_width
+    first = st.compact_capacities(cfg, T)[0]
+    assert first == 6144
+
+    def fwdbwd(branch):
+        def chunk(gate, up, down, h2, r):
+            p = {"gate": gate, "up": up, "down": down}
+            return branch(p, h2, *st.sorted_picks(cfg, r)).sum()
+        return jax.grad(jax.checkpoint(chunk), (0, 1, 2, 3, 4))
+
+    shapes = [((8, H, F), jnp.float32), ((8, H, F), jnp.float32),
+              ((8, F, H), jnp.float32), ((T, H), jnp.float32),
+              ((T, cfg.num_experts), jnp.float32)]
+    compact = _compile(fwdbwd(functools.partial(st.compacted, cfg, first)),
+                       *shapes, sharding=one_chip)
+    full = _compile(fwdbwd(functools.partial(st.full_width, cfg)),
+                    *shapes, sharding=one_chip)
+    assert "ragged-dot" in compact.as_text()
+    assert not re.search(rf"f32\[{T * 6},({H}|{F})\]", compact.as_text())
+    moved = compact.cost_analysis()["bytes accessed"]
+    assert moved < 9e9, moved
+    assert full.cost_analysis()["bytes accessed"] > 2 * moved
+
+
+def test_expert_chunk_backward_runs_the_chosen_branch_alone(one_chip):
+    """The chunk as the model differentiates it: the backward pass
+    holds one loop for each branch, of one trip or none, and every
+    grouped product is inside one of them. Were the bodies lifted out
+    (they depend on nothing a trip changes, but for the barrier of
+    `_once`) all three would run for every chunk: 30.3 ms a chunk on
+    the chip where one branch takes 10.9 (PERF.md section 6)."""
+    from commefficient_tpu.models import smallthinker as st
+
+    cfg = st.SmallThinkerConfig(num_layers=4, held_experts=(0, 8),
+                                vocab_size=18992)
+    T, H, F = cfg.moe_chunk, cfg.hidden_size, cfg.expert_width
+
+    def chunk(gate, up, down, h2, r):
+        p = {"gate": gate, "up": up, "down": down}
+        return st.expert_chunk(cfg, p, h2, r)[0].sum()
+
+    compiled = _compile(
+        jax.grad(jax.checkpoint(chunk), (0, 1, 2, 3, 4)),
+        ((8, H, F), jnp.float32), ((8, H, F), jnp.float32),
+        ((8, F, H), jnp.float32), ((T, H), jnp.float32),
+        ((T, cfg.num_experts), jnp.float32), sharding=one_chip)
+    entry = compiled.as_text().split("\nENTRY ")[1]
+    assert len(re.findall(r" while\(", entry)) == 3
+    assert " conditional(" not in entry and "ragged-dot" not in entry
+    assert "ragged-dot" in compiled.as_text()
