@@ -7,18 +7,19 @@ read back by the driver's own `smallthinker_config`, the ids come from
 `HashTokenizer(vocab_size)`, the loaders are the driver's, the loss is
 `build_smallthinker`'s language-model loss, then FedModel,
 FedOptimizer, round scheduler, LR schedule and telemetry as for GPT2.
-The weights are the benchmark's, made from the seed by the
-configuration's reference module.
+The weights are the benchmark's, made by the configuration's reference
+module from the configuration's `weights_seed` (one model under every
+`--seed`, which then draws the traffic alone: since the expert layer
+carries only the held picks' rows, a round's time follows the routing,
+and a random router's routing follows its weights), or from `--seed`
+where the configuration states none.
 
 At D = 6.6e8 a copy of the model is 2.6 GB, so this driver keeps none
 it does not need: the tree of weights is dropped once FedModel has
 flattened it (`Built.params` holds shapes, which is all the harness
-reads of it), and the host copy of the weights after the first round,
-which only the harness's uncompared `support_overlap` diagnostics
-read, is not made (`weights` returns None for that one call; the
-harness skips those diagnostics then). The compared readings (the
-weights before the first and after the third round, the momentum
-after the first) are whole.
+reads of it), and the host copies the harness asks for (the weights
+before the first and after the third round, the momentum after the
+first) come off the device in pieces (`reference.to_host`).
 
 `rounds` is cv_train's loop with the two byte totals read one round
 late, like the loss.
@@ -34,6 +35,7 @@ import numpy as np
 from fedbench.drivers.cv_train import (  # noqa: F401  (same contract)
     Built, RoundOut, close, common_argv, sync,
 )
+from fedbench.reference import to_host
 
 # keys of the configuration file that are the model's public config.json
 # (plus the two that say which experts of how many are held here)
@@ -45,11 +47,6 @@ PUBLISHED = (
     "num_key_value_heads", "rms_norm_eps", "rope_layout", "rope_scaling",
     "rope_theta", "sliding_window_layout", "sliding_window_size",
     "tie_word_embeddings", "vocab_size", "router_width", "held_experts")
-
-# set by state_after_first: the next `weights` call is the harness's
-# copy for its diagnostics
-_skip_next_weights = [False]
-
 
 def argv_for(config: dict, traffic: dict, seed: int, data_dir: str,
              journal: str, bf16: bool, trace: bool) -> list:
@@ -94,7 +91,8 @@ def build(config: dict, traffic: dict, ref_module, seed: int,
             f"fedbench: the corpus pads to "
             f"{train_loader.dataset.seq_len} tokens, the traffic file "
             f"states {expect}")
-    params = ref_module.init_params(config, seed)
+    params = ref_module.init_params(config,
+                                    config.get("weights_seed", seed))
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     cfg, loss_train, loss_val, params = gpt2_train.build_smallthinker(
@@ -115,7 +113,6 @@ def build(config: dict, traffic: dict, ref_module, seed: int,
     lr_scheduler = LambdaLR(opt, lr_lambda=schedule)
     tele = attach_run_telemetry(model, cfg, os.path.dirname(journal),
                                 True, driver="gpt2_train")
-    _skip_next_weights[0] = False
     return Built(model, opt, lr_scheduler, train_loader, tele, shapes)
 
 
@@ -167,25 +164,11 @@ def rounds(job: Built, tamper=None, clock=time.perf_counter):
             emit(pending)
 
 
-def _to_host(x) -> np.ndarray:
-    """A D-vector off the device, 64M coordinates at a time (the
-    runtime keeps a host staging buffer of each transfer's size)."""
-    out = np.empty(x.shape, np.float32)
-    step = 1 << 26
-    for i in range(0, x.shape[0], step):
-        out[i:i + step] = np.asarray(x[i:i + step])
-    return out
-
-
 def state_after_first(job: Built, batch) -> dict:
     """The server's momentum after the first round, copied to the
     host (the cell's mode is `uncompressed`)."""
-    _skip_next_weights[0] = True
-    return {"momentum": _to_host(job.model.server.Vvelocity)}
+    return {"momentum": to_host(job.model.server.Vvelocity)}
 
 
-def weights(job: Built):
-    if _skip_next_weights[0]:
-        _skip_next_weights[0] = False
-        return None
-    return _to_host(job.model.server.ps_weights)
+def weights(job: Built) -> np.ndarray:
+    return to_host(job.model.server.ps_weights)

@@ -16,6 +16,7 @@ import gc
 import importlib.util
 import json
 import os
+import resource
 import shutil
 import sys
 import time
@@ -236,7 +237,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         gen = drv.rounds(job, tamper=(_mask_second_half
                                       if fault == "half_batch" else None))
         feeds, lrs, prog_steps = [], [], []
-        first_state = None
+        first_state = weights_first = None
         for i in range(max(WARM_ROUNDS, CHECK_ROUNDS)):
             out = next(gen)
             if i < CHECK_ROUNDS:
@@ -246,7 +247,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                 prog_steps.append((out.outputs[0], out.upload_bytes))
                 if i == 0:
                     first_state = drv.state_after_first(job, batch)
-                    weights_first = drv.weights(job)
+                    # for `support_overlap`: how far the two sides
+                    # selected the same coordinates. An uncompressed
+                    # round selects none, so it would say nothing
+                    # there for one more D-vector held through set-up
+                    if cell.traffic["mode"] != "uncompressed":
+                        weights_first = drv.weights(job)
                     lap("first round (programs traced, compiled or "
                         "loaded)")
                 if i == CHECK_ROUNDS - 1:
@@ -266,28 +272,29 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         drv.close(job, ok)
 
     # ---- the comparison, once the window has closed and the
-    # program's state is freed
+    # program's state is freed. `program` is the only name left for
+    # its vectors, so that `take_sums` can let each go once read.
     program = ref.Readings(
         [ref.StepReadings(float(np.mean(np.asarray(l))), float(u),
                           first_state if i == 0 else {})
          for i, (l, u) in enumerate(prog_steps)], weights_checked,
         weights_first)
-    del job, gen, out
+    del job, gen, out, first_state, weights_checked, weights_first
     gc.collect()
     t_ref = time.perf_counter()
     fns = ref.make_model_fns(cell.ref_module, cell.config, template)
-    reference = ref.run_reference(
+    sums = ref.take_sums(program, ref.reference_rounds(
         ref.job_from(cell.config, cell.traffic), fns, weights0,
-        feeds, lrs)
-    checks = ref.compare(program, reference, weights0, slices,
-                         cell.traffic["limits"])
+        feeds, lrs), weights0, slices)
+    del program, weights0
+    checks = ref.compare(sums, cell.traffic["limits"])
     checks["compiles_in_window"] = {
         "value": float(window["compiles_in_window"]), "limit": 0.0}
     correct = ref.is_correct(checks)
-    for name, value in ref.diagnostics(program, reference, weights0,
-                                       slices).items():
+    for name, value in ref.diagnostics(sums).items():
         say(f"[fedbench] diagnostic {name}={value:.6g}")
-    say(f"[fedbench] reference {time.perf_counter() - t_ref:.2f} s")
+    say(f"[fedbench] reference {time.perf_counter() - t_ref:.2f} s, "
+        f"host peak {host_peak_gib():.2f} GiB resident")
 
     values = dict(window["metrics"])
     values["setup_s"] = setup_s
@@ -309,6 +316,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         result["breakdown"] = window["breakdown"]
     result["checks"] = checks
     return result
+
+
+def host_peak_gib() -> float:
+    """The process's peak resident size so far (`ru_maxrss`, which
+    Linux counts in KiB): what the next cell's comparison is sized
+    from."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2 ** 20
 
 
 def _mask_second_half(batch):

@@ -14,12 +14,20 @@ benchmark fed.
 
 The model's arithmetic runs through jax on whatever device is there
 (after the program's state has been freed); the compressor and the
-server run in numpy on the host.
+server run in numpy on the host, in float32 vectors updated in place.
+The comparison follows the reference round by round and keeps float64
+sums taken a block at a time (`take_sums`), so that at D = 6.6e8 the
+phase holds five float32 D-vectors at its peak (`weights0`, the
+program's first-round momentum, and the reference's weights, momentum
+and gradient) and none of float64.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
-from typing import Callable, Dict, List, NamedTuple, Optional
+from typing import (
+    Callable, Dict, Iterator, List, NamedTuple, Optional, Tuple,
+)
 
 import numpy as np
 
@@ -159,13 +167,17 @@ class StepReadings(NamedTuple):
     state: Dict[str, np.ndarray]      # named rows of optimizer state after the step
 
 
-class Readings(NamedTuple):
-    """What is compared, from either side: one StepReadings per
-    checked round (state kept for the first only) and the flat
-    weights after the last."""
+@dataclasses.dataclass
+class Readings:
+    """What the program's side hands to the comparison: one
+    StepReadings per checked round (state kept for the first only),
+    the flat weights after the last and, where the driver keeps them,
+    after the first. `take_sums` lets each vector go once it has read
+    it (the field becomes None, the first round's rows leave their
+    dict), so whoever built this keeps no other name for them."""
     steps: List[StepReadings]
-    weights: np.ndarray
-    weights_first: Optional[np.ndarray] = None   # after the first round
+    weights: Optional[np.ndarray]
+    weights_first: Optional[np.ndarray] = None
 
 
 class ModelFns(NamedTuple):
@@ -176,6 +188,23 @@ class ModelFns(NamedTuple):
     client_grad: Callable
     to_host: Callable
     add: Callable
+
+
+# coordinates of a D-vector brought off the device at a time
+TRANSFER = 1 << 26
+
+
+def to_host(x) -> np.ndarray:
+    """A D-vector off the device into a float32 array of the caller's
+    own, TRANSFER coordinates at a time: the runtime keeps a host
+    staging buffer of each transfer's size, and `np.asarray` of the
+    whole vector would make that one more D-vector."""
+    n = x.shape[0]
+    out = np.empty(n, np.float32)
+    for i in range(0, n, TRANSFER):
+        out[i:i + TRANSFER] = np.asarray(
+            x if n <= TRANSFER else x[i:i + TRANSFER])
+    return out
 
 
 def make_model_fns(ref_module, config: dict, template, dtype=None,
@@ -208,29 +237,40 @@ def make_model_fns(ref_module, config: dict, template, dtype=None,
         return g * mask.sum(), loss
 
     add = jax.jit(lambda a, b: a + b)
-    return ModelFns(client_grad, lambda x: np.asarray(x), add)
+    return ModelFns(client_grad, to_host, add)
 
 
-def run_reference(job: Job, fns: ModelFns, weights0: np.ndarray,
-                  feeds: list, lrs: list) -> Readings:
+def reference_rounds(job: Job, fns: ModelFns, weights0: np.ndarray,
+                     feeds: list, lrs: list
+                     ) -> Iterator[Tuple[StepReadings, np.ndarray]]:
     """Follow the rounds in `feeds` (each (client_ids, data, mask) as
-    the program was fed) from `weights0`."""
+    the program was fed) from `weights0`; after each round, its
+    StepReadings and the weights.
+
+    The server's vectors (`w`, `V`, the gradient off the device) are
+    float32 and updated in place, so that a round holds no spare
+    D-vector: the gradient's buffer takes the step `lr * update` once
+    the momentum has taken the gradient. What is yielded are those
+    vectors themselves (the weights; after the first round the rows
+    of the momentum state): the next round writes to them, so read
+    them before asking for it."""
     import jax.numpy as jnp
 
     w = np.array(weights0, np.float32)
-    sketch = (Sketch(job.d, job.num_cols, job.num_rows, job.hash_seed)
-              if job.mode == "sketch" else None)
+    decay = job.weight_decay / job.num_workers
+    sketch = E = None
     if job.mode == "sketch":
+        sketch = Sketch(job.d, job.num_cols, job.num_rows, job.hash_seed)
         V = np.zeros((job.num_rows, job.num_cols), np.float32)
-        E = np.zeros_like(V)
+        if job.error_type == "virtual":
+            E = np.zeros_like(V)
     else:
         V = np.zeros(job.d, np.float32)
-        E = np.zeros(job.d, np.float32)
     # per-client rows, held for the clients that took part
     c_err: Dict[int, np.ndarray] = {}
     c_vel: Dict[int, np.ndarray] = {}
-    steps = []
-    for (client_ids, data, mask), lr in zip(feeds, lrs):
+    for n, ((client_ids, data, mask), lr) in enumerate(zip(feeds, lrs)):
+        first = n == 0
         wdev = jnp.asarray(w)
         counts = mask.sum(axis=1)
         total = float(counts.sum())
@@ -242,9 +282,11 @@ def run_reference(job: Job, fns: ModelFns, weights0: np.ndarray,
                 g, loss = fns.client_grad(
                     wdev, tuple(x[i] for x in data), mask[i])
                 losses.append(float(loss))
-                g = fns.to_host(g) / max(float(counts[i]), 1.0)
-                g = g + (job.weight_decay / job.num_workers) * w
-                g = g * float(counts[i])
+                g = fns.to_host(g)
+                g /= max(float(counts[i]), 1.0)
+                if decay != 0:
+                    g += decay * w
+                g *= float(counts[i])
                 vel = c_vel.get(int(cid), 0.0)
                 err = c_err.get(int(cid), 0.0)
                 if job.local_momentum > 0:
@@ -262,14 +304,13 @@ def run_reference(job: Job, fns: ModelFns, weights0: np.ndarray,
                 if job.local_momentum > 0:
                     c_vel[int(cid)] = (vel * keep).astype(np.float32)
                 agg += sent
-                if len(steps) == 0:
+                if first:
                     if job.local_momentum > 0:
                         state[f"velocity[{i}]"] = c_vel[int(cid)]
                     if job.error_type == "local":
                         state[f"error[{i}]"] = c_err[int(cid)]
             gradient = (agg / max(total, 1.0)).astype(np.float32)
-            V = gradient + job.virtual_momentum * V
-            update = V
+            del agg
         else:
             acc = None
             for i in range(len(client_ids)):
@@ -277,38 +318,44 @@ def run_reference(job: Job, fns: ModelFns, weights0: np.ndarray,
                     wdev, tuple(x[i] for x in data), mask[i])
                 losses.append(float(loss))
                 acc = g if acc is None else fns.add(acc, g)
-            g = fns.to_host(acc)
-            g = g + (job.weight_decay / job.num_workers) * w * total
-            gradient = (g / max(total, 1.0)).astype(np.float32)
-            if job.mode == "sketch":
-                V = sketch.encode(gradient) + job.virtual_momentum * V
-                if job.error_type == "virtual":
-                    E = E + V
-                    table = E
-                else:
-                    table = V
-                update = top_k_dense(sketch.estimates(table), job.k)
-                keep = (sketch.encode(update) == 0)
-                if job.error_type == "virtual":
-                    E = E * keep
-                V = V * keep
-                if len(steps) == 0:
-                    for j in range(job.num_rows):
-                        state[f"momentum[{j}]"] = V[j].copy()
+            gradient = fns.to_host(acc)
+            del acc, g
+            if decay != 0:
+                gradient += decay * w * total
+            gradient /= max(total, 1.0)
+        del wdev
+        if job.mode == "sketch":
+            V *= job.virtual_momentum
+            V += sketch.encode(gradient)
+            if job.error_type == "virtual":
+                E += V
+                table = E
             else:
-                V = gradient + job.virtual_momentum * V
-                update = V
-                if len(steps) == 0:
-                    state["momentum"] = V.copy()
-        w = (w - np.float32(lr) * update).astype(np.float32)
-        if not steps:
-            w_first = w.copy()
-        steps.append(StepReadings(
+                table = V
+            update = top_k_dense(sketch.estimates(table), job.k)
+            keep = (sketch.encode(update) == 0)
+            if job.error_type == "virtual":
+                E *= keep
+            V *= keep
+            if first:
+                for j in range(job.num_rows):
+                    state[f"momentum[{j}]"] = V[j]
+        else:
+            V *= job.virtual_momentum
+            V += gradient
+            update = V
+            if first and job.mode == "uncompressed":
+                state["momentum"] = V
+        # nothing reads the gradient once the momentum has taken it
+        np.multiply(update, np.float32(lr), out=gradient)
+        w -= gradient
+        # (a suspended generator keeps its locals: the buffer goes now)
+        del gradient, update
+        yield StepReadings(
             loss=float(np.mean(losses)),
             upload_bytes=float(job.upload_bytes_per_client
                                * len(client_ids)),
-            state=state))
-    return Readings(steps, w, w_first)
+            state=state), w
 
 
 # --------------------------------------------------------------------------
@@ -317,6 +364,12 @@ def run_reference(job: Job, fns: ModelFns, weights0: np.ndarray,
 
 def _gap(a: float, b: float) -> float:
     return abs(a - b) / max(abs(b), 1e-30)
+
+
+def _reading(value: float) -> float:
+    """A number as it is printed and compared: one that is not
+    finite reads as infinite, over every limit."""
+    return float(value) if math.isfinite(value) else float("inf")
 
 
 def leaf_slices(template) -> list:
@@ -333,9 +386,126 @@ def leaf_slices(template) -> list:
     return out
 
 
-def compare(program: Readings, reference: Readings, weights0: np.ndarray,
-            slices: list, limits: dict) -> dict:
-    """Every number compared, each with its limit. Returns
+# cells of a row or of the weight vectors walked at a time: the
+# comparison's transients are a few float64 blocks of this length,
+# whatever D is (8 MB each, which still fits a host's caches: blocks
+# of 2^22 and more walk at half the speed)
+BLOCK = 1 << 20
+
+
+class RowSums(NamedTuple):
+    """One first-round state row against the reference's, over the
+    cells that both still hold."""
+    program_sq: float       # sum of squares of the program's cells there
+    reference_sq: float     # of the reference's
+    diff_sq: float          # of their difference
+    shared: int             # cells both hold (non-zero on both sides)
+    held: int               # cells the reference holds
+
+
+class Sums(NamedTuple):
+    """Everything `compare` and `diagnostics` read, taken by
+    `take_sums` while the reference follows the rounds: float64
+    scalars, none of them the length of a vector."""
+    loss_gap: float
+    upload_bytes_gap: float
+    rows: Dict[str, RowSums]            # by the reference's row names
+    program_change_sq: np.ndarray       # per leaf, the change over the
+    reference_change_sq: np.ndarray     # checked rounds, squared and summed
+    # coordinates the first round moved: (program, reference, both);
+    # None where the program's side kept no weights after it
+    updated: Optional[tuple]
+
+
+def row_sums(row: np.ndarray, ref_row: np.ndarray,
+             block: int = BLOCK) -> RowSums:
+    program_sq = reference_sq = diff_sq = 0.0
+    shared = held = 0
+    for lo in range(0, ref_row.shape[0], block):
+        p = np.asarray(row[lo:lo + block], np.float32)
+        r = ref_row[lo:lo + block]
+        both = r != 0
+        held += int(np.count_nonzero(both))
+        both &= p != 0
+        n = int(np.count_nonzero(both))
+        if n == 0:
+            continue
+        shared += n
+        if n < p.shape[0]:
+            p, r = p[both], r[both]
+        p64 = p.astype(np.float64)
+        r64 = r.astype(np.float64)
+        program_sq += float(p64 @ p64)
+        reference_sq += float(r64 @ r64)
+        p64 -= r64
+        diff_sq += float(p64 @ p64)
+    return RowSums(program_sq, reference_sq, diff_sq, shared, held)
+
+
+def change_sums(weights: np.ndarray, weights0: np.ndarray, slices: list,
+                block: int = BLOCK) -> np.ndarray:
+    """Per leaf, the sum of squares of `weights - weights0`. A block
+    gives each leaf it touches that leaf's part of it, so a leaf may
+    span blocks and a block leaves."""
+    out = np.zeros(len(slices))
+    leaf = 0
+    for lo in range(0, weights0.shape[0], block):
+        hi = min(lo + block, weights0.shape[0])
+        change = np.asarray(weights[lo:hi], np.float64) - weights0[lo:hi]
+        while leaf < len(slices) and slices[leaf][1] < hi:
+            _, a, b = slices[leaf]
+            part = change[max(a, lo) - lo:min(b, hi) - lo]
+            out[leaf] += float(part @ part)
+            if b > hi:
+                break           # the next block has the leaf's rest
+            leaf += 1
+    return out
+
+
+def updated_counts(program_w: np.ndarray, reference_w: np.ndarray,
+                   weights0: np.ndarray, block: int = BLOCK) -> tuple:
+    """Coordinates that differ from `weights0`: in the program's
+    weights, in the reference's, in both."""
+    program = reference = both = 0
+    for lo in range(0, weights0.shape[0], block):
+        w0 = weights0[lo:lo + block]
+        sp = np.asarray(program_w[lo:lo + block]) != w0
+        sr = reference_w[lo:lo + block] != w0
+        program += int(np.count_nonzero(sp))
+        reference += int(np.count_nonzero(sr))
+        sp &= sr
+        both += int(np.count_nonzero(sp))
+    return program, reference, both
+
+
+def take_sums(program: Readings, rounds: Iterator, weights0: np.ndarray,
+              slices: list, block: int = BLOCK) -> Sums:
+    """Follow the reference (`rounds`, a `reference_rounds`) and take
+    each sum as soon as both sides' vectors for it exist, `block`
+    cells at a time; each of the program's vectors is let go once
+    read (see Readings). Beside `weights0`, what is held at any time
+    is what the reference itself holds and, until its first round is
+    done, the program's first-round rows and weights."""
+    program_sq = change_sums(program.weights, weights0, slices, block)
+    program.weights = None
+    rows, updated = {}, None
+    loss_gaps, upload_gaps = [], []
+    for mine, (step, w) in zip(program.steps, rounds):
+        for name, ref_row in step.state.items():
+            rows[name] = row_sums(mine.state.pop(name), ref_row, block)
+        if program.weights_first is not None:
+            updated = updated_counts(program.weights_first, w, weights0,
+                                     block)
+            program.weights_first = None
+        loss_gaps.append(_gap(mine.loss, step.loss))
+        upload_gaps.append(abs(mine.upload_bytes - step.upload_bytes))
+    return Sums(max(loss_gaps), max(upload_gaps), rows, program_sq,
+                change_sums(w, weights0, slices, block), updated)
+
+
+def compare(sums: Sums, limits: dict) -> dict:
+    """Every number compared, each with its limit, from the sums
+    `take_sums` made of the two sides. Returns
     {name: {"value": v, "limit": l}} for the numbers the cell's
     traffic file gives a limit; `correct` is all(v <= l).
 
@@ -357,52 +527,36 @@ def compare(program: Readings, reference: Readings, weights0: np.ndarray,
     upload_bytes_gap  bytes billed per round against the
                       configuration's arithmetic (exact)
     """
-    out = {}
-    loss = max(_gap(p.loss, r.loss)
-               for p, r in zip(program.steps, reference.steps))
-    out["loss_gap"] = loss
-    worst = worst_diff = 0.0
-    ref_state = reference.steps[0].state
-    for name, ref_row in ref_state.items():
-        row = np.asarray(program.steps[0].state[name], np.float32)
-        both = (row != 0) & (ref_row != 0)
-        if not both.any():
-            worst = worst_diff = 1.0
-            continue
-        p64 = row[both].astype(np.float64)
-        r64 = ref_row[both].astype(np.float64)
-        b = float(np.linalg.norm(r64))
-        shared = both.sum() / max(int((ref_row != 0).sum()), 1)
-        # rows that share few cells were not built from one gradient
-        few = 0.0 if shared > 0.5 else 1.0
-        worst = max(worst, _gap(float(np.linalg.norm(p64)), b), few)
-        worst_diff = max(worst_diff, float(np.linalg.norm(p64 - r64))
-                         / max(b, 1e-30), few)
-    out["first_grad_gap"] = worst
-    out["first_grad_diff"] = worst_diff
-    out["change_gap"] = change_gap(program.weights, reference.weights,
-                                   weights0, slices)
-    out["upload_bytes_gap"] = max(
-        abs(p.upload_bytes - r.upload_bytes)
-        for p, r in zip(program.steps, reference.steps))
+    gap, diff = first_grad(sums)
+    out = {"loss_gap": sums.loss_gap, "first_grad_gap": gap,
+           "first_grad_diff": diff, "change_gap": change_gap(sums),
+           "upload_bytes_gap": sums.upload_bytes_gap}
     result = {}
     for name, value in out.items():
         if name not in limits:
             continue          # read by `diagnostics`, not compared here
-        if not math.isfinite(value):
-            value = float("inf")
-        result[name] = {"value": float(value),
+        result[name] = {"value": _reading(value),
                         "limit": float(limits[name])}
     return result
 
 
-_ALL = {name: 0.0 for name in (
-    "loss_gap", "first_grad_gap", "first_grad_diff", "change_gap",
-    "upload_bytes_gap")}
+def first_grad(sums: Sums) -> tuple:
+    """(`first_grad_gap`, `first_grad_diff`) of `compare`."""
+    worst = worst_diff = 0.0
+    for s in sums.rows.values():
+        if s.shared == 0:
+            worst = worst_diff = 1.0
+            continue
+        b = math.sqrt(s.reference_sq)
+        # rows that share few cells were not built from one gradient
+        few = 0.0 if s.shared / max(s.held, 1) > 0.5 else 1.0
+        worst = max(worst, _gap(math.sqrt(s.program_sq), b), few)
+        worst_diff = max(worst_diff,
+                         math.sqrt(s.diff_sq) / max(b, 1e-30), few)
+    return worst, worst_diff
 
 
-def change_gap(program_w, reference_w, weights0, slices,
-               floor: str = "rms") -> float:
+def change_gap(sums: Sums, floor: str = "rms") -> float:
     """The parameters' change by the worst leaf: the gap between the
     norms of the program's and the reference's change of that leaf,
     against the reference's norm of that leaf or a floor, whichever
@@ -413,40 +567,32 @@ def change_gap(program_w, reference_w, weights0, slices,
     median alone lets one coordinate selected on one side decide the
     number (PERF.md, section 2). `floor="median"` is the median
     alone, kept as a diagnostic."""
-    dp = np.asarray(program_w, np.float64) - weights0
-    dr = np.asarray(reference_w, np.float64) - weights0
-    ref_norms = np.array([np.linalg.norm(dr[a:b]) for _, a, b in slices])
+    ref_norms = np.sqrt(sums.reference_change_sq)
     base = float(np.median(ref_norms))
     if floor == "rms":
         base = max(base, float(np.sqrt(np.mean(ref_norms ** 2))))
     worst = 0.0
-    for (_, a, b), rn in zip(slices, ref_norms):
-        worst = max(worst, abs(float(np.linalg.norm(dp[a:b])) - rn)
+    for pn, rn in zip(np.sqrt(sums.program_change_sq), ref_norms):
+        worst = max(worst, abs(float(pn) - rn)
                     / max(float(rn), base, 1e-30))
     return worst
 
 
-def diagnostics(program: Readings, reference: Readings,
-                weights0: np.ndarray, slices: list) -> dict:
+def diagnostics(sums: Sums) -> dict:
     """Not compared: how the two sides' first updates overlap. The
     program selects with the chip's approximate top-k (recall about
     0.95) or a sampled threshold, the reference with an exact top-k,
     so `support_overlap` (the share of the reference's updated
     coordinates that the program updated too) says how much of
     `change_gap` is selection."""
-    every = compare(program, reference, weights0, slices, _ALL)
-    out = {"first_grad_diff": every["first_grad_diff"]["value"],
-           "change_gap_median_floor": change_gap(
-               program.weights, reference.weights, weights0, slices,
-               floor="median")}
-    if program.weights_first is None or reference.weights_first is None:
+    out = {"first_grad_diff": _reading(first_grad(sums)[1]),
+           "change_gap_median_floor": change_gap(sums, floor="median")}
+    if sums.updated is None:
         return out
-    sp = np.asarray(program.weights_first) != weights0
-    sr = np.asarray(reference.weights_first) != weights0
-    return {**out, "program_updated": float(sp.sum()),
-            "reference_updated": float(sr.sum()),
-            "support_overlap": float((sp & sr).sum())
-            / max(float(sr.sum()), 1.0)}
+    program, reference, both = sums.updated
+    return {**out, "program_updated": float(program),
+            "reference_updated": float(reference),
+            "support_overlap": float(both) / max(float(reference), 1.0)}
 
 
 def is_correct(checks: dict) -> bool:

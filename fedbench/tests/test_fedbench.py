@@ -255,3 +255,43 @@ def _load(rel):
     from fedbench import harness
     return harness.load_module(os.path.join(BENCH, rel),
                                "t_" + rel.replace("/", "_")[:-3])
+
+
+class _Stop(Exception):
+    pass
+
+
+@pytest.mark.parametrize("weights_seed,seed,expect", [
+    (None, 2 ** 31 + 11, 2 ** 31 + 11),
+    (7, 2 ** 31 + 11, 7),
+    (7, 12, 7),
+])
+def test_weights_come_from_the_configurations_seed(weights_seed, seed,
+                                                   expect, tmp_path):
+    """A configuration that states `weights_seed` is one model under
+    every `--seed` (the seed then draws the traffic alone: cohorts,
+    rows, order); one that does not takes its weights from `--seed`.
+    The SmallThinker cell needs the first: its round time follows the
+    routing its random weights give."""
+    from fedbench import harness, traffic as traffic_mod
+
+    cell = harness.Cell(os.path.join(HERE, "tiny_smallthinker",
+                                     "manifest.json"),
+                        "tiny_smallthinker_unc")
+    config = dict(cell.config)
+    if weights_seed is not None:
+        config["weights_seed"] = weights_seed
+    seen = []
+
+    class Ref:
+        @staticmethod
+        def init_params(cfg, s):
+            seen.append(s)
+            raise _Stop
+
+    data_dir = traffic_mod.ensure_corpus(
+        cell.traffic, os.path.join(cell.bench_dirs[0], ".cache"))
+    with pytest.raises(_Stop):
+        cell.driver.build(config, cell.traffic, Ref, seed, data_dir,
+                          str(tmp_path / "journal.jsonl"))
+    assert seen == [expect]
