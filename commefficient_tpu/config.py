@@ -572,10 +572,10 @@ class Config:
     # fed_aggregator.py:88; we return a new frozen Config instead)
     grad_size: int = 0
     # set by a driver whose model has expert layers (like grad_size, no
-    # flag): their number. The loss's last metric is then each
-    # client's expert load [layers, held + 1], and the round appends
-    # four counters a layer to its telemetry vector
-    # (telemetry/metrics.expert_load_vector).
+    # flag): their number, dense layers not counted. The loss's last
+    # metric is then each client's expert load [layers, held + 3], and
+    # the round appends six counters an expert layer to its telemetry
+    # vector (telemetry/metrics.expert_load_vector).
     expert_load_layers: int = 0
 
     # --- derived helpers -------------------------------------------------

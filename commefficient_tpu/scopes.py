@@ -51,6 +51,8 @@ for one of them by name finds it anywhere on the path):
                       by expert, the carried rows' gather and masks
     expert_ffn        the grouped expert products, the return to
                       position order and the weighted combine
+    shared_expert     an expert every position goes through beside
+                      the routed ones (models/afmoe.py)
 """
 from __future__ import annotations
 
@@ -60,7 +62,8 @@ SCOPE_PREFIX = "fed_"
 SCOPES = ("fwdbwd", "residual", "encode", "aggregate", "select",
           "server_state", "telemetry", "gather_cohort", "scatter_back",
           "pack_change_bits",
-          "attention_full", "attention_window", "moe_route", "expert_ffn")
+          "attention_full", "attention_window", "moe_route", "expert_ffn",
+          "shared_expert")
 
 
 def scope(name: str):

@@ -39,9 +39,13 @@ Metric semantics (indices are `METRIC_NAMES` order):
                     style error feedback otherwise hides. 0 when the
                     mode has no error accumulator.
 
-A model with expert layers (Config.expert_load_layers) appends six
-counters a layer, `moe<l>_<name>` for name in LOAD_COUNTERS, from the
-per-client loads its loss reports (models/smallthinker.py):
+A model with expert layers (Config.expert_load_layers: how many)
+appends six counters an expert layer, `moe<l>_<name>` for name in
+LOAD_COUNTERS, from the per-client loads its loss reports
+(models/experts.expert_chunk). `l` counts the expert layers only, in
+the order of the stack: where leading layers are dense
+(models/afmoe.py) they report no load, and moe0 is the first layer
+with experts.
 
   routed            picks of the round's positions that fell on an
                     expert this chip holds
@@ -126,7 +130,7 @@ def expert_load_vector(load) -> jnp.ndarray:
     loads: load [W, layers, held + 3], per client and layer the picks
     on each held expert, the picks in all, those of them in chunks of
     positions that ran compacted and, last, the held picks' share of
-    the chunk that had most (models/smallthinker.expert_chunk)."""
+    the chunk that had most (models/experts.expert_chunk)."""
     load = load.astype(jnp.float32)
     live_peak = load[..., -1].max(0)                # over the cohort
     load = load[..., :-1].sum(0)

@@ -37,7 +37,7 @@ from commefficient_tpu.models.gpt2 import (
     resize_position_embeddings, resize_token_embeddings, save_pretrained,
     try_load_pretrained,
 )
-from commefficient_tpu.models import smallthinker
+from commefficient_tpu.models import afmoe, smallthinker
 from commefficient_tpu.parallel import multihost as mh
 from commefficient_tpu.parallel.mesh import make_multihost_client_mesh
 from commefficient_tpu.parallel.tp import tp_loss
@@ -214,8 +214,8 @@ def train_gpt2(model: FedModel, opt: FedOptimizer, lr_scheduler,
             }
             if len(parts) == 2:
                 # the double-heads loss reports its two terms; a
-                # language-model-only loss (--model smallthinker) has
-                # none beside the loss itself
+                # language-model-only loss (--model smallthinker,
+                # trinity) has none beside the loss itself
                 row["lm_loss"] = float(np.mean(mh.gather_host(parts[0])))
                 row["mc_loss"] = float(np.mean(mh.gather_host(parts[1])))
             row["total_time"] = timer.total_time
@@ -465,43 +465,60 @@ def build_model_and_params(cfg: Config, tokenizer, seq_len: int,
     return module, params
 
 
-# ---------------- --model smallthinker -----------------------------------
+# ---------------- --model smallthinker | trinity -------------------------
 
-def smallthinker_config(cfg: Config):
-    """None unless `--model smallthinker`; else the model's sizes:
-    the tiny preset under --test, otherwise the public `config.json`
-    found in --model_checkpoint (a directory, as for GPT2), where the
-    keys `held_experts` [first, count] and `router_width` may say
-    which experts of how many this process holds (one chip's share of
-    an expert-parallel deployment). Weights are drawn from --seed:
-    no checkpoint of this family is read."""
-    if not cfg.model.lower().startswith("smallthinker"):
+# the mixture-of-experts language models, by the start of --model: the
+# model's module, its configuration class, and the key of its
+# config.json that counts its routed experts
+DECODERS = {
+    "smallthinker": (smallthinker, smallthinker.SmallThinkerConfig,
+                     "moe_num_primary_experts"),
+    "trinity": (afmoe, afmoe.AfmoeConfig, "num_experts"),
+}
+
+
+def _decoder(cfg: Config):
+    return next((entry for name, entry in DECODERS.items()
+                 if cfg.model.lower().startswith(name)), None)
+
+
+def decoder_config(cfg: Config):
+    """None unless --model names one of DECODERS; else the model's
+    sizes: the tiny preset under --test, otherwise the public
+    `config.json` found in --model_checkpoint (a directory, as for
+    GPT2), where the keys `held_experts` [first, count] and
+    `router_width` may say which experts of how many this process
+    holds (one chip's share of an expert-parallel deployment). Weights
+    are drawn from --seed: no checkpoint of these families is read."""
+    entry = _decoder(cfg)
+    if entry is None:
         return None
+    module, config_class, experts_key = entry
     if cfg.do_test:
-        return smallthinker.TINY.replace(remat=cfg.do_remat)
+        return module.TINY.replace(remat=cfg.do_remat)
     import json
     path = os.path.join(cfg.model_checkpoint, "config.json")
     if not os.path.isfile(path):
         raise FileNotFoundError(
-            f"--model smallthinker: no config.json in "
+            f"--model {cfg.model}: no config.json in "
             f"--model_checkpoint {cfg.model_checkpoint!r}")
     with open(path) as f:
         published = json.load(f)
-    experts = int(published.get("router_width",
-                                published["moe_num_primary_experts"]))
-    return smallthinker.SmallThinkerConfig.from_published(
+    experts = int(published.get("router_width", published[experts_key]))
+    return config_class.from_published(
         published, num_experts=experts,
         held_experts=tuple(published.get("held_experts", (0, experts))))
 
 
-def build_smallthinker(cfg: Config, mcfg, tokenizer, params=None):
+def build_decoder(cfg: Config, mcfg, tokenizer, params=None):
     """(cfg with the model's counters switched on, train loss, val
     loss, params): the language-model loss over every real token of
     a sequence, for FedModel. `params` may be handed in (the
     benchmark makes its own from the seed)."""
+    module = next(m for m, c, _ in DECODERS.values() if isinstance(mcfg, c))
     mcfg = mcfg.replace(vocab_size=len(tokenizer))
     pad = tokenizer.special_ids()["<pad>"]
-    loss_train = smallthinker.make_lm_loss(mcfg, pad)
+    loss_train = module.make_lm_loss(mcfg, pad)
 
     def loss_val(p, batch, mask):
         # no candidate is chosen, so no accuracy: zero in its place
@@ -510,10 +527,13 @@ def build_smallthinker(cfg: Config, mcfg, tokenizer, params=None):
 
     loss_val.cohort = True
     if params is None:
-        params = smallthinker.init_params(
-            mcfg, jax.random.PRNGKey(cfg.seed))
-    return (cfg.replace(expert_load_layers=mcfg.num_layers), loss_train,
-            loss_val, params)
+        params = module.init_params(mcfg, jax.random.PRNGKey(cfg.seed))
+    return (cfg.replace(expert_load_layers=mcfg.expert_layers),
+            loss_train, loss_val, params)
+
+
+# the names the benchmark's SmallThinker driver calls
+smallthinker_config, build_smallthinker = decoder_config, build_decoder
 
 
 def main(argv=None) -> bool:
@@ -531,10 +551,10 @@ def main(argv=None) -> bool:
     timer = Timer()
     np.random.seed(cfg.seed)
 
-    st_cfg = smallthinker_config(cfg)
-    if st_cfg is not None:
+    lm_cfg = decoder_config(cfg)
+    if lm_cfg is not None:
         # ids are drawn from the vocabulary the model holds
-        tokenizer = HashTokenizer(st_cfg.vocab_size)
+        tokenizer = HashTokenizer(lm_cfg.vocab_size)
     else:
         tokenizer = make_tokenizer(
             cfg.model_checkpoint,
@@ -557,10 +577,10 @@ def main(argv=None) -> bool:
                    for f in ("pytorch_model.bin", "pytorch_model.npz"))):
         source = cfg.finetune_path
 
-    if st_cfg is not None:
+    if lm_cfg is not None:
         module = None
-        cfg, loss_train, loss_val, params = build_smallthinker(
-            cfg, st_cfg, tokenizer)
+        cfg, loss_train, loss_val, params = build_decoder(
+            cfg, lm_cfg, tokenizer)
     else:
         module, params = build_model_and_params(
             cfg, tokenizer, seq_len, source=source,

@@ -223,12 +223,11 @@ def test_mesh_gather_moves_rows_without_the_gather_op(state_motion_mesh):
 
 # ---------------- the language model's round program, whole ---------------
 
-@pytest.fixture(scope="module")
-def smallthinker_round(topo, one_chip):
-    """The round program of the benchmark's `smallthinker_unc_w2_l8192`
-    cell (D=370,547,200: two sequences of 8,192 positions, four
-    layers, 8 of 64 experts, uncompressed with momentum), compiled
-    for one described chip as FedModel would dispatch it."""
+def _lm_round(topo, module, config_file):
+    """(cfg, compiled): the round program of the benchmark cell whose
+    configuration is `config_file` (two sequences of 8,192 positions,
+    uncompressed with momentum), compiled for one described chip as
+    FedModel would dispatch it."""
     import json
     import numpy as np
     from jax.flatten_util import ravel_pytree
@@ -236,23 +235,22 @@ def smallthinker_round(topo, one_chip):
 
     from commefficient_tpu.config import Config
     from commefficient_tpu.federated import round as fround
-    from commefficient_tpu.models import smallthinker as st
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "fedbench", "configs",
-                           "smallthinker_21b_ep8.json")) as f:
+                           config_file)) as f:
         published = json.load(f)
-    mcfg = st.SmallThinkerConfig.from_published(
+    mcfg = type(module.TINY).from_published(
         published, num_experts=published["router_width"],
         held_experts=tuple(published["held_experts"]))
-    W, L, D = 2, 8192, st.num_params(mcfg)
+    W, L, D = 2, 8192, module.num_params(mcfg)
     mesh = Mesh(np.array(topo.devices[:1]), ("clients",))
     rep, cl = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
     holder = {}
 
     def flat():
         vec, holder["unravel"] = ravel_pytree(jax.tree.map(
-            lambda s: jnp.zeros(s, jnp.float32), st.param_shapes(mcfg),
+            lambda s: jnp.zeros(s, jnp.float32), module.param_shapes(mcfg),
             is_leaf=lambda x: isinstance(x, tuple)))
         return vec
 
@@ -260,9 +258,9 @@ def smallthinker_round(topo, one_chip):
     cfg = Config(mode="uncompressed", error_type="none",
                  virtual_momentum=0.9, local_momentum=0.0, num_workers=W,
                  local_batch_size=1, weight_decay=0.0, num_clients=64,
-                 expert_load_layers=mcfg.num_layers) \
+                 expert_load_layers=mcfg.expert_layers) \
         .replace(grad_size=D).validate()
-    handle = fround.make_train_fn(st.make_lm_loss(mcfg, 4),
+    handle = fround.make_train_fn(module.make_lm_loss(mcfg, 4),
                                   holder["unravel"], cfg, mesh)
 
     def shaped(shape, dtype, sharding):
@@ -285,6 +283,33 @@ def smallthinker_round(topo, one_chip):
         .lower(server, cohort, batch, shaped((), jnp.float32, rep),
                shaped((2,), jnp.uint32, rep)).compile()
     return cfg, compiled
+
+
+@pytest.fixture(scope="module")
+def smallthinker_round(topo, one_chip):
+    """The `smallthinker_unc_w2_l8192` cell's round (D=370,547,200:
+    four layers, 8 of 64 experts)."""
+    from commefficient_tpu.models import smallthinker
+    return _lm_round(topo, smallthinker, "smallthinker_21b_ep8.json")
+
+
+def test_trinity_round_fits_one_chip(topo, one_chip):
+    """The `trinity_unc_w2_l8192` cell's round (D=504,147,712: a dense
+    layer and four expert layers, 8 of 128 experts beside a shared
+    one): 12.0 GiB, where chunks of 4,096 positions asked for 16.35,
+    over a v5e's 15.75. The expert layer's forward runs once a layer
+    (one `conditional` each): the layer keeps the routed sum for its
+    backward pass and does not run the experts again."""
+    from commefficient_tpu.models import afmoe
+    cfg, compiled = _lm_round(topo, afmoe, "trinity_mini_ep16.json")
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert m.alias_size_in_bytes >= 2 * 4 * cfg.grad_size
+    assert total < 12.5 * 2 ** 30, total
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert len(re.findall(r" conditional\(", text)) == 4
 
 
 def test_smallthinker_round_fits_one_chip(smallthinker_round):
