@@ -229,12 +229,10 @@ def attention(cfg: AfmoeConfig, p: dict, h, window_on: bool):
         k = rmsnorm(k, p["k_norm"], cfg.rms_eps)
         if window_on:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
-        a = blockwise_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            cfg.window_size if window_on else None, cfg.attn_block)
-    a = a.transpose(0, 2, 1, 3).reshape(N, L, -1)
-    return a * jax.nn.sigmoid(h @ p["wg"])
+        a = blockwise_attention(q, k, v,
+                                cfg.window_size if window_on else None,
+                                cfg.attn_block)
+    return a.reshape(N, L, -1) * jax.nn.sigmoid(h @ p["wg"])
 
 
 def mixture(cfg: AfmoeConfig, p: dict, h2):

@@ -38,14 +38,15 @@ order. The loss takes a whole cohort at once (`cohort = True`, see
 grouped products are not written per client and then vmapped.
 
 Long sequences: attention is `ops.attention.blockwise_attention`
-(scores exist a block at a time); each layer is rematerialised in the
-backward pass; the expert layer runs in chunks of `moe_chunk`
-positions, each rematerialised, so the sorted copies of the picks
-(the held picks' rows up to the chunk's capacity; six rows a position
-only where the held picks outnumber four times a uniform router's, or
-every expert is held) never exist for a whole batch; the
-head and the loss run in blocks of `loss_block` positions, so logits
-over the vocabulary never exist for a whole batch either.
+(scores exist a block at a time, on a TPU in its kernels' VMEM; q, k,
+v and its result in the projections' layout); each layer is
+rematerialised in the backward pass; the expert layer runs in chunks
+of `moe_chunk` positions, each rematerialised, so the sorted copies
+of the picks (the held picks' rows up to the chunk's capacity; six
+rows a position only where the held picks outnumber four times a
+uniform router's, or every expert is held) never exist for a whole
+batch; the head and the loss run in blocks of `loss_block` positions,
+so logits over the vocabulary never exist for a whole batch either.
 """
 from __future__ import annotations
 
@@ -189,11 +190,10 @@ def attention(cfg: SmallThinkerConfig, p: dict, h, rope_on: bool,
     with scope("attention_window" if window_on else "attention_full"):
         if rope_on:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
-        a = blockwise_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3),
-            cfg.window_size if window_on else None, cfg.attn_block)
-    return a.transpose(0, 2, 1, 3).reshape(N, L, -1)
+        a = blockwise_attention(q, k, v,
+                                cfg.window_size if window_on else None,
+                                cfg.attn_block)
+    return a.reshape(N, L, -1)
 
 
 def layer(cfg: SmallThinkerConfig, rope_on: bool, window_on: bool,

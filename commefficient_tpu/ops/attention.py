@@ -1,10 +1,12 @@
-"""Causal attention for long sequences: Pallas flash kernel + tiled VJP.
+"""Causal attention for long sequences, two ops, each a Pallas kernel
+on a TPU and the same mathematics in XLA elsewhere.
 
-The reference's GPT2 path materializes the full [B, H, L, L] score
-matrix inside pytorch_transformers (and our baseline einsum path does
-the same — models/gpt2.py SelfAttention), which is fine at PersonaChat
-lengths but quadratic-memory at long context. This module provides the
-long-context path, TPU-first:
+`flash_attention` (GPT2, models/gpt2.py at L >= 256): q, k, v
+[B, H, L, Dh], any L, returns [B, H, L, Dh]. The reference's GPT2 path
+materializes the full [B, H, L, L] score matrix inside
+pytorch_transformers (and our baseline einsum path does the same —
+models/gpt2.py SelfAttention), which is fine at PersonaChat lengths
+but quadratic-memory at long context.
 
   * forward: a hand-written Pallas kernel (`_flash_fwd_kernel`) — grid
     (batch*head, q-block, k-block) with the online-softmax state
@@ -30,7 +32,14 @@ The online-softmax block fold is shared (`online_softmax_fold`)
 between the XLA forward and `parallel/ring.py`'s ring attention — one
 copy of the numerically delicate rescaling.
 
-Shapes: q, k, v [B, H, L, Dh], any L. Returns [B, H, L, Dh].
+`blockwise_attention` (models/smallthinker.py, models/afmoe.py):
+grouped query heads, full or sliding-window causal masks, q, k, v and
+the result in the projections' layout [B, L, heads, Dh]. On a TPU one
+Pallas kernel a direction (`bw_attn_fwd`, `bw_attn_bwd`) whose grid
+holds only the (query block, key block) pairs the mask leaves
+non-empty (`pair_table`), one program per pair holding a group's
+query heads; elsewhere XLA loops over the same pairs (`_bw_fwd`,
+`_bw_bwd`). Its section below says how.
 """
 from __future__ import annotations
 
@@ -40,6 +49,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -355,32 +365,76 @@ def reference_attention(q, k, v, sm_scale: Optional[float] = None):
 #
 # The long-sequence path of models that mix full and sliding-window
 # causal attention and share each key-value head among several query
-# heads (models/smallthinker.py). Plain XLA, no kernel: an outer loop
-# over query blocks, an inner loop over just the key blocks a query
-# block can see, the online-softmax state carried between them. A
-# block the mask rules out whole is never visited, forward or
-# backward; K and V keep their Hkv heads (the query heads of a group
-# ride an extra axis of q, nothing is repeated in memory); scores
-# exist one [G * block, block] tile per key-value head at a time.
-# The matmul operands are the mathematical quantities themselves
-# (scores are scaled after the product, p is exp(s - lse)), so at the
-# chip's default precision they round as a plain softmax(QK^T) V does.
+# heads (models/smallthinker.py, models/afmoe.py). q, k, v and o come
+# and go as [B, L, heads, Dh], the reshape of what `h @ W_q` gives and
+# what `W_o` takes.
+#
+# On a TPU, one Pallas kernel a direction. The grid is (sequence,
+# key-value head, visible block pair): a static table of the (query
+# block, key block) pairs the mask leaves non-empty, built at trace
+# time from (L, window, block) and handed to the kernel by scalar
+# prefetch, so a pair the mask rules out whole is neither fetched nor
+# computed. One program holds a group's G query heads, so a k and v
+# tile is read once for all of them. Scores, probabilities, dp and ds
+# live in VMEM; the forward's online-softmax state is VMEM scratch and
+# its output block stays resident across a query block's key blocks;
+# the backward runs query blocks outer and key blocks inner, dq
+# resident across a query block's key blocks and dk, dv of the whole
+# (padded) sequence of one key-value head resident across the head's
+# pairs (2 x 4 MiB f32 at 8,192 positions, 128 wide).
+#
+# Elsewhere the same mathematics in plain XLA (`_bw_fwd`, `_bw_bwd`):
+# an outer loop over query blocks, an inner loop over the key blocks a
+# query block can see, in a [B, Hkv, G, L, Dh] layout of their own.
+#
+# Both round the same operands: every product takes bfloat16 operands
+# into a float32 accumulator, as the chip's default precision does
+# with the float32 einsums of the XLA path (q.k^T and p.v; do.v^T,
+# p^T.do, ds.k and ds^T.q), scores are scaled after the product and
+# p is exp(s - lse); m, l, lse, o and the gradients are float32.
 
 BLOCKWISE_BLOCK = 512
 
 
-def _allowed(q_pos, k_pos, window: Optional[int]):
-    ok = q_pos[:, None] >= k_pos[None, :]
+def _visible(q_pos, k_pos, window: Optional[int]):
+    """The mask, elementwise over broadcast positions."""
+    ok = q_pos >= k_pos
     if window is not None:
-        ok = ok & (k_pos[None, :] > q_pos[:, None] - window)
+        ok = ok & (k_pos > q_pos - window)
     return ok
+
+
+def _allowed(q_pos, k_pos, window: Optional[int]):
+    return _visible(q_pos[:, None], k_pos[None, :], window)
+
+
+def _span(window: Optional[int], block: int) -> Optional[int]:
+    """How many key blocks before its own a query block sees: the pair
+    (a, j) holds a visible position iff 0 <= a - j <= span (None: no
+    window). Its nearest positions lie (a - j - 1) * block + 1 apart,
+    and the window takes distances up to window - 1."""
+    if window is None:
+        return None
+    return -(-(window - 1) // block)
 
 
 def _first_block(a, window: Optional[int], block: int):
     """The first key block that query block `a` can see."""
-    if window is None:
+    span = _span(window, block)
+    if span is None:
         return jnp.zeros_like(a)
-    return jnp.maximum(a - (-(-window // block)), 0)
+    return jnp.maximum(a - span, 0)
+
+
+def pair_table(n_blocks: int, window: Optional[int], block: int):
+    """(query block, key block) int32 arrays of the pairs the mask
+    leaves non-empty, query blocks ascending and each one's key blocks
+    ascending: the kernels' grid."""
+    span = _span(window, block)
+    pairs = [(a, j) for a in range(n_blocks)
+             for j in range(0 if span is None else max(a - span, 0), a + 1)]
+    qi, kj = np.array(pairs, np.int32).T
+    return qi, kj
 
 
 def _blocked(x, block: int):
@@ -488,43 +542,307 @@ def _bw_bwd(q, k, v, o, lse, do, window, block):
             _unblocked(dvb).astype(v.dtype))
 
 
+def _heads_first(x, Hkv):
+    """[B, L, H, Dh] -> [B, Hkv, H // Hkv, L, Dh]."""
+    B, L, H, Dh = x.shape
+    return x.reshape(B, L, Hkv, H // Hkv, Dh).transpose(0, 2, 3, 1, 4)
+
+
+def _heads_last(x):
+    """Inverse of `_heads_first`."""
+    B, Hkv, G, L, Dh = x.shape
+    return x.transpose(0, 3, 1, 2, 4).reshape(B, L, Hkv * G, Dh)
+
+
+def _bw_fwd_xla(q, k, v, window, block):
+    """`_bw_fwd` in the projections' layout."""
+    Hkv = k.shape[2]
+    o, lse = _bw_fwd(_heads_first(q, Hkv), _heads_first(k, Hkv)[:, :, 0],
+                     _heads_first(v, Hkv)[:, :, 0], window, block)
+    return _heads_last(o), lse
+
+
+def _bw_bwd_xla(q, k, v, o, do, lse, window, block):
+    Hkv = k.shape[2]
+    dq, dk, dv = _bw_bwd(
+        _heads_first(q, Hkv), _heads_first(k, Hkv)[:, :, 0],
+        _heads_first(v, Hkv)[:, :, 0], _heads_first(o, Hkv),
+        lse, _heads_first(do, Hkv), window, block)
+    return (_heads_last(dq), _heads_last(dk[:, :, None]),
+            _heads_last(dv[:, :, None]))
+
+
+# ---------------- the kernels ----------------------------------------------
+#
+# The kernels read and write q, k, v, o and the gradients as [B, heads
+# * Dh, L], positions in lanes: that is how the chip's compiler lays
+# out the projections' products and the gradients the weights'
+# products take (position-minor), so the transposes around the calls
+# are free (read as [B, L, heads * Dh], the same arrays made it copy
+# each one, 268 MB a q of Trinity's). Inside, scores are [key, query],
+# each query's softmax state and lse a lane-dense row, and the only
+# transposes are of a pair's k and v tiles.
+
+# Scoped VMEM the kernels may take: the backward's resident dk and dv
+# blocks (double-buffered, 16 MiB at 8,192 positions and Dh 128) put it
+# over the default 16 MiB; a v5e core has 128 MiB.
+_BW_VMEM_BYTES = 64 * 2 ** 20
+# What the chip's default precision rounds the operands of a float32
+# product to. On a CPU, whose products do not round, a test sets it to
+# float32 to hold the kernels to the XLA path's results exactly.
+_MXU_OPERAND = jnp.bfloat16
+
+
+def _pair_mask(a, j, block: int, window: Optional[int]):
+    """[key, query] of the pair (a, j): which the mask keeps."""
+    shape = (block, block)
+    k_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    q_pos = a * block + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return _visible(q_pos, k_pos, window)
+
+
+def _tile_scores(kt, q, ok, scale):
+    """Masked, scaled [key, query] scores of one head: kt [bk, Dh],
+    q [Dh, bq]."""
+    s = jnp.dot(kt, q.astype(_MXU_OPERAND),
+                preferred_element_type=jnp.float32) * scale
+    return jnp.where(ok, s, NEG_INF)
+
+
+def _nt(a, b):
+    """a [m, n] times b [k, n] transposed -> [m, k], f32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _bw_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                   m_scr, l_scr, *, groups, head_dim, block, window):
+    """One visible pair of one (sequence, key-value head): fold key
+    block j into query block a's online-softmax state, all G query
+    heads. The state is [G, 1, block] scratch; the output block
+    accumulates in place until the pair a == j ends it and writes the
+    G rows of lse."""
+    p = pl.program_id(2)
+    a, j = qi_ref[p], kj_ref[p]
+    scale = 1.0 / math.sqrt(head_dim)
+
+    @pl.when(j == _first_block(a, window, block))
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    kt = k_ref[0].T.astype(_MXU_OPERAND)
+    v = v_ref[0].astype(_MXU_OPERAND)
+    ok = _pair_mask(a, j, block, window)
+    for g in range(groups):
+        rows = slice(g * head_dim, (g + 1) * head_dim)
+        s = _tile_scores(kt, q_ref[0, rows, :], ok, scale)
+        m_prev = m_scr[g]
+        m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+        # a query with no visible key yet keeps m = NEG_INF and counts
+        # exp(0) here; the first visible key's rescale exp(NEG_INF - m)
+        # zeroes that, and every query sees its own position
+        pr = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[g] = alpha * l_scr[g] + pr.sum(axis=0, keepdims=True)
+        o_ref[0, rows, :] = o_ref[0, rows, :] * alpha + jnp.dot(
+            v, pr.astype(_MXU_OPERAND), preferred_element_type=jnp.float32)
+        m_scr[g] = m_new
+
+    @pl.when(j == a)
+    def _finish():
+        for g in range(groups):
+            rows = slice(g * head_dim, (g + 1) * head_dim)
+            o_ref[0, rows, :] = o_ref[0, rows, :] / l_scr[g]
+            lse_ref[0, 0, g:g + 1, :] = m_scr[g] + jnp.log(l_scr[g])
+
+
+def _bw_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
+                   lse_ref, dq_ref, dk_ref, dv_ref, delta_scr, *, groups,
+                   head_dim, block, window):
+    """One visible pair of one (sequence, key-value head): the pair's
+    share of dq (resident across query block a's pairs) and of dk, dv
+    (the head's whole sequence, resident across all its pairs). A
+    query block's first pair takes its rows delta = colsum(do * o)."""
+    p = pl.program_id(2)
+    a, j = qi_ref[p], kj_ref[p]
+    scale = 1.0 / math.sqrt(head_dim)
+
+    @pl.when(p == 0)
+    def _init_kv():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+        dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
+
+    @pl.when(j == _first_block(a, window, block))
+    def _init_q():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
+        for g in range(groups):
+            rows = slice(g * head_dim, (g + 1) * head_dim)
+            delta_scr[g] = (do_ref[0, rows, :] * o_ref[0, rows, :]).sum(
+                axis=0, keepdims=True)
+
+    kt = k_ref[0].T.astype(_MXU_OPERAND)
+    vt = v_ref[0].T.astype(_MXU_OPERAND)
+    k = k_ref[0].astype(_MXU_OPERAND)
+    ok = _pair_mask(a, j, block, window)
+    dk = jnp.zeros((head_dim, block), jnp.float32)
+    dv = jnp.zeros((head_dim, block), jnp.float32)
+    for g in range(groups):
+        rows = slice(g * head_dim, (g + 1) * head_dim)
+        q = q_ref[0, rows, :].astype(_MXU_OPERAND)
+        do = do_ref[0, rows, :].astype(_MXU_OPERAND)
+        # masked scores give exp(NEG_INF - lse) = 0, pad queries
+        # (lse = LSE_PAD) exp(s - LSE_PAD) = 0
+        pr = jnp.exp(_tile_scores(kt, q, ok, scale)
+                     - lse_ref[0, 0, g:g + 1, :])
+        dv += _nt(do, pr.astype(_MXU_OPERAND))
+        dp = jnp.dot(vt, do, preferred_element_type=jnp.float32)
+        ds = (pr * (dp - delta_scr[g]) * scale).astype(_MXU_OPERAND)
+        dq_ref[0, rows, :] += jnp.dot(k, ds,
+                                      preferred_element_type=jnp.float32)
+        dk += _nt(q, ds)
+    cols = pl.ds(pl.multiple_of(j * block, block), block)
+    dk_ref[0, :, cols] += dk
+    dv_ref[0, :, cols] += dv
+
+
+def _bw_specs(groups: int, head_dim: int, block: int, Lp: int):
+    """BlockSpecs over the grid (sequence, key-value head, pair) of
+    [B, heads * Dh, Lp] operands: a query block's G heads, a key
+    block's one head, the G rows of lse of a query block ([B, Hkv, G,
+    Lp]), the head's whole sequence."""
+    return dict(
+        q=pl.BlockSpec((1, groups * head_dim, block),
+                       lambda b, h, p, qi, kj: (b, h, qi[p])),
+        kv=pl.BlockSpec((1, head_dim, block),
+                        lambda b, h, p, qi, kj: (b, h, kj[p])),
+        row=pl.BlockSpec((1, 1, groups, block),
+                         lambda b, h, p, qi, kj: (b, h, 0, qi[p])),
+        seq=pl.BlockSpec((1, head_dim, Lp),
+                         lambda b, h, p, qi, kj: (b, h, 0)))
+
+
+def _bw_call(kernel, name, operands, in_specs, out_shapes, out_specs,
+             scratch, grid, interpret):
+    return pl.pallas_call(
+        kernel, name=name, out_shape=out_shapes,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BW_VMEM_BYTES),
+        interpret=interpret)(*operands)
+
+
+def _lanes(x):
+    """[B, L, H, Dh] -> [B, H * Dh, L]."""
+    B, L, H, Dh = x.shape
+    return x.reshape(B, L, H * Dh).transpose(0, 2, 1)
+
+
+def _unlanes(x, heads: int):
+    """[B, H * Dh, L] -> [B, L, H, Dh]."""
+    B, C, L = x.shape
+    return x.transpose(0, 2, 1).reshape(B, L, heads, C // heads)
+
+
+def _bw_fwd_pallas(q, k, v, window, block, interpret=False):
+    """q [B, Lp, Hq, Dh], k and v [B, Lp, Hkv, Dh], Lp a multiple of
+    `block` -> (o [B, Lp, Hq, Dh] f32, lse [B, Hkv, G, Lp] f32)."""
+    B, Lp, Hq, Dh = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    qi, kj = pair_table(Lp // block, window, block)
+    vma, operands = vary_together(jnp.asarray(qi), jnp.asarray(kj),
+                                  _lanes(q), _lanes(k), _lanes(v))
+    spec = _bw_specs(G, Dh, block, Lp)
+    o, lse = _bw_call(
+        functools.partial(_bw_fwd_kernel, groups=G, head_dim=Dh,
+                          block=block, window=window),
+        "bw_attn_fwd", operands, [spec["q"], spec["kv"], spec["kv"]],
+        [jax.ShapeDtypeStruct((B, Hq * Dh, Lp), jnp.float32, vma=vma),
+         jax.ShapeDtypeStruct((B, Hkv, G, Lp), jnp.float32, vma=vma)],
+        [spec["q"], spec["row"]],
+        [pltpu.VMEM((G, 1, block), jnp.float32)] * 2,
+        (B, Hkv, len(qi)), interpret)
+    return _unlanes(o, Hq), lse
+
+
+def _bw_bwd_pallas(q, k, v, o, do, lse, window, block, interpret=False):
+    """The gradients of `_bw_fwd_pallas`'s o, all f32 in the layout of
+    q, k and v."""
+    B, Lp, Hq, Dh = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    qi, kj = pair_table(Lp // block, window, block)
+    vma, operands = vary_together(
+        jnp.asarray(qi), jnp.asarray(kj), _lanes(q), _lanes(k), _lanes(v),
+        _lanes(do), _lanes(o), lse)
+    spec = _bw_specs(G, Dh, block, Lp)
+    kv = jax.ShapeDtypeStruct((B, Hkv * Dh, Lp), jnp.float32, vma=vma)
+    dq, dk, dv = _bw_call(
+        functools.partial(_bw_bwd_kernel, groups=G, head_dim=Dh,
+                          block=block, window=window),
+        "bw_attn_bwd", operands,
+        [spec["q"], spec["kv"], spec["kv"], spec["q"], spec["q"],
+         spec["row"]],
+        [jax.ShapeDtypeStruct((B, Hq * Dh, Lp), jnp.float32, vma=vma),
+         kv, kv],
+        [spec["q"], spec["seq"], spec["seq"]],
+        [pltpu.VMEM((G, 1, block), jnp.float32)],
+        (B, Hkv, len(qi)), interpret)
+    return _unlanes(dq, Hq), _unlanes(dk, Hkv), _unlanes(dv, Hkv)
+
+
+# ---------------- the public op ---------------------------------------------
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def blockwise_attention(q, k, v, window: Optional[int] = None,
                         block: int = BLOCKWISE_BLOCK):
-    """Causal attention of q [B, Hq, L, Dh] over k, v [B, Hkv, L, Dh],
+    """Causal attention of q [B, L, Hq, Dh] over k, v [B, L, Hkv, Dh],
     Hq a multiple of Hkv (query head i reads key-value head
     i // (Hq // Hkv)); with `window`, position i sees only positions
     j > i - window. Any L: it is padded to a block multiple inside
     (pad keys lie after every real query, pad queries are cut off).
-    Returns [B, Hq, L, Dh]."""
+    Returns [B, L, Hq, Dh]: `.reshape(B, L, Hq * Dh)` is what an
+    output projection takes."""
     o, _ = _bwa_fwd_impl(q, k, v, window, block)
     return o
 
 
 def _bwa_pad(x, Lp):
-    pad = Lp - x.shape[-2]
+    pad = Lp - x.shape[1]
     if pad == 0:
         return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
+    return jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
 
 
-def _bwa_group(q, Hkv):
-    B, Hq, L, Dh = q.shape
-    return q.reshape(B, Hkv, Hq // Hkv, L, Dh)
-
-
-def _bwa_geometry(L: int, block: int):
-    """(block, padded length): a short sequence is one block."""
-    block = min(block, _pad_len(L, 8))
+def _bwa_geometry(L: int, block: int, lanes: int = 8):
+    """(block, padded length): a short sequence is one block, a block a
+    multiple of `lanes` (the kernels' 128: positions are their lanes)."""
+    block = _pad_len(min(block, _pad_len(L, lanes)), lanes)
     return block, _pad_len(L, block)
 
 
+def _bwa_path(L: int, block: int):
+    """(forward, backward, block, padded length) of this backend."""
+    if _on_tpu():
+        return (_bw_fwd_pallas, _bw_bwd_pallas,
+                *_bwa_geometry(L, block, 128))
+    return _bw_fwd_xla, _bw_bwd_xla, *_bwa_geometry(L, block)
+
+
 def _bwa_fwd_impl(q, k, v, window, block):
-    B, Hq, L, Dh = q.shape
-    block, Lp = _bwa_geometry(L, block)
-    o, lse = _bw_fwd(_bwa_group(_bwa_pad(q, Lp), k.shape[1]),
-                     _bwa_pad(k, Lp), _bwa_pad(v, Lp), window, block)
-    return o.reshape(B, Hq, Lp, Dh)[:, :, :L], lse[..., :L]
+    """(o [B, L, Hq, Dh], lse [B, Hkv, G, Lp] f32), lse's pad rows
+    LSE_PAD: their p vanishes in the backward pass."""
+    L = q.shape[1]
+    fwd, _, block, Lp = _bwa_path(L, block)
+    o, lse = fwd(*(_bwa_pad(x, Lp) for x in (q, k, v)), window, block)
+    if Lp > L:
+        lse = jnp.where(jnp.arange(Lp) < L, lse, LSE_PAD)
+    return o[:, :L].astype(q.dtype), lse
 
 
 def _bwa_fwd(q, k, v, window, block):
@@ -534,19 +852,13 @@ def _bwa_fwd(q, k, v, window, block):
 
 def _bwa_bwd(window, block, res, do):
     q, k, v, o, lse = res
-    B, Hq, L, Dh = q.shape
-    Hkv = k.shape[1]
-    block, Lp = _bwa_geometry(L, block)
-    pad = Lp - L
-    # pad queries: zero do, and an lse that makes their p vanish
-    lsep = (jnp.pad(lse, ((0, 0),) * 3 + ((0, pad),),
-                    constant_values=LSE_PAD) if pad else lse)
-    dq, dk, dv = _bw_bwd(
-        _bwa_group(_bwa_pad(q, Lp), Hkv), _bwa_pad(k, Lp),
-        _bwa_pad(v, Lp), _bwa_group(_bwa_pad(o, Lp), Hkv), lsep,
-        _bwa_group(_bwa_pad(do, Lp), Hkv), window, block)
-    return (dq.reshape(B, Hq, Lp, Dh)[:, :, :L], dk[:, :, :L],
-            dv[:, :, :L])
+    L = q.shape[1]
+    _, bwd, block, Lp = _bwa_path(L, block)
+    # pad queries: zero do, and lse's LSE_PAD rows
+    dq, dk, dv = bwd(*(_bwa_pad(x, Lp) for x in (q, k, v, o, do)), lse,
+                     window, block)
+    return (dq[:, :L].astype(q.dtype), dk[:, :L].astype(k.dtype),
+            dv[:, :L].astype(v.dtype))
 
 
 blockwise_attention.defvjp(_bwa_fwd, _bwa_bwd)
@@ -554,14 +866,15 @@ blockwise_attention.defvjp(_bwa_fwd, _bwa_bwd)
 
 def reference_windowed_attention(q, k, v, window: Optional[int] = None):
     """Dense masked grouped-query attention (O(L^2) memory), for
-    equivalence tests of `blockwise_attention`."""
-    B, Hq, L, Dh = q.shape
-    Hkv = k.shape[1]
-    qg = q.reshape(B, Hkv, Hq // Hkv, L, Dh).astype(jnp.float32)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k.astype(jnp.float32)) \
+    equivalence tests of `blockwise_attention`: q [B, L, Hq, Dh], k
+    and v [B, L, Hkv, Dh] -> [B, L, Hq, Dh]."""
+    B, L, Hq, Dh = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, L, Hkv, Hq // Hkv, Dh).astype(jnp.float32)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32)) \
         / math.sqrt(Dh)
     pos = jnp.arange(L)
     s = jnp.where(_allowed(pos, pos, window), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32)) \
-        .reshape(B, Hq, L, Dh).astype(q.dtype)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32)) \
+        .reshape(B, L, Hq, Dh).astype(q.dtype)

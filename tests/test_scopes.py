@@ -157,12 +157,16 @@ def test_scopes_are_metadata_three_rounds_bit_identical(mode, no_scopes):
 
 
 def test_pallas_calls_are_named():
-    """A kernel is found in a trace by name: the flash forward, the
-    repo's one kernel, passes `name=` to `pallas_call`."""
+    """A kernel is found in a trace by name: every `pallas_call` of
+    the repo (the flash forward; the grouped attention's forward and
+    backward, through one call site) passes `name=`."""
     import inspect
 
     from commefficient_tpu.ops import attention
 
     src = inspect.getsource(attention)
     assert 'name="flash_fwd"' in src
-    assert len(re.findall(r"pl\.pallas_call\(", src)) == 1
+    assert '"bw_attn_fwd"' in src and '"bw_attn_bwd"' in src
+    calls = re.findall(r"pl\.pallas_call\((.*?)\)", src, re.S)
+    assert len(calls) == 2
+    assert all("name=" in c for c in calls)
