@@ -69,11 +69,12 @@ def make_flat_grad_fn(loss_fn: LossFn, unravel: Callable,
     reference's fp32-only CUDA path doesn't have)."""
     def flat_grad(weights_vec, batch, mask):
         def scalar_loss(vec):
-            params = unravel(vec)
             b = batch
-            if compute_dtype is not None:
-                params = _cast_tree(params, compute_dtype)
-                b = _cast_tree(b, compute_dtype)
+            with scope("unravel"):
+                params = unravel(vec)
+                if compute_dtype is not None:
+                    params = _cast_tree(params, compute_dtype)
+                    b = _cast_tree(b, compute_dtype)
             loss, metrics = loss_fn(params, b, mask)
             return loss.astype(jnp.float32), _cast_tree(
                 metrics, jnp.float32)
@@ -90,10 +91,11 @@ def make_flat_loss_fn(loss_fn: LossFn, unravel: Callable,
     eval cost and compile time are forward-only by construction, not by
     hoping XLA DCEs an unused gradient (this matters at GPT2 size)."""
     def flat_loss(weights_vec, batch, mask):
-        params = unravel(weights_vec)
-        if compute_dtype is not None:
-            params = _cast_tree(params, compute_dtype)
-            batch = _cast_tree(batch, compute_dtype)
+        with scope("unravel"):
+            params = unravel(weights_vec)
+            if compute_dtype is not None:
+                params = _cast_tree(params, compute_dtype)
+                batch = _cast_tree(batch, compute_dtype)
         loss, metrics = loss_fn(params, batch, mask)
         return loss.astype(jnp.float32), _cast_tree(metrics, jnp.float32)
     flat_loss.cohort = is_cohort_loss(loss_fn)

@@ -34,9 +34,10 @@ all k picks, held or not.
 The routed experts are `models/experts.py`'s held-expert layer, the
 one SmallThinker's layer calls: this model gives it the sigmoid router
 (`route`) and `silu`. The shared expert runs for every position under
-the scope `shared_expert`. Everything is a function of a plain
-parameter tree, the one the benchmark's plain reference builds
-(`fedbench/configs/afmoe.py`), flattened in `jax.tree_util` order.
+the scope `shared_expert`, the dense layers' SwiGLU under `dense_mlp`.
+Everything is a function of a plain parameter tree, the one the
+benchmark's plain reference builds (`fedbench/configs/afmoe.py`),
+flattened in `jax.tree_util` order.
 Long sequences as in SmallThinker: blockwise attention, each layer
 rematerialised, the expert layer in chunks, head and loss in blocks.
 """
@@ -221,9 +222,10 @@ def swiglu(h, gate, up, down):
 def attention(cfg: AfmoeConfig, p: dict, h, window_on: bool):
     """h [N, L, H] (normed) -> [N, L, num_heads * head_dim], gated."""
     N, L, _ = h.shape
-    q = (h @ p["wq"]).reshape(N, L, cfg.num_heads, cfg.head_dim)
-    k = (h @ p["wk"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
-    v = (h @ p["wv"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
+    with scope("attn_proj"):
+        q = (h @ p["wq"]).reshape(N, L, cfg.num_heads, cfg.head_dim)
+        k = (h @ p["wk"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
+        v = (h @ p["wv"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
     with scope("attention_window" if window_on else "attention_full"):
         q = rmsnorm(q, p["q_norm"], cfg.rms_eps)
         k = rmsnorm(k, p["k_norm"], cfg.rms_eps)
@@ -232,7 +234,8 @@ def attention(cfg: AfmoeConfig, p: dict, h, window_on: bool):
         a = blockwise_attention(q, k, v,
                                 cfg.window_size if window_on else None,
                                 cfg.attn_block)
-    return a.reshape(N, L, -1) * jax.nn.sigmoid(h @ p["wg"])
+    with scope("attn_proj"):
+        return a.reshape(N, L, -1) * jax.nn.sigmoid(h @ p["wg"])
 
 
 def mixture(cfg: AfmoeConfig, p: dict, h2):
@@ -249,14 +252,22 @@ def mixture(cfg: AfmoeConfig, p: dict, h2):
 
 def layer(cfg: AfmoeConfig, dense: bool, window_on: bool, p: dict, x):
     eps = cfg.rms_eps
-    a = attention(cfg, p, rmsnorm(x, p["norm_in"], eps), window_on)
-    x1 = x + rmsnorm(a @ p["wo"], p["norm_post_attn"], eps)
-    h2 = rmsnorm(x1, p["norm_pre_mlp"], eps)
+    with scope("norm"):
+        h = rmsnorm(x, p["norm_in"], eps)
+    a = attention(cfg, p, h, window_on)
+    with scope("attn_proj"):
+        o = a @ p["wo"]
+    with scope("norm"):
+        x1 = x + rmsnorm(o, p["norm_post_attn"], eps)
+        h2 = rmsnorm(x1, p["norm_pre_mlp"], eps)
     if dense:
-        f, load = swiglu(h2, p["mlp_gate"], p["mlp_up"], p["mlp_down"]), None
+        with scope("dense_mlp"):
+            f = swiglu(h2, p["mlp_gate"], p["mlp_up"], p["mlp_down"])
+        load = None
     else:
         f, load = mixture(cfg, p, h2)
-    return x1 + rmsnorm(f, p["norm_post_mlp"], eps), load
+    with scope("norm"):
+        return x1 + rmsnorm(f, p["norm_post_mlp"], eps), load
 
 
 def hidden(cfg: AfmoeConfig, params: dict, input_ids):
@@ -275,8 +286,9 @@ def hidden(cfg: AfmoeConfig, params: dict, input_ids):
         x, load = f(params[f"layer_{i}"], x)
         if load is not None:
             loads.append(load)
-    return (rmsnorm(x, params["final_norm"], cfg.rms_eps),
-            jnp.stack(loads, axis=1))
+    with scope("norm"):
+        x = rmsnorm(x, params["final_norm"], cfg.rms_eps)
+    return x, jnp.stack(loads, axis=1)
 
 
 def logits(cfg: AfmoeConfig, params: dict, input_ids):

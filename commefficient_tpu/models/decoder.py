@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from commefficient_tpu.models.experts import block_of, merge_loads
+from commefficient_tpu.scopes import scope
 
 
 def rmsnorm(x, g, eps: float):
@@ -75,7 +76,8 @@ def make_lm_loss(cfg, pad_id: int, hidden):
                  * mask[:, :, None, None]).astype(jnp.float32) \
             .reshape(W * B * C, L)
         h, load = hidden(cfg, params, ids)
-        nll = next_token_nll(cfg, params["head"], h, labels, valid)
+        with scope("head_loss"):
+            nll = next_token_nll(cfg, params["head"], h, labels, valid)
         per_client = nll.reshape(W, B * C).sum(1) / jnp.maximum(
             valid.reshape(W, -1).sum(1), 1.0)
         return per_client, (

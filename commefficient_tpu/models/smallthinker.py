@@ -184,9 +184,10 @@ def attention(cfg: SmallThinkerConfig, p: dict, h, rope_on: bool,
               window_on: bool):
     """h [N, L, H] (normed) -> [N, L, num_heads * head_dim]."""
     N, L, _ = h.shape
-    q = (h @ p["wq"]).reshape(N, L, cfg.num_heads, cfg.head_dim)
-    k = (h @ p["wk"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
-    v = (h @ p["wv"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
+    with scope("attn_proj"):
+        q = (h @ p["wq"]).reshape(N, L, cfg.num_heads, cfg.head_dim)
+        k = (h @ p["wk"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
+        v = (h @ p["wv"]).reshape(N, L, cfg.num_kv_heads, cfg.head_dim)
     with scope("attention_window" if window_on else "attention_full"):
         if rope_on:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
@@ -200,12 +201,17 @@ def layer(cfg: SmallThinkerConfig, rope_on: bool, window_on: bool,
           p: dict, x):
     with scope("moe_route"):
         r = x @ p["router"]
-    a = attention(cfg, p, rmsnorm(x, p["norm1"], cfg.rms_eps), rope_on,
-                  window_on)
-    x1 = x + a @ p["wo"]
-    y, load = expert_layer(
-        cfg, p, rmsnorm(x1, p["norm2"], cfg.rms_eps), r)
-    return x1 + y, load
+    with scope("norm"):
+        h = rmsnorm(x, p["norm1"], cfg.rms_eps)
+    a = attention(cfg, p, h, rope_on, window_on)
+    with scope("attn_proj"):
+        o = a @ p["wo"]
+    with scope("norm"):
+        x1 = x + o
+        h2 = rmsnorm(x1, p["norm2"], cfg.rms_eps)
+    y, load = expert_layer(cfg, p, h2, r)
+    with scope("norm"):
+        return x1 + y, load
 
 
 def hidden(cfg: SmallThinkerConfig, params: dict, input_ids):
@@ -220,8 +226,9 @@ def hidden(cfg: SmallThinkerConfig, params: dict, input_ids):
             f = jax.checkpoint(f)
         x, load = f(params[f"layer_{i}"], x)
         loads.append(load)
-    return (rmsnorm(x, params["final_norm"], cfg.rms_eps),
-            jnp.stack(loads, axis=1))
+    with scope("norm"):
+        x = rmsnorm(x, params["final_norm"], cfg.rms_eps)
+    return x, jnp.stack(loads, axis=1)
 
 
 def logits(cfg: SmallThinkerConfig, params: dict, input_ids):

@@ -53,6 +53,22 @@ for one of them by name finds it anywhere on the path):
                       position order and the weighted combine
     shared_expert     an expert every position goes through beside
                       the routed ones (models/afmoe.py)
+    attn_proj         the q, k, v input projections, the output gate's
+                      projection and its product (models/afmoe.py),
+                      the output projection
+    dense_mlp         a dense layer's SwiGLU (models/afmoe.py)
+    norm              the residual stream's RMSNorms with the residual
+                      adds they wrap, and the final norm
+    head_loss         the head product, the softmax and the label pick
+                      in blocks, and their sums (decoder.next_token_nll)
+    unravel           the flat vector's split into the model's leaves
+                      and their cast (client.make_flat_grad_fn,
+                      make_flat_loss_fn); its transpose assembles the
+                      flat gradient
+
+Each is laid at its call sites, never inside a helper another layer
+calls too (`rmsnorm` also normalises q and k under `attention_*`), so
+that no op's path carries two inner names.
 """
 from __future__ import annotations
 
@@ -63,7 +79,8 @@ SCOPES = ("fwdbwd", "residual", "encode", "aggregate", "select",
           "server_state", "telemetry", "gather_cohort", "scatter_back",
           "pack_change_bits",
           "attention_full", "attention_window", "moe_route", "expert_ffn",
-          "shared_expert")
+          "shared_expert", "attn_proj", "dense_mlp", "norm", "head_loss",
+          "unravel")
 
 
 def scope(name: str):

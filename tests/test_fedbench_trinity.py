@@ -146,7 +146,9 @@ def test_registered_file_is_the_catalog_row_but_for_reduced():
              if cell["name"] in m.get("workloads", ())}
     assert names == {"attention_full_ms", "attention_window_ms",
                      "moe_route_ms", "expert_ffn_ms", "expert_ffn_roofline",
-                     "shared_expert_ms"}
+                     "shared_expert_ms", "attn_proj_ms", "norm_ms",
+                     "head_loss_ms", "unravel_ms", "dense_mlp_ms",
+                     "fwdbwd_other_ms"}
     for name in names:
         assert os.path.isfile(os.path.join(BENCH, "metrics", name + ".py"))
 
@@ -202,5 +204,80 @@ def test_shared_expert_reader(monkeypatch):
     reader = load("metrics", "shared_expert_ms")
     assert reader.read({"rounds": 2}) == pytest.approx(1000.0)
     del paths[1], paths[2]
+    monkeypatch.setattr(_scopes, "_cache", {})
+    assert reader.read({"rounds": 2}) is None
+
+
+def _fake_plane(monkeypatch, paths, names, ops):
+    from fedbench import reduce as reducer
+    from fedbench.metrics import _scopes
+
+    plane = {"tf_op": paths, "names": names,
+             "lines": {reducer.OP_LINE: ops, reducer.MODULE_LINE: []}}
+    monkeypatch.setattr(_scopes, "_xplane", lambda ctx: "fake.pb")
+    monkeypatch.setattr(_scopes, "_device_planes",
+                        lambda path: {"/device:TPU:0": plane})
+    monkeypatch.setattr(_scopes, "_cache", {})
+
+
+# a named op, an unnamed one and a `while` over both, inside `fwdbwd`;
+# an op outside it; an op in the head's loop
+NAMED_PATHS = {
+    1: "jit(round_step)/fed_fwdbwd/transpose(jvp(fed_fwdbwd))/checkpoint"
+       "/fed_attn_proj/dot_general",
+    2: "jit(round_step)/fed_fwdbwd/jvp()/closed_call/while/body/add",
+    3: "jit(round_step)/fed_fwdbwd/jvp()/closed_call/while",
+    4: "jit(round_step)/fed_telemetry/reduce_sum",
+    5: "jit(round_step)/fed_fwdbwd/jvp(fed_head_loss)/while/body/dot",
+}
+NAMED_HLO = {
+    1: "%fusion.7 = f32[2,8192,4096]{1,2,0:T(8,128)} fusion(f32[2,8192,"
+       "2048]{2,1,0:T(8,128)} %p.1), kind=kOutput, calls=%fused.7",
+    2: "%add.3 = f32[2048]{0:T(1024)} add(f32[2048]{0} %a, f32[2048]{0} %b)",
+    3: "%while.616 = (s32[], f32[2048]{0:T(1024)S(1)}) while((s32[], "
+       "f32[2048]{0}) %tuple.2), condition=%cond.1, body=%body.1",
+    4: "%reduce.9 = f32[] reduce(f32[8]{0} %x, f32[] %c), to_apply=%sum",
+    5: "%convolution.4 = f32[2048,128]{1,0} convolution(f32[2048,64]{1,0}"
+       " %h, f32[64,128]{1,0} %w), dim_labels=bf_io->bf",
+}
+NAMED_OPS = [(3, 0.0, 4.0), (1, 0.0, 1.0), (2, 1.5, 2.5), (2, 2.0, 3.0),
+             (4, 5.0, 9.0), (5, 9.0, 10.0)]
+
+
+def test_new_layer_reader(monkeypatch):
+    """`metrics/attn_proj_ms.py` reads its name anywhere on an op's
+    path (the `while` over it carries another path and is not its);
+    a program without the name gives None."""
+    paths = dict(NAMED_PATHS)
+    _fake_plane(monkeypatch, paths, dict(NAMED_HLO), NAMED_OPS)
+    reader = load("metrics", "attn_proj_ms")
+    assert reader.read({"rounds": 2}) == pytest.approx(500.0)
+    del paths[1]
+    from fedbench.metrics import _scopes
+    monkeypatch.setattr(_scopes, "_cache", {})
+    assert reader.read({"rounds": 2}) is None
+
+
+def test_fwdbwd_other_reader(monkeypatch, capsys):
+    """`metrics/fwdbwd_other_ms.py` counts the interval union of the
+    ops under `fwdbwd` with none of the ten inner names, leaves out the
+    `while` that covers them, and the ops outside `fwdbwd`; it names
+    the instructions it counted; a program that lays none of the five
+    new names gives None."""
+    paths = dict(NAMED_PATHS)
+    _fake_plane(monkeypatch, paths, dict(NAMED_HLO), NAMED_OPS)
+    reader = load("metrics", "fwdbwd_other_ms")
+    # add.3 over [1.5, 3.0): 1.5 s in 2 rounds
+    assert reader.read({"rounds": 2}) == pytest.approx(750.0)
+    assert '["add.3:f32[2048]", 1000.0]' in capsys.readouterr().err
+    assert reader.is_container(NAMED_HLO[3])
+    assert reader.is_container("%conditional.2 = (f32[8]{0}) conditional("
+                               "s32[] %i, f32[8]{0} %a), branch_computat"
+                               "ions={%b0, %b1}")
+    assert not any(reader.is_container(NAMED_HLO[k]) for k in (1, 2, 4, 5))
+    # the parent: the old inner names only
+    paths[1] = paths[1].replace("fed_attn_proj", "fed_attention_full")
+    paths[5] = paths[5].replace("fed_head_loss", "fed_expert_ffn")
+    from fedbench.metrics import _scopes
     monkeypatch.setattr(_scopes, "_cache", {})
     assert reader.read({"rounds": 2}) is None

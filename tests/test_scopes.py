@@ -3,7 +3,8 @@ round, gather, scatter and pack programs carry every scope name their
 mode has; scopes are metadata, so the lowered program text without
 locations, the three-program dispatch, its composed twin and three
 rounds' ServerState are what they are without them; the
-pallas_call is named."""
+pallas_call is named. The language models' inner names are metadata
+too, each alone on an op's path and inside `fwdbwd`."""
 import contextlib
 import re
 
@@ -16,6 +17,7 @@ from commefficient_tpu import scopes
 from commefficient_tpu.config import Config
 from commefficient_tpu.federated import accounting, client, server
 from commefficient_tpu.federated import round as fround
+from commefficient_tpu.models import afmoe, decoder, experts, smallthinker
 from commefficient_tpu.ops.flat import flatten_params
 from commefficient_tpu.parallel.mesh import make_client_mesh
 
@@ -31,11 +33,11 @@ MODES = {
 }
 # the names each mode's programs must carry (table A of ISSUE 27)
 ROUND_SCOPES = {
-    "sketch": {"fwdbwd", "encode", "aggregate", "select",
+    "sketch": {"fwdbwd", "unravel", "encode", "aggregate", "select",
                "server_state", "telemetry"},
-    "local_topk": {"fwdbwd", "residual", "aggregate", "server_state",
-                   "telemetry"},
-    "true_topk": {"fwdbwd", "residual", "aggregate", "select",
+    "local_topk": {"fwdbwd", "unravel", "residual", "aggregate",
+                   "server_state", "telemetry"},
+    "true_topk": {"fwdbwd", "unravel", "residual", "aggregate", "select",
                   "server_state", "telemetry"},
 }
 
@@ -117,7 +119,8 @@ def no_scopes(monkeypatch):
     """The program as it was before the scopes: every module's
     `scope` hands back a null context."""
     def off():
-        for mod in (client, server, fround, accounting):
+        for mod in (client, server, fround, accounting, afmoe,
+                    smallthinker, decoder, experts):
             monkeypatch.setattr(mod, "scope",
                                 lambda name: contextlib.nullcontext())
     return off
@@ -154,6 +157,95 @@ def test_scopes_are_metadata_three_rounds_bit_identical(mode, no_scopes):
     assert text1 == text0
     for a, b in zip(server1 + clients1, server0 + clients0):
         np.testing.assert_array_equal(a, b)
+
+
+# the language models' newer inner names, each in both models' loss and
+# gradient but `dense_mlp`, which only Trinity's dense layer lays
+NEW_INNER = {"attn_proj", "dense_mlp", "norm", "head_loss", "unravel"}
+MODELS = {"trinity": afmoe, "smallthinker": smallthinker}
+
+
+def _laid(name):
+    return NEW_INNER - ({"dense_mlp"} if name == "smallthinker" else set())
+
+
+def _lm_grad(name):
+    """The tiny model's loss and gradient as the fused backward runs
+    them: the cohort loss lifted to the flat vector, under `fwdbwd`."""
+    mod = MODELS[name]
+    cfg = mod.TINY
+    vec, unravel = flatten_params(mod.init_params(cfg, jax.random.PRNGKey(0)))
+    flat_loss = client.make_flat_loss_fn(mod.make_lm_loss(cfg, 0), unravel)
+    rcfg = Config(mode="uncompressed", grad_size=vec.size, num_workers=2,
+                  weight_decay=5e-4, local_momentum=0.0,
+                  virtual_momentum=0.0).validate()
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 1, 1, 16), 1,
+                             cfg.vocab_size)
+    mask = jnp.ones((2, 1), jnp.float32)
+
+    def grad(w):
+        g, losses, _, _ = client.fused_shard_grads(flat_loss, w, (ids,),
+                                                   mask, rcfg)
+        return g, losses
+
+    lowered = jax.jit(grad).lower(vec)
+    compiled = lowered.compile()
+    g, losses = compiled(vec)
+    return {"text": lowered.as_text(), "names": _names(lowered),
+            "compiled": compiled.as_text(), "grad": np.asarray(g),
+            "losses": np.asarray(losses)}
+
+
+@pytest.fixture(scope="module")
+def lm_scoped():
+    """`_lm_grad` of each model with its names, made once."""
+    made = {}
+
+    def get(name):
+        if name not in made:
+            made[name] = _lm_grad(name)
+        return made[name]
+    return get
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_model_names_are_metadata(name, lm_scoped, no_scopes):
+    """With the inner names and without: the lowered loss and gradient
+    (locations stripped) are the same text, loss and gradient the same
+    bits; every new name is in the program, `dense_mlp` in Trinity's
+    only."""
+    scoped = lm_scoped(name)
+    no_scopes()
+    bare = _lm_grad(name)
+    assert scoped["names"] & NEW_INNER == _laid(name)
+    assert bare["names"] == set()
+    assert scoped["text"] == bare["text"]
+    for key in ("grad", "losses"):
+        np.testing.assert_array_equal(scoped[key], bare[key])
+    assert np.isfinite(scoped["losses"]).all()
+    assert np.abs(scoped["grad"]).max() > 0
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_new_names_stand_alone_inside_fwdbwd(name, lm_scoped):
+    """In the compiled program, an op that carries a new name carries
+    no other inner name and lies under `fwdbwd`, so the outermost-name
+    rule still gives it to `client_fwdbwd_ms`. Where the compiler
+    merges instructions it joins their names with ';': each name is
+    checked on its own."""
+    text = lm_scoped(name)["compiled"]
+    seen = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        for path in op_name.split(";"):
+            inner = set(re.findall(
+                r"(?:^|[/(])fed_([a-z_]+)(?=[/):]|$)", path)) - {"fwdbwd"}
+            new = inner & NEW_INNER
+            if new:
+                assert inner == new and len(new) == 1, path
+                assert re.search(r"(?:^|[/(])fed_fwdbwd(?=[/):]|$)",
+                                 path), path
+                seen |= new
+    assert seen == _laid(name)
 
 
 def test_pallas_calls_are_named():
