@@ -573,8 +573,8 @@ class Config:
     grad_size: int = 0
     # set by a driver whose model has expert layers (like grad_size, no
     # flag): their number, dense layers not counted. The loss's last
-    # metric is then each client's expert load [layers, held + 3], and
-    # the round appends six counters an expert layer to its telemetry
+    # metric is then each client's expert load [layers, held + 4], and
+    # the round appends seven counters an expert layer to its telemetry
     # vector (telemetry/metrics.expert_load_vector).
     expert_load_layers: int = 0
 

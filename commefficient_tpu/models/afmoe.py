@@ -240,7 +240,7 @@ def attention(cfg: AfmoeConfig, p: dict, h, window_on: bool):
 
 def mixture(cfg: AfmoeConfig, p: dict, h2):
     """The expert layer: h2 [N, L, H] (normed) -> (shared expert plus
-    the held experts' part [N, L, H], load [N, held + 3])."""
+    the held experts' part [N, L, H], load [N, held + 4])."""
     with scope("moe_route"):
         r = h2 @ p["router"]
     with scope("shared_expert"):
@@ -272,7 +272,7 @@ def layer(cfg: AfmoeConfig, dense: bool, window_on: bool, p: dict, x):
 
 def hidden(cfg: AfmoeConfig, params: dict, input_ids):
     """input_ids [N, L] -> (final normed hidden [N, L, H],
-    load [N, expert layers, held + 3]): the dense layers report no
+    load [N, expert layers, held + 4]): the dense layers report no
     load."""
     x = params["embed"][input_ids]
     if cfg.mup:
@@ -299,5 +299,5 @@ def logits(cfg: AfmoeConfig, params: dict, input_ids):
 
 def make_lm_loss(cfg: AfmoeConfig, pad_id: int):
     """`decoder.make_lm_loss` over this model's layers:
-    losses [W], (load [W, expert layers, held + 3],)."""
+    losses [W], (load [W, expert layers, held + 4],)."""
     return decoder.make_lm_loss(cfg, pad_id, hidden)

@@ -58,7 +58,7 @@ def make_lm_loss(cfg, pad_id: int, hidden):
     """Next-token loss over every real token of a PersonaChat
     sequence (`data/persona.py`'s batch: input_ids [.., B, C, L]
     first), for a whole cohort at once: batch leaves [W, B, ...],
-    mask [W, B] -> (losses [W], (load [W, layers, held + 3],)), where
+    mask [W, B] -> (losses [W], (load [W, layers, held + 4],)), where
     `hidden(cfg, params, ids)` gives the final normed hidden states
     and the expert layers' loads; the round's telemetry turns the
     loads into its per-layer counters

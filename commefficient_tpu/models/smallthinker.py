@@ -41,7 +41,8 @@ Long sequences: attention is `ops.attention.blockwise_attention`
 (scores exist a block at a time, on a TPU in its kernels' VMEM; q, k,
 v and its result in the projections' layout); each layer is
 rematerialised in the backward pass; the expert layer runs in chunks
-of `moe_chunk` positions, each rematerialised, so the sorted copies
+of `moe_chunk` positions, each computed again in the layer's own
+backward pass (`models/experts.py`), so the sorted copies
 of the picks (the held picks' rows up to the chunk's capacity; six
 rows a position only where the held picks outnumber four times a
 uniform router's, or every expert is held) never exist for a whole
@@ -216,7 +217,7 @@ def layer(cfg: SmallThinkerConfig, rope_on: bool, window_on: bool,
 
 def hidden(cfg: SmallThinkerConfig, params: dict, input_ids):
     """input_ids [N, L] -> (final normed hidden [N, L, H],
-    load [N, layers, held + 3])."""
+    load [N, layers, held + 4])."""
     x = params["embed"][input_ids]
     loads = []
     for i in range(cfg.num_layers):
@@ -241,5 +242,5 @@ def logits(cfg: SmallThinkerConfig, params: dict, input_ids):
 
 def make_lm_loss(cfg: SmallThinkerConfig, pad_id: int):
     """`decoder.make_lm_loss` over this model's layers:
-    losses [W], (load [W, layers, held + 3],)."""
+    losses [W], (load [W, layers, held + 4],)."""
     return decoder.make_lm_loss(cfg, pad_id, hidden)

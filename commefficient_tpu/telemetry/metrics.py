@@ -40,9 +40,9 @@ Metric semantics (indices are `METRIC_NAMES` order):
                     mode has no error accumulator.
 
 A model with expert layers (Config.expert_load_layers: how many)
-appends six counters an expert layer, `moe<l>_<name>` for name in
+appends seven counters an expert layer, `moe<l>_<name>` for name in
 LOAD_COUNTERS, from the per-client loads its loss reports
-(models/experts.expert_chunk). `l` counts the expert layers only, in
+(models/experts.expert_layer). `l` counts the expert layers only, in
 the order of the stack: where leading layers are dense
 (models/afmoe.py) they report no load, and moe0 is the first layer
 with experts.
@@ -59,6 +59,10 @@ with experts.
   compact_share     the share of the round's chunks of positions whose
                     expert layer ran in one of its capacities, under a
                     row a pick (0 where every expert is held)
+  chunks            the round's chunks of positions whose weight
+                    gradient went into the layer's one accumulator
+                    (models/experts._chunks): every chunk the layer
+                    ran
   live_peak         the held picks' share of all picks, in the chunk
                     that had most of them
 """
@@ -78,7 +82,7 @@ METRIC_NAMES = (
 )
 NUM_METRICS = len(METRIC_NAMES)
 LOAD_COUNTERS = ("routed", "max_load", "min_load", "absent_share",
-                 "compact_share", "live_peak")
+                 "compact_share", "chunks", "live_peak")
 METRIC_INDEX = {name: i for i, name in enumerate(METRIC_NAMES)}
 
 _EPS = 1e-12
@@ -127,19 +131,21 @@ def round_vector(losses, counts, delta, verror, vvelocity,
 
 def expert_load_vector(load) -> jnp.ndarray:
     """[layers * len(LOAD_COUNTERS)] f32 from the cohort's expert
-    loads: load [W, layers, held + 3], per client and layer the picks
+    loads: load [W, layers, held + 4], per client and layer the picks
     on each held expert, the picks in all, those of them in chunks of
-    positions that ran compacted and, last, the held picks' share of
-    the chunk that had most (models/experts.expert_chunk)."""
+    positions that ran compacted, the chunks and, last, the held
+    picks' share of the chunk that had most
+    (models/experts.expert_layer)."""
     load = load.astype(jnp.float32)
     live_peak = load[..., -1].max(0)                # over the cohort
     load = load[..., :-1].sum(0)
-    held, picks, compact = load[:, :-2], load[:, -2], load[:, -1]
+    held, picks, compact, chunks = (load[:, :-3], load[:, -3], load[:, -2],
+                                    load[:, -1])
     routed = held.sum(-1)
     picks = jnp.maximum(picks, 1.0)
     return jnp.stack(
         [routed, held.max(-1), held.min(-1), 1.0 - routed / picks,
-         compact / picks, live_peak], axis=-1).reshape(-1)
+         compact / picks, chunks, live_peak], axis=-1).reshape(-1)
 
 
 def metric_names(size: int) -> tuple:

@@ -430,6 +430,9 @@ def test_driver_trains_trinity(tmp_path):
     for l in range(layers):
         assert m[f"moe{l}_routed"] > 0
         assert m[f"moe{l}_absent_share"] == 0.0
+        # every layer's backward pass took the same whole chunks
+        assert m[f"moe{l}_chunks"] == m["moe0_chunks"] > 0
+        assert float(m[f"moe{l}_chunks"]).is_integer()
     assert f"moe{layers}_routed" not in m
     assert np.isfinite(m["train_loss"]) and m["examples"] > 0
 

@@ -196,10 +196,11 @@ def test_skewed_router_drops_no_token(case):
     params["embed"] = params["embed"].at[:, 0].set(first)
     params = skewed(cfg, params, pull0, pull1)
     _, load = st.hidden(cfg, params, ids)
-    load = np.asarray(load)[:, 0]                # [N, held + 3]
-    sizes, total, compact, peak = (load[:, :-3], load[:, -3], load[:, -2],
-                                   load[:, -1])
+    load = np.asarray(load)[:, 0]                # [N, held + 4]
+    sizes, total, compact, chunks, peak = (
+        load[:, :-4], load[:, -4], load[:, -3], load[:, -2], load[:, -1])
     assert total.tolist() == [2 * L, 2 * L]
+    assert chunks.tolist() == [L // cfg.moe_chunk] * 2
     if some:
         assert (sizes[:, 0] == L // 4).all()
     else:
@@ -366,7 +367,12 @@ def test_every_expert_held_traces_no_branch():
     def full(p, h2, r):
         return st.full_width(cfg, p, h2, *st.sorted_picks(cfg, r)).sum()
 
-    for fn in (mine, jax.grad(mine, (0, 1, 2))):
+    def layer(cfg):
+        return lambda p, h2, r: st.expert_layer(
+            cfg, p, h2[None], r[None])[0].sum()
+
+    for fn in (mine, jax.grad(mine, (0, 1, 2)), layer(cfg),
+               jax.grad(layer(cfg), (0, 1, 2))):
         assert not {"cond", "while"} & set(primitives(fn, p, h2, r))
     share = cfg.replace(held_experts=(0, 2))
     p2, _, _ = chunk_inputs(share, L)
@@ -375,11 +381,13 @@ def test_every_expert_held_traces_no_branch():
         return st.expert_chunk(share, p, h2, r)[0].sum()
 
     assert Counter(primitives(shared, p2, h2, r))["cond"] == 1
-    # backward the choice is a loop of one trip or none for each of
-    # the two branches, and no second `cond`: with one there the
-    # chip's compiler keeps a copy of the round's old weights through
-    # the program's peak (tests/test_tpu_compile.py holds the total)
-    backward = Counter(primitives(jax.grad(shared, (0, 1, 2)), p2, h2, r))
+    # backward (the layer's, over its chunks) the choice is a loop of
+    # one trip or none for each of the two branches, and no second
+    # `cond`: with one there the chip's compiler keeps a copy of the
+    # round's old weights through the program's peak
+    # (tests/test_tpu_compile.py holds the total)
+    backward = Counter(primitives(jax.grad(layer(share), (0, 1, 2)), p2,
+                                  h2, r))
     assert backward["cond"] == 1 and backward["while"] == 2
     got = Counter(primitives(jax.grad(mine, (0, 1, 2)), p, h2, r))
     want = Counter(primitives(jax.grad(full, (0, 1, 2)), p, h2, r))
@@ -394,25 +402,27 @@ def test_load_counters_of_a_hand_built_load():
     ran one compacted and one not, client 1's one chunk compacted."""
     from commefficient_tpu.telemetry import metrics as tm
     assert tm.LOAD_COUNTERS == ("routed", "max_load", "min_load",
-                                "absent_share", "compact_share",
+                                "absent_share", "compact_share", "chunks",
                                 "live_peak")
     names = tm.metric_names(tm.NUM_METRICS + 2 * len(tm.LOAD_COUNTERS))
     assert names[:tm.NUM_METRICS] == tm.METRIC_NAMES
     assert names[tm.NUM_METRICS:] == tuple(
         f"moe{l}_{c}" for l in range(2) for c in tm.LOAD_COUNTERS)
-    assert names.index("moe1_compact_share") == tm.NUM_METRICS + 10
-    assert names.index("moe1_live_peak") == tm.NUM_METRICS + 11
-    chunks = jnp.asarray([[[3.0, 1.0, 32.0, 32.0, 4 / 32],
-                           [20.0, 4.0, 32.0, 0.0, 24 / 32]]])
-    one = st.merge_loads(chunks, 1)              # client 0: [1, held + 3]
-    assert np.asarray(one).tolist() == [[23.0, 5.0, 64.0, 32.0, 0.75]]
-    load = jnp.stack([one, jnp.asarray([[2.0, 2.0, 32.0, 32.0, 4 / 32]])])
-    vec = np.asarray(tm.expert_load_vector(load.reshape(2, 1, 5)))
+    assert names.index("moe1_compact_share") == tm.NUM_METRICS + 11
+    assert names.index("moe1_chunks") == tm.NUM_METRICS + 12
+    assert names.index("moe1_live_peak") == tm.NUM_METRICS + 13
+    chunks = jnp.asarray([[[3.0, 1.0, 32.0, 32.0, 1.0, 4 / 32],
+                           [20.0, 4.0, 32.0, 0.0, 1.0, 24 / 32]]])
+    one = st.merge_loads(chunks, 1)              # client 0: [1, held + 4]
+    assert np.asarray(one).tolist() == [[23.0, 5.0, 64.0, 32.0, 2.0, 0.75]]
+    load = jnp.stack(
+        [one, jnp.asarray([[2.0, 2.0, 32.0, 32.0, 1.0, 4 / 32]])])
+    vec = np.asarray(tm.expert_load_vector(load.reshape(2, 1, 6)))
     np.testing.assert_allclose(
-        vec, [32.0, 25.0, 7.0, 1 - 32 / 96, 64 / 96, 0.75], rtol=1e-6)
+        vec, [32.0, 25.0, 7.0, 1 - 32 / 96, 64 / 96, 3.0, 0.75], rtol=1e-6)
     # one chunk compacted of two
     half = np.asarray(tm.expert_load_vector(one[None]))
-    assert half[4] == 0.5 and half[5] == 0.75
+    assert half[4] == 0.5 and half[5] == 2.0 and half[6] == 0.75
 
 
 @pytest.mark.parametrize("share", range(4))
@@ -482,6 +492,8 @@ def test_driver_trains_smallthinker(tmp_path, mode):
         # every expert is held in the tiny preset
         assert m[f"moe{l}_absent_share"] == 0.0
         assert m[f"moe{l}_compact_share"] == 0.0
+        assert m[f"moe{l}_chunks"] == m["moe0_chunks"] > 0
+        assert float(m[f"moe{l}_chunks"]).is_integer()
         assert m[f"moe{l}_live_peak"] == 1.0
     assert np.isfinite(m["train_loss"]) and m["examples"] > 0
 

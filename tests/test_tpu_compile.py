@@ -22,7 +22,9 @@ sample must be a strided slice, not a gather.
 And the language model's round program whole, attention by the
 kernels as a TPU runs it, with one chunk of its expert layer alone:
 compacted to the held experts' rows it must move a fraction of the
-full-width path's bytes.
+full-width path's bytes. And the expert layer over its chunks, forward
+and backward: its grouped products counted in the lowered program, and
+no weight-sized zeros or copies inside the backward's loops.
 
 A compile that passes is not a chip run: chip_smoke.py is.
 """
@@ -457,29 +459,130 @@ def test_compacted_expert_chunk_moves_a_fraction_of_the_bytes(one_chip):
     assert full.cost_analysis()["bytes accessed"] > 2 * moved
 
 
-def test_expert_chunk_backward_runs_the_chosen_branch_alone(one_chip):
-    """The chunk as the model differentiates it: the backward pass
-    holds one loop for each branch, of one trip or none, and every
-    grouped product is inside one of them. Were the bodies lifted out
-    (they depend on nothing a trip changes, but for the barrier of
-    `_once`) all three would run for every chunk: 30.3 ms a chunk on
-    the chip where one branch takes 10.9 (PERF.md section 6)."""
+def _computations(text):
+    """{name: (is the entry, body text)} of a compiled module's text."""
+    out = {}
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ [\(\{])", text):
+        m = re.match(r"(ENTRY )?%([\w.\-]+)", comp)
+        if m:
+            out[m.group(2)] = (bool(m.group(1)), comp)
+    return out
+
+
+def _instructions(body, shapes):
+    """(opcode, result type) of each instruction of `body` whose result
+    has one of `shapes` (as `f32[8,2560,768]`)."""
+    return [(op, typ) for typ, op in re.findall(
+        r"\n\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", body)
+        if typ.split("{")[0] in shapes]
+
+
+@pytest.fixture(scope="module")
+def expert_layer_program(one_chip):
+    """The SmallThinker cell's expert layer (8 of 64 experts held,
+    top-6, chunks of 4,096 positions) over its two sequences of 8,192
+    positions, four chunks, forward and backward, compiled for one
+    described chip."""
     from commefficient_tpu.models import smallthinker as st
 
     cfg = st.SmallThinkerConfig(num_layers=4, held_experts=(0, 8),
                                 vocab_size=18992)
-    T, H, F = cfg.moe_chunk, cfg.hidden_size, cfg.expert_width
+    H, F, E = cfg.hidden_size, cfg.expert_width, cfg.num_experts
 
-    def chunk(gate, up, down, h2, r):
+    def layer(gate, up, down, h2, r):
         p = {"gate": gate, "up": up, "down": down}
-        return st.expert_chunk(cfg, p, h2, r)[0].sum()
+        return st.expert_layer(cfg, p, h2, r)[0].sum()
 
     compiled = _compile(
-        jax.grad(jax.checkpoint(chunk), (0, 1, 2, 3, 4)),
+        jax.value_and_grad(layer, (0, 1, 2, 3, 4)),
         ((8, H, F), jnp.float32), ((8, H, F), jnp.float32),
-        ((8, F, H), jnp.float32), ((T, H), jnp.float32),
-        ((T, cfg.num_experts), jnp.float32), sharding=one_chip)
-    entry = compiled.as_text().split("\nENTRY ")[1]
-    assert len(re.findall(r" while\(", entry)) == 3
-    assert " conditional(" not in entry and "ragged-dot" not in entry
-    assert "ragged-dot" in compiled.as_text()
+        ((8, F, H), jnp.float32), ((2, 8192, H), jnp.float32),
+        ((2, 8192, E), jnp.float32), sharding=one_chip)
+    return (f"f32[8,{H},{F}]", f"f32[8,{F},{H}]"), compiled.as_text()
+
+
+def test_expert_chunk_backward_runs_the_chosen_branch_alone(
+        expert_layer_program):
+    """The layer as the model differentiates it: a loop over the
+    chunks forward (each chunk's capacity a `conditional`) and one
+    backward, whose body holds one loop for each branch, of one trip
+    or none, and every grouped product of the backward pass inside
+    them. Were the bodies lifted out (they depend on nothing a trip
+    changes, but for the barrier of `_once`) all three would run for
+    every chunk: 30.3 ms a chunk on the chip where one branch takes
+    10.9 (PERF.md section 6)."""
+    _, text = expert_layer_program
+    comps = _computations(text)
+    entry = next(body for is_entry, body in comps.values() if is_entry)
+    assert len(re.findall(r" conditional\(", text)) == 1
+    assert "ragged-dot" not in entry
+    loops = re.findall(r" while\(.*?body=%([\w.\-]+)", entry)
+    assert len(loops) == 2
+    backward = [comps[name][1] for name in loops
+                if len(re.findall(r" while\(", comps[name][1])) == 3]
+    assert len(backward) == 1
+    assert "ragged-dot" not in backward[0]
+    assert " conditional(" not in backward[0]
+
+
+def test_expert_layer_backward_adds_into_one_accumulator(
+        expert_layer_program):
+    """No weight-sized zeros, copy or select a chunk: the layer's
+    gradient accumulators are made once (three `broadcast`s of the
+    weights' shapes, in the entry), the weights transposed once (three
+    `copy`s, in the entry), and inside the loops each branch's trip
+    adds its three products into them. Before, every chunk made the
+    zeros, copied the three weights inside each trip, and the map's
+    transpose added each chunk's gradient to its carried sum."""
+    weights, text = expert_layer_program
+    made = {"entry": [], "loops": []}
+    for is_entry, body in _computations(text).values():
+        made["entry" if is_entry else "loops"] += _instructions(body, weights)
+    ops = {k: collections.Counter(op for op, _ in v)
+           for k, v in made.items()}
+    assert ops["entry"]["broadcast"] == 3 and ops["entry"]["copy"] == 3
+    for op in ("broadcast", "copy", "select", "fusion"):
+        assert ops["loops"][op] == 0, (op, made["loops"])
+    # three branches, three weights
+    assert ops["loops"]["add"] == 9
+
+
+@pytest.mark.parametrize("family", ["smallthinker", "trinity"])
+def test_expert_layer_grouped_products_do_not_grow(one_chip, family):
+    """The grouped products (`chlo.ragged_dot`) of one expert layer in
+    the lowered program, at the cell's shapes: forward three a branch
+    (the switch's three branches), backward nine a branch (the chunk's
+    three again, and two transposes each), as the map of
+    rematerialised chunks had them."""
+    from commefficient_tpu.models import afmoe, experts
+    from commefficient_tpu.models import smallthinker as st
+
+    if family == "smallthinker":
+        cfg = st.SmallThinkerConfig(num_layers=4, held_experts=(0, 8),
+                                    vocab_size=18992)
+    else:
+        cfg = afmoe.AfmoeConfig(num_layers=5, num_dense_layers=1,
+                                held_experts=(0, 8), vocab_size=25024)
+    H, F, E = cfg.hidden_size, cfg.expert_width, cfg.num_experts
+    assert len(experts.compact_capacities(cfg, cfg.moe_chunk)) == 2
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    p = {"gate": shaped(8, H, F), "up": shaped(8, H, F),
+         "down": shaped(8, F, H), "expert_bias": shaped(E)}
+    h2, r = shaped(2, 8192, H), shaped(2, 8192, E)
+
+    def layer(p, h2, r):
+        return experts.expert_layer(cfg, p, h2, r)[0]
+
+    def count(fn, *args):
+        text = jax.jit(fn).lower(*args).as_text()
+        return len(re.findall(r"= \"chlo\.ragged_dot\"", text))
+
+    def both(p, h2, r, dy):
+        y, back = jax.vjp(layer, p, h2, r)
+        return y, back(dy)
+
+    forward = count(layer, p, h2, r)
+    assert (forward, count(both, p, h2, r, h2) - forward) == (9, 27)
