@@ -577,6 +577,11 @@ class Config:
     # the round appends seven counters an expert layer to its telemetry
     # vector (telemetry/metrics.expert_load_vector).
     expert_load_layers: int = 0
+    # likewise, the model's state-space mixer layers: the loss's metrics
+    # then end with each client's mixer stat [layers, 2] after the
+    # load, and the round appends two counters a mixer layer
+    # (telemetry/metrics.mixer_vector).
+    mixer_layers: int = 0
 
     # --- derived helpers -------------------------------------------------
     def replace(self, **kw) -> "Config":

@@ -1464,8 +1464,8 @@ def make_train_fn(loss_fn: fclient.LossFn, unravel: Callable,
                     survivors=(jnp.float32(num_workers) if eff is None
                                else eff.sum()))
                 if cfg.expert_load_layers:
-                    tele = jnp.concatenate(
-                        [tele, tmetrics.expert_load_vector(metrics[-1])])
+                    tele = jnp.concatenate([tele, *tmetrics.layer_vectors(
+                        metrics, cfg.mixer_layers)])
         else:
             tele = tmetrics.empty_vector()
 

@@ -54,7 +54,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from commefficient_tpu.models import decoder
 from commefficient_tpu.models.decoder import rmsnorm, rope
-from commefficient_tpu.models.experts import expert_layer
+from commefficient_tpu.models.experts import expert_layer, sigmoid_route
 from commefficient_tpu.ops.attention import blockwise_attention
 from commefficient_tpu.scopes import scope
 
@@ -98,17 +98,9 @@ class AfmoeConfig:
         return self.num_layers - self.num_dense_layers
 
     def route(self, r, p):
-        """Router logits r [T, E] -> (idx [T, k]: top k of the sigmoid
-        scores plus the expert bias, weight [T, k]: the picks' unbiased
-        scores, over their sum where `route_norm`, times
-        `route_scale`)."""
-        s = jax.nn.sigmoid(r.astype(jnp.float32))
-        _, idx = jax.lax.top_k(s + p["expert_bias"].astype(jnp.float32),
-                               self.experts_per_token)
-        w = jnp.take_along_axis(s, idx, axis=-1)
-        if self.route_norm:
-            w = w / (w.sum(-1, keepdims=True) + 1e-20)
-        return idx, w * self.route_scale
+        """`experts.sigmoid_route` with the layer's expert bias."""
+        return sigmoid_route(r, p["expert_bias"], self.experts_per_token,
+                             self.route_norm, self.route_scale)
 
     def expert_act(self, x):
         return jax.nn.silu(x)

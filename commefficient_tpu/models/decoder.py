@@ -1,8 +1,8 @@
 """Pieces the mixture-of-experts language models share (`models/
-smallthinker.py`, `models/afmoe.py`) beside their expert layer
-(`models/experts.py`): RMSNorm, rotate-half RoPE, and the next-token
-loss over a whole cohort, with the head and the softmax in blocks of
-positions."""
+smallthinker.py`, `models/afmoe.py`, `models/nemotron_h.py`) beside
+their expert layer (`models/experts.py`): RMSNorm, rotate-half RoPE,
+and the next-token loss over a whole cohort, with the head and the
+softmax in blocks of positions."""
 from __future__ import annotations
 
 import jax
@@ -60,10 +60,12 @@ def make_lm_loss(cfg, pad_id: int, hidden):
     first), for a whole cohort at once: batch leaves [W, B, ...],
     mask [W, B] -> (losses [W], (load [W, layers, held + 4],)), where
     `hidden(cfg, params, ids)` gives the final normed hidden states
-    and the expert layers' loads; the round's telemetry turns the
-    loads into its per-layer counters
-    (telemetry/metrics.expert_load_vector). A client's loss is the
-    mean over the real next tokens of its valid examples."""
+    and the expert layers' loads, and in a model with state-space
+    mixers their stats [.., mixer layers, 2] after those, which the
+    metrics then end with, summed over a client's sequences; the
+    round's telemetry turns them into its per-layer counters
+    (telemetry/metrics.layer_vectors). A client's loss is the mean
+    over the real next tokens of its valid examples."""
 
     def compute_loss(params, batch, mask):
         ids = batch[0]
@@ -75,13 +77,14 @@ def make_lm_loss(cfg, pad_id: int, hidden):
         valid = ((labels != pad_id).reshape(W, B, C, L)
                  * mask[:, :, None, None]).astype(jnp.float32) \
             .reshape(W * B * C, L)
-        h, load = hidden(cfg, params, ids)
+        h, load, *mixer = hidden(cfg, params, ids)
         with scope("head_loss"):
             nll = next_token_nll(cfg, params["head"], h, labels, valid)
         per_client = nll.reshape(W, B * C).sum(1) / jnp.maximum(
             valid.reshape(W, -1).sum(1), 1.0)
         return per_client, (
-            merge_loads(load.reshape(W, B * C, *load.shape[1:]), 1),)
+            merge_loads(load.reshape(W, B * C, *load.shape[1:]), 1),
+            *(m.reshape(W, B * C, *m.shape[1:]).sum(1) for m in mixer))
 
     compute_loss.cohort = True
     return compute_loss

@@ -24,16 +24,20 @@ layer (`_chunks`).
 
 `cfg`, everywhere here, is the model's frozen configuration. What the
 layer reads of it: `num_experts` (the router's width),
-`experts_per_token`, `held_experts`, `moe_chunk`, and two methods that
-are the family's own:
+`experts_per_token`, `held_experts`, `moe_chunk`, two methods that
+are the family's own, and which weights an expert has:
 
     cfg.route(r, p)     router logits r [T, E] (and the layer's
                         parameters p) -> (idx [T, k] over all E
                         experts, weight [T, k] of each pick)
-    cfg.expert_act(x)   the gate's activation: y = (act(x G) * (x U)) D
+    cfg.expert_act(x)   the activation: a gated expert (WEIGHTS) is
+                        y = (act(x G) * (x U)) D, an ungated one
+                        (UNGATED) y = act(x U) D
+    cfg.expert_weights  WEIGHTS or UNGATED; WEIGHTS where the family
+                        does not say
 
-`p` holds each held expert's `gate`, `up` [held, H, F] and `down`
-[held, F, H].
+`p` holds each held expert's `gate` (gated only), `up` [held, H, F]
+and `down` [held, F, H].
 """
 from __future__ import annotations
 
@@ -46,7 +50,26 @@ import jax.numpy as jnp
 
 from commefficient_tpu.scopes import scope
 
-WEIGHTS = ("gate", "up", "down")     # the held experts' own
+WEIGHTS = ("gate", "up", "down")     # a gated family's held experts
+UNGATED = ("up", "down")             # an ungated family's
+
+
+def weights_of(cfg) -> tuple:
+    """The names of the family's expert weights, `down` last."""
+    return getattr(cfg, "expert_weights", WEIGHTS)
+
+
+def sigmoid_route(r, bias, k: int, norm: bool, scale: float):
+    """A sigmoid router with a selection bias (AFMoE, Nemotron-H):
+    logits r [T, E] -> (idx [T, k]: top k of the sigmoid scores plus
+    `bias`, weight [T, k]: the picks' unbiased scores, over their sum
+    where `norm`, times `scale`)."""
+    s = jax.nn.sigmoid(r.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx, w * scale
 
 
 def block_of(L: int, at_most: int) -> int:
@@ -102,34 +125,45 @@ _BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _ffn(cfg, w: dict, wt, rows, sizes, live):
-    """The held experts' three grouped products on rows sorted by
-    expert, cut to the `live` rows."""
+    """The held experts' grouped products on rows sorted by expert,
+    cut to the `live` rows: a gated family's three, an ungated one's
+    two."""
     return _ffn_fwd(cfg, w, wt, rows, sizes, live)[0]
+
+
+def _inner(cfg, *ins):
+    """The expert's activation from its input products: (x G, x U)
+    gated, (x U,) ungated."""
+    if len(ins) == 2:
+        return cfg.expert_act(ins[0]) * ins[1]
+    return cfg.expert_act(ins[0])
 
 
 def _ffn_fwd(cfg, w, wt, rows, sizes, live):
     cut = _cut_to(live)
-    g = cut(jax.lax.ragged_dot(rows, w["gate"], sizes))
-    u = cut(jax.lax.ragged_dot(rows, w["up"], sizes))
-    out = cut(jax.lax.ragged_dot(cfg.expert_act(g) * u, w["down"], sizes))
-    return out, (w, wt, rows, sizes, live, g, u)
+    ins = tuple(cut(jax.lax.ragged_dot(rows, w[name], sizes))
+                for name in weights_of(cfg)[:-1])
+    out = cut(jax.lax.ragged_dot(_inner(cfg, *ins), w["down"], sizes))
+    return out, (w, wt, rows, sizes, live, ins)
 
 
 def _ffn_bwd(cfg, res, dout):
-    """JAX's own transposes of the three products, the rows' gradient
-    reading the weights from `wt`, already `transposed`, where given."""
-    w, wt, rows, sizes, live, g, u = res
+    """JAX's own transposes of the products, the rows' gradient reading
+    the weights from `wt`, already `transposed`, where given."""
+    w, wt, rows, sizes, live, ins = res
     if wt is None:
         wt = transposed(w)
     cut = _cut_to(live)
     dout = cut(dout)
-    a, act_back = jax.vjp(lambda g, u: cfg.expert_act(g) * u, g, u)
-    dg, du = map(cut, act_back(
-        jax.lax.ragged_dot(dout, wt["down"], sizes)))
-    drows = (jax.lax.ragged_dot(dg, wt["gate"], sizes)
-             + jax.lax.ragged_dot(du, wt["up"], sizes))
+    names = weights_of(cfg)[:-1]
+    a, act_back = jax.vjp(functools.partial(_inner, cfg), *ins)
+    dins = tuple(map(cut, act_back(
+        jax.lax.ragged_dot(dout, wt["down"], sizes))))
+    drows = functools.reduce(jnp.add, (
+        jax.lax.ragged_dot(d, wt[name], sizes)
+        for name, d in zip(names, dins)))
     dw = {name: jax.lax.ragged_dot_general(x, dy, sizes, _BY_GROUP)
-          for name, x, dy in (("gate", rows, dg), ("up", rows, du),
+          for name, x, dy in (*((n, rows, d) for n, d in zip(names, dins)),
                               ("down", a, dout))}
     return dw, None, drows, None, None
 
@@ -146,8 +180,8 @@ def transposed(w: dict) -> dict:
 def _grouped_ffn(cfg, p: dict, rows, sizes, live, wt=None):
     """`_ffn` on `p`'s weights; `wt`, where given, holds them
     `transposed` for the backward pass."""
-    return _ffn(cfg, {name: p[name] for name in WEIGHTS}, wt, rows, sizes,
-                live)
+    return _ffn(cfg, {name: p[name] for name in weights_of(cfg)}, wt, rows,
+                sizes, live)
 
 
 def full_width(cfg, p: dict, h2, prob, order, sizes, wt=None):
@@ -258,8 +292,8 @@ def expert_chunk(cfg, p: dict, h2, r):
     if caps:
         which = _branch_of(caps, sizes)
         y = jax.lax.switch(which, _branches(cfg, T),
-                           {name: p[name] for name in WEIGHTS}, h2, prob,
-                           order, sizes)
+                           {name: p[name] for name in weights_of(cfg)}, h2,
+                           prob, order, sizes)
         compact = jnp.where(which < len(caps), picks, 0)
     else:
         y = full_width(cfg, p, h2, prob, order, sizes)
@@ -343,7 +377,7 @@ def _chunks_bwd(cfg, res, cts):
     picks: no gradient reaches them here."""
     p, h2, r = res
     dy = cts[0]
-    w = {name: p[name] for name in WEIGHTS}
+    w = {name: p[name] for name in weights_of(cfg)}
     # once a layer: transposed inside the loops, they were copied in
     # every trip
     wt = transposed(w)

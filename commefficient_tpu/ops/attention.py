@@ -679,7 +679,10 @@ def _bw_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
         dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
         for g in range(groups):
             rows = slice(g * head_dim, (g + 1) * head_dim)
-            delta_scr[g] = (do_ref[0, rows, :] * o_ref[0, rows, :]).sum(
+            # float32 whatever the operands' dtype (the bfloat16
+            # control's do and o)
+            delta_scr[g] = (do_ref[0, rows, :].astype(jnp.float32)
+                            * o_ref[0, rows, :].astype(jnp.float32)).sum(
                 axis=0, keepdims=True)
 
     kt = k_ref[0].T.astype(_MXU_OPERAND)

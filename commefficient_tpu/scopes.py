@@ -61,6 +61,11 @@ for one of them by name finds it anywhere on the path):
                       adds they wrap, and the final norm
     head_loss         the head product, the softmax and the label pick
                       in blocks, and their sums (decoder.next_token_nll)
+    ssm_proj          a Mamba-2 mixer's input and output projections
+                      (models/nemotron_h.py)
+    ssm_scan          the rest of the mixer: the causal convolution,
+                      dt, the chunked scan (ops/ssd.py), the D skip
+                      and the gated group norm
     unravel           the flat vector's split into the model's leaves
                       and their cast (client.make_flat_grad_fn,
                       make_flat_loss_fn); its transpose assembles the
@@ -80,7 +85,7 @@ SCOPES = ("fwdbwd", "residual", "encode", "aggregate", "select",
           "pack_change_bits",
           "attention_full", "attention_window", "moe_route", "expert_ffn",
           "shared_expert", "attn_proj", "dense_mlp", "norm", "head_loss",
-          "unravel")
+          "unravel", "ssm_proj", "ssm_scan")
 
 
 def scope(name: str):

@@ -138,7 +138,7 @@ def attach_run_telemetry(model, cfg, log_dir: str, coord: bool,
         # stage tracer for this run (session-owned; disabled at
         # close); the controller tag keys cross-controller stitching
         trace=bool(getattr(cfg, "trace", False)),
-        controller=jax.process_index())
+        controller=jax.process_index(), mixer_layers=cfg.mixer_layers)
     model.attach_telemetry(tele)
     tele.journal_event(
         "run_start", driver=driver, mode=cfg.mode,
@@ -174,6 +174,7 @@ class TelemetrySession:
                    pass multihost.gather_host in multi-controller runs
                    (the default jax.device_get only handles
                    process-addressable arrays)
+    mixer_layers:  Config.mixer_layers, to name the vector's counters
     """
 
     def __init__(self, journal: Optional[RunJournal] = None,
@@ -182,8 +183,10 @@ class TelemetrySession:
                  profile_dir: str = "profile_spans",
                  materialize: Callable = jax.device_get,
                  clock: Callable[[], float] = time.monotonic,
-                 trace: bool = False, controller: int = 0):
+                 trace: bool = False, controller: int = 0,
+                 mixer_layers: int = 0):
         self.journal = journal
+        self._mixer_layers = mixer_layers
         self.tracker = tracker
         # graftscope (ISSUE 13, --trace): enable the process-global
         # tracer for this run; the session owns it — drained at every
@@ -339,7 +342,7 @@ class TelemetrySession:
                 and seconds > 0):
             self.tracker.update_round(ids, counts_h, seconds,
                                       scheduled=scheduled)
-        named = tmetrics.named(vec_h)
+        named = tmetrics.named(vec_h, self._mixer_layers)
         if self.journal is not None:
             fields = {"round": round_idx}
             if named:
@@ -447,7 +450,8 @@ class TelemetrySession:
                           "seconds": round(per_round_s, 6)}
                 if telemetry_rows is not None:
                     named = tmetrics.named(
-                        np.asarray(telemetry_rows[i], np.float32))
+                        np.asarray(telemetry_rows[i], np.float32),
+                        self._mixer_layers)
                     if named:
                         fields["metrics"] = named
                 if comm_rows is not None:
@@ -470,7 +474,8 @@ class TelemetrySession:
                 self._check_trip(
                     int(first_round) + i,
                     tmetrics.named(np.asarray(telemetry_rows[i],
-                                              np.float32)))
+                                              np.float32),
+                                   self._mixer_layers))
 
     # ---------------- profiler capture (--profile_spans) -----------------
     def span_profile_begin(self, span_idx: int) -> None:

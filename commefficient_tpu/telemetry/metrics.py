@@ -65,6 +65,15 @@ with experts.
                     ran
   live_peak         the held picks' share of all picks, in the chunk
                     that had most of them
+
+A model with state-space mixer layers as well (Config.mixer_layers;
+models/nemotron_h.py) appends, after those, two counters a mixer
+layer, `ssm<l>_<name>` for name in MIXER_COUNTERS:
+
+  carry             the mean over heads and chunks of exp(sum of dt A
+                    over the chunk): the share of a chunk's end state
+                    that reaches the next chunk (ops/ssd.ssd_scan)
+  chunks            the chunks the layer scanned in the round
 """
 from __future__ import annotations
 
@@ -83,6 +92,7 @@ METRIC_NAMES = (
 NUM_METRICS = len(METRIC_NAMES)
 LOAD_COUNTERS = ("routed", "max_load", "min_load", "absent_share",
                  "compact_share", "chunks", "live_peak")
+MIXER_COUNTERS = ("carry", "chunks")
 METRIC_INDEX = {name: i for i, name in enumerate(METRIC_NAMES)}
 
 _EPS = 1e-12
@@ -148,18 +158,43 @@ def expert_load_vector(load) -> jnp.ndarray:
          compact / picks, chunks, live_peak], axis=-1).reshape(-1)
 
 
-def metric_names(size: int) -> tuple:
+def mixer_vector(stat) -> jnp.ndarray:
+    """[layers * len(MIXER_COUNTERS)] f32 from the cohort's mixer
+    stats: stat [W, layers, 2], per client and layer the sum over its
+    chunks of the heads' mean carried share, and the chunks
+    (models/nemotron_h.ssm)."""
+    stat = stat.astype(jnp.float32).sum(0)
+    chunks = stat[:, 1]
+    return jnp.stack([stat[:, 0] / jnp.maximum(chunks, 1.0), chunks],
+                     axis=-1).reshape(-1)
+
+
+def layer_vectors(aux, mixer_layers: int) -> tuple:
+    """The per-layer counters of a model with expert layers, from its
+    loss's metrics `aux`: they end with the expert load and, where the
+    model has `mixer_layers`, the mixer stat after it."""
+    if not mixer_layers:
+        return (expert_load_vector(aux[-1]),)
+    return expert_load_vector(aux[-2]), mixer_vector(aux[-1])
+
+
+def metric_names(size: int, mixer_layers: int = 0) -> tuple:
     """Names of a telemetry vector of `size` entries: METRIC_NAMES,
-    then LOAD_COUNTERS for each expert layer the vector carries."""
-    layers = (size - NUM_METRICS) // len(LOAD_COUNTERS)
+    then LOAD_COUNTERS for each expert layer the vector carries, then
+    MIXER_COUNTERS for each of its `mixer_layers`."""
+    mixer = mixer_layers * len(MIXER_COUNTERS)
+    layers = (size - NUM_METRICS - mixer) // len(LOAD_COUNTERS)
     return METRIC_NAMES + tuple(
-        f"moe{l}_{c}" for l in range(layers) for c in LOAD_COUNTERS)
+        f"moe{l}_{c}" for l in range(layers) for c in LOAD_COUNTERS) \
+        + tuple(f"ssm{l}_{c}" for l in range(mixer_layers)
+                for c in MIXER_COUNTERS)
 
 
-def named(vec) -> dict:
+def named(vec, mixer_layers: int = 0) -> dict:
     """Host-side convenience: {metric name: float} from one materialized
-    telemetry vector (or a no-op {} for a zero-size placeholder)."""
+    telemetry vector (or a no-op {} for a zero-size placeholder);
+    `mixer_layers` as Config.mixer_layers."""
     if vec is None or getattr(vec, "size", 0) == 0:
         return {}
-    return {name: float(vec[i])
-            for i, name in enumerate(metric_names(len(vec)))}
+    return {name: float(vec[i]) for i, name in
+            enumerate(metric_names(len(vec), mixer_layers))}

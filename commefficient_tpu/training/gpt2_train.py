@@ -37,7 +37,7 @@ from commefficient_tpu.models.gpt2 import (
     resize_position_embeddings, resize_token_embeddings, save_pretrained,
     try_load_pretrained,
 )
-from commefficient_tpu.models import afmoe, smallthinker
+from commefficient_tpu.models import afmoe, nemotron_h, smallthinker
 from commefficient_tpu.parallel import multihost as mh
 from commefficient_tpu.parallel.mesh import make_multihost_client_mesh
 from commefficient_tpu.parallel.tp import tp_loss
@@ -212,10 +212,10 @@ def train_gpt2(model: FedModel, opt: FedOptimizer, lr_scheduler,
                 "train_time": timer(),
                 "train_loss": losses[-1],
             }
-            if len(parts) == 2:
+            if len(parts) == 2 and not cfg.expert_load_layers:
                 # the double-heads loss reports its two terms; a
-                # language-model-only loss (--model smallthinker,
-                # trinity) has none beside the loss itself
+                # language-model-only loss (DECODERS) reports its
+                # layers' counters, which the telemetry names
                 row["lm_loss"] = float(np.mean(mh.gather_host(parts[0])))
                 row["mc_loss"] = float(np.mean(mh.gather_host(parts[1])))
             row["total_time"] = timer.total_time
@@ -465,7 +465,7 @@ def build_model_and_params(cfg: Config, tokenizer, seq_len: int,
     return module, params
 
 
-# ---------------- --model smallthinker | trinity -------------------------
+# ---------------- --model smallthinker | trinity | nemotron --------------
 
 # the mixture-of-experts language models, by the start of --model: the
 # model's module, its configuration class, and the key of its
@@ -474,6 +474,7 @@ DECODERS = {
     "smallthinker": (smallthinker, smallthinker.SmallThinkerConfig,
                      "moe_num_primary_experts"),
     "trinity": (afmoe, afmoe.AfmoeConfig, "num_experts"),
+    "nemotron": (nemotron_h, nemotron_h.NemotronHConfig, "n_routed_experts"),
 }
 
 
@@ -528,7 +529,8 @@ def build_decoder(cfg: Config, mcfg, tokenizer, params=None):
     loss_val.cohort = True
     if params is None:
         params = module.init_params(mcfg, jax.random.PRNGKey(cfg.seed))
-    return (cfg.replace(expert_load_layers=mcfg.expert_layers),
+    return (cfg.replace(expert_load_layers=mcfg.expert_layers,
+                        mixer_layers=getattr(mcfg, "mixer_layers", 0)),
             loss_train, loss_val, params)
 
 

@@ -158,6 +158,8 @@ KERNEL_CASES = [
     # (L, G, window): block 8, two key-value heads, Dh 16
     (48, 7, None), (48, 8, None), (48, 7, 16), (48, 8, 16), (48, 7, 32),
     (48, 8, 32), (45, 7, None), (45, 8, 16), (45, 8, 32),
+    # Nemotron-H's 32 query heads over 2 key-value heads
+    (45, 16, 16), (48, 16, None),
 ]
 
 
@@ -275,3 +277,19 @@ def test_block_geometry(L, block, lanes, want):
     """(block, padded length): the kernels take blocks of whole
     128-position lanes, the XLA path whole 8-position sublanes."""
     assert A._bwa_geometry(L, block, lanes) == want
+
+
+def test_kernels_take_bfloat16_operands():
+    """The bfloat16 control's q, k, v, o and do (Config.do_bf16), at
+    Nemotron-H's 16 query heads a key-value head: both kernels agree
+    with their float32 run to bfloat16's reach. (What the chip's
+    compiler refuses of such operands, interpret mode does not:
+    `tests/test_tpu_compile.py` compiles the backward for them.)"""
+    q, k, v, do = kernel_inputs(48, 16, seed=20)
+    low = run_kernels(*(x.astype(jnp.bfloat16) for x in (q, k, v, do)),
+                      None)
+    want = run_kernels(q, k, v, do, None)
+    for name, g, w in zip(("o", "lse", "dq", "dk", "dv"), low, want):
+        g = g.astype(jnp.float32)
+        rel = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        assert rel < 2e-2, (name, rel)

@@ -4,9 +4,10 @@ every chunk's rows' written in place of its inputs. Against the plain
 formulation it replaced: `jax.lax.map` over the chunks, each a
 `jax.checkpoint` of `expert_chunk`, differentiated by JAX's own rules
 (the grouped products' custom backward taken out too), on the TINY
-SmallThinker and Trinity configurations at several chunks, with some
-experts held and with all held, and routings that put chunks into
-every capacity and into the every-pick fallback."""
+SmallThinker and Trinity configurations (gated experts) and the TINY
+Nemotron-H one (ungated relu^2 experts, two weights) at several
+chunks, with some experts held and with all held, and routings that
+put chunks into every capacity and into the every-pick fallback."""
 import functools
 
 import jax
@@ -15,6 +16,7 @@ import numpy as np
 import pytest
 
 from commefficient_tpu.models import afmoe, experts
+from commefficient_tpu.models import nemotron_h as nh
 from commefficient_tpu.models import smallthinker as st
 
 N, L = 2, 64                 # 8 chunks of TINY's 16 positions
@@ -50,6 +52,9 @@ CASES = {
     "trinity_some_held": (afmoe.TINY, (6, 2), 32,
                           (0.0, 0.9, 4.0, 0.0, 0.9, 4.0, 0.6, 0.0)),
     "trinity_all_held": (afmoe.TINY, (0, 16), 16, (0.0,) * 8),
+    "nemotron_some_held": (nh.TINY, (6, 2), 32,
+                           (0.0, 0.9, 4.0, 0.0, 0.9, 4.0, 0.6, 0.0)),
+    "nemotron_all_held": (nh.TINY, (0, 16), 16, (0.0,) * 8),
 }
 
 
@@ -116,7 +121,7 @@ def test_layer_backward_matches_the_plain_map(name, by_jax_rules):
         np.asarray(load), np.concatenate(
             [np.asarray(load_want)[:, :-1], np.full((N, 1), chunks),
              np.asarray(load_want)[:, -1:]], axis=1))
-    for k in experts.WEIGHTS:
+    for k in experts.weights_of(cfg):
         assert_close(got[0][k], want[0][k], k)
     # the router's own parameters only choose the picks
     for k in ("router", "expert_bias"):
@@ -126,11 +131,11 @@ def test_layer_backward_matches_the_plain_map(name, by_jax_rules):
     assert_close(got[2], want[2], "dr")
 
 
-def test_products_backward_is_jaxs_own():
+def products_backward_against_jaxs(name):
     """The grouped products' own backward (the weights read transposed,
     passed in or not) against JAX's rules on the same products."""
-    cfg, p, h2, r, dy = case_inputs("trinity_some_held")
-    w = {k: p[k] for k in experts.WEIGHTS}
+    cfg, p, h2, r, dy = case_inputs(name)
+    w = {k: p[k] for k in experts.weights_of(cfg)}
     prob, order, sizes = experts.sorted_picks(cfg, r[0, :16], p)
     C = 16 * cfg.experts_per_token
     rows = h2.reshape(-1, cfg.hidden_size)[order[:C] // 4 % 64]
@@ -141,10 +146,20 @@ def test_products_backward_is_jaxs_own():
     for wt in (None, experts.transposed(w)):
         got = jax.vjp(lambda w, rows: experts._ffn(
             cfg, w, wt, rows, sizes, live), w, rows)[1](ct)
-        for k in experts.WEIGHTS:
+        for k in w:
             assert_close(got[0][k], want[0][k], k)
         assert_close(jnp.where(live, got[1], 0), jnp.where(live, want[1], 0),
                      "drows")
+
+
+def test_products_backward_is_jaxs_own():
+    """Three products of a gated family's experts."""
+    products_backward_against_jaxs("trinity_some_held")
+
+
+def test_ungated_products_backward_is_jaxs_own():
+    """Two products of an ungated family's (relu^2) experts."""
+    products_backward_against_jaxs("nemotron_some_held")
 
 
 def lm_case(module, held, experts_):
@@ -169,9 +184,11 @@ def lm_case(module, held, experts_):
 
 @pytest.mark.parametrize("module,held,experts_", [
     (st, (0, 2), 16), (st, (0, 8), 8),
-    (afmoe, (4, 2), 16), (afmoe, (0, 16), 16)],
+    (afmoe, (4, 2), 16), (afmoe, (0, 16), 16),
+    (nh, (4, 2), 16), (nh, (0, 16), 16)],
     ids=["smallthinker_some_held", "smallthinker_all_held",
-         "trinity_some_held", "trinity_all_held"])
+         "trinity_some_held", "trinity_all_held", "nemotron_some_held",
+         "nemotron_all_held"])
 def test_whole_model_gradient_matches_the_plain_map(module, held, experts_,
                                                     by_jax_rules,
                                                     monkeypatch):

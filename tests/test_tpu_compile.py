@@ -84,9 +84,10 @@ def test_flash_forward_compiles(one_chip, L, dtype):
 
 
 @pytest.mark.parametrize("heads,window", [
-    ((32, 4), 2048), ((32, 4), None), ((28, 4), 4096), ((28, 4), None)],
+    ((32, 4), 2048), ((32, 4), None), ((28, 4), 4096), ((28, 4), None),
+    ((32, 2), None)],
     ids=["trinity-window", "trinity-full", "smallthinker-window",
-         "smallthinker-full"])
+         "smallthinker-full", "nemotron-full"])
 def test_blockwise_kernels_compile_at_the_cells_shapes(one_chip, heads,
                                                        window):
     """Two sequences of 8,192 positions in blocks of 512, Dh 128: the
@@ -105,6 +106,19 @@ def test_blockwise_kernels_compile_at_the_cells_shapes(one_chip, heads,
         assert name in text
         # the pair table rides in as two s32[pairs] scalar operands
         assert f"s32[{pairs}]" in text
+
+
+def test_blockwise_backward_compiles_for_bfloat16_operands(one_chip):
+    """The bfloat16 control (Config.do_bf16) hands the kernels bfloat16
+    q, k, v, o and do; the backward's row sums of do * o go to float32
+    scratch, which the chip's compiler takes only as float32."""
+    (B, L, Hq, Hkv, Dh) = (2, 8192, 32, 2, 128)
+    q, kv = ((B, L, Hq, Dh), jnp.bfloat16), ((B, L, Hkv, Dh), jnp.bfloat16)
+    lse = ((B, Hkv, Hq // Hkv, L), jnp.float32)
+    bwd = _compile(lambda q, k, v, o, do, lse: attention._bw_bwd_pallas(
+        q, k, v, o, do, lse, None, 512), q, kv, kv, q, q, lse,
+        sharding=one_chip)
+    assert "bw_attn_bwd" in bwd.as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -295,7 +309,8 @@ def _lm_round_on_tpu(topo, module, config_file):
     cfg = Config(mode="uncompressed", error_type="none",
                  virtual_momentum=0.9, local_momentum=0.0, num_workers=W,
                  local_batch_size=1, weight_decay=0.0, num_clients=64,
-                 expert_load_layers=mcfg.expert_layers) \
+                 expert_load_layers=mcfg.expert_layers,
+                 mixer_layers=getattr(mcfg, "mixer_layers", 0)) \
         .replace(grad_size=D).validate()
     handle = fround.make_train_fn(module.make_lm_loss(mcfg, 4),
                                   holder["unravel"], cfg, mesh)
@@ -374,6 +389,29 @@ def test_trinity_round_fits_one_chip(topo, one_chip):
     assert "copy(%server_ps_weights" not in text
     # four 2,048-window layers (70 pairs each), one full layer (136)
     _assert_attention_by_the_kernels(text, {70: 4, 136: 1})
+
+
+def test_nemotron_round_fits_one_chip(topo, one_chip):
+    """The `nemotron_unc_w2_l8192` cell's round (D=528,093,120: three
+    expert layers, three Mamba-2 mixers and one attention layer, 8 of
+    128 experts beside a shared one): 12.53 GiB. The expert layer's
+    forward runs once a layer (one `conditional` each); attention is
+    the kernels at G = 16 query heads a key-value head; the mixers'
+    ops carry their two scope names."""
+    from commefficient_tpu.models import nemotron_h
+    cfg, compiled = _lm_round(topo, nemotron_h, "nemotron3_nano_ep16.json")
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert m.alias_size_in_bytes >= 2 * 4 * cfg.grad_size
+    assert total < 13.0 * 2 ** 30, total
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert len(re.findall(r" conditional\(", text)) == 3
+    assert "copy(%server_ps_weights" not in text
+    _assert_attention_by_the_kernels(text, {136: 1})
+    for name in ("ssm_scan", "ssm_proj"):
+        assert re.search(rf"[/(]fed_{name}[/)]", text), name
 
 
 def test_smallthinker_round_fits_one_chip(smallthinker_round):
